@@ -2815,7 +2815,7 @@ def _render_solver_status(snap: dict) -> str:
                 else ""
             )
             if mem
-            else "unreported by backend (CPU fallback reports none)"
+            else "unreported by backend (XLA:CPU reports none)"
         )
         + f"   live arrays {_fmt_bytes(snap.get('live_array_bytes'))}"
         + f" (highwater {_fmt_bytes(snap.get('live_array_highwater_bytes'))})"

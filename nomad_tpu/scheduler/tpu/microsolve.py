@@ -10,7 +10,7 @@ compact instance readback), executed synchronously on the host with
 zero device round-trip and zero jit involvement. The dense path's
 lowering, materialization, spread splits, overflow repair, and failure
 accounting are all shared — only the kernel invocation differs — so a
-micro solve is the dense solve, minus the tunnel.
+micro solve is the dense solve, minus the device round-trip.
 
 Not a third semantics: differential coverage pins this kernel to the
 jax compact kernel's outcomes (tests/test_tpu_solver.py), the same way

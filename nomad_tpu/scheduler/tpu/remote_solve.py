@@ -82,15 +82,7 @@ class RemoteSolver:
 
     def _ensure_resident(self) -> ResidentClusterState:
         if self._resident is None:
-            mesh = None
-            if (getattr(self.config, "mesh_devices", 0) or 0) > 1:
-                from .sharding import solver_mesh
-
-                try:
-                    mesh = solver_mesh(self.config.mesh_devices)
-                except RuntimeError:
-                    self.config.mesh_devices = 0
-            self._resident = ResidentClusterState(mesh=mesh)
+            self._resident = ResidentClusterState.for_config(self.config)
             self.warmups += 1
             metrics.incr("nomad.solver.pool.warmups")
         return self._resident

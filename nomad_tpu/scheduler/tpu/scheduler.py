@@ -48,26 +48,14 @@ def _mesh_for(config: SchedulerConfig, solve_fn):
     process-cached so every solver shares the compiled kernels.
     mesh_devices=1 is honored as a real 1-device mesh — the sharded
     bench's scaling baseline runs the SAME kernel at every mesh size.
-
-    A misconfigured mesh (NOMAD_TPU_MESH_DEVICES beyond the backend's
-    device count) must not raise: every scheduler process() would fail
-    and redeliver its eval forever. Degrade loudly to single-chip and
-    clear mesh_devices on the config so the error logs once per config,
-    not once per solve (TPUBatchWorker._ensure_resident applies the
-    same policy for its resident tensors)."""
+    A mesh wider than the backend raises (sharding.SolverMesh): the TPU
+    worker validates the same configuration when it starts, so a served
+    solve never gets here with one."""
     n = getattr(config, "mesh_devices", 0) or 0
     if solve_fn is None and n >= 1:
         from .sharding import solver_mesh
 
-        try:
-            return solver_mesh(n)
-        except RuntimeError as exc:
-            logger.error(
-                "mesh_devices=%d unusable (%s); falling back to the "
-                "single-chip solver — fix NOMAD_TPU_MESH_DEVICES or "
-                "the backend's device count", n, exc,
-            )
-            config.mesh_devices = 0
+        return solver_mesh(n)
     return None
 
 

@@ -16,9 +16,9 @@ needs to split the node axis over a `jax.sharding.Mesh`:
     pad rows carry zero capacity so they can never place).
   * shard accounting — per-shard real-row occupancy for solverobs and
     the modeled ICI bytes an all-gather solve moves (the transfer ledger
-    records them under the ``allgather`` direction; the CPU-fallback
-    mesh has no real ICI, so the model IS the measurement and is
-    documented as such in docs/sharding.md).
+    records them under the ``allgather`` direction; they are computed
+    from shapes, not measured on the interconnect, and documented as
+    such in docs/sharding.md).
 
 Layering: this module lives under scheduler/tpu, the one package allowed
 to import jax eagerly (nomad-vet NV-layering); the control plane reaches
@@ -64,8 +64,10 @@ class SolverMesh:
             if n_devices is not None:
                 if len(devices) < n_devices:
                     raise RuntimeError(
-                        f"mesh wants {n_devices} devices, backend has "
-                        f"{len(devices)}"
+                        f"mesh wants {n_devices} devices, the "
+                        f"{devices[0].platform} backend has "
+                        f"{len(devices)}: fix NOMAD_TPU_MESH_DEVICES "
+                        "(mesh_devices) or the backend's device count"
                     )
                 devices = devices[:n_devices]
         self.axis = axis

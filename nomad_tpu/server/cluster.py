@@ -2175,6 +2175,12 @@ class ClusterServer:
         return self.raft.is_leader()
 
     def start(self) -> None:
+        if self.server.tpu_worker is not None:
+            # -tpu-scheduler: take the device before serving anything. A
+            # server that cannot solve where it was told to refuses to
+            # start, instead of winning an election and accepting jobs
+            # its worker then fails to place.
+            self.server.tpu_worker.prepare()
         self.rpc.start()
         self.raft.start()
         self.serf.start()

@@ -17,6 +17,7 @@ from __future__ import annotations
 
 import logging
 import queue as queue_mod
+import sys
 import threading
 import time
 from concurrent.futures import CancelledError
@@ -38,13 +39,16 @@ DEQUEUE_TIMEOUT_S = 0.5
 def _retriable_device_error(e: BaseException) -> bool:
     """Classify a device-stage failure: retriable ⇒ the batch falls back
     to the host solve path (a sick device degrades throughput instead of
-    wedging the pipeline); terminal ⇒ the existing nack path. XLA
+    wedging the pipeline); terminal ⇒ the existing nack path. jax
     runtime errors (device OOM, halted chip, transfer failure) are
     retriable — the host oracle needs no device. Injected chaos faults
-    carry their own classification."""
+    carry their own classification. jax is reached through sys.modules:
+    a device error can only come from a process that already loaded it,
+    and the control plane never imports it itself."""
     if isinstance(e, faultplane.DeviceFault):
         return e.retriable
-    return type(e).__name__ == "XlaRuntimeError"
+    jax = sys.modules.get("jax")
+    return jax is not None and isinstance(e, jax.errors.JaxRuntimeError)
 
 
 class Backpressure:
@@ -351,8 +355,8 @@ class TPUBatchWorker:
         # Multi-chip (config.mesh_devices > 1): one ResidentClusterState
         # per worker, sharded over the mesh — resident tensors are
         # placed per-shard once and steady-state solves ship only usage
-        # deltas into the owning shard. Built lazily at the first solve
-        # (jax stays unloaded until the TPU path actually runs).
+        # deltas into the owning shard. Built by start(), which is where
+        # the device is resolved.
         self._resident = None
         # Solver-pool tier (server/solver_pool.py): when the cluster
         # attaches a tracker here, mega-batch drains dispatch to warm
@@ -364,46 +368,31 @@ class TPUBatchWorker:
         # Worker._run): a revoke window must throttle, not hot-loop.
         self._nl_backoff = WORKER_POLICY.backoff()
 
-    def _ensure_resident(self) -> None:
-        """Build the (possibly mesh-sharded) ResidentClusterState at the
-        first solve — jax stays unloaded until the TPU path actually
-        runs. A misconfigured mesh (NOMAD_TPU_MESH_DEVICES beyond what
-        the backend exposes) must NOT raise here: the exception would
-        nack and redeliver every eval forever — the cluster accepts
-        jobs but never places. Degrade loudly to single-chip instead,
-        and clear mesh_devices so the scheduler's _mesh_for doesn't
-        re-raise the same error per solve.
+    def prepare(self) -> None:
+        """Resolve the device and build the (possibly mesh-sharded)
+        ResidentClusterState. ClusterServer.start() and start() call
+        it: this is where a server loads jax and takes the chip, and a
+        backend that cannot serve the configuration — no accelerator
+        without an explicit JAX_PLATFORMS=cpu (device.resolve_device),
+        or mesh_devices beyond the backend's device count
+        (ResidentClusterState.for_config) — raises here, so the server refuses to
+        start instead of solving somewhere other than where it was told
+        to.
 
-        Single-chip workers get a plain ResidentClusterState too (new
-        with the interactive fast path): beyond the resident device
-        tensors it carries the WARM EVAL CONTEXT — the cached ready-node
-        lists, host-table skeleton, and lowered-group skeletons that let
-        a repeat-shaped interactive eval skip the node scan and lowering
-        entirely (solver.py)."""
+        Single-chip workers get a plain ResidentClusterState too:
+        beyond the resident device tensors it carries the WARM EVAL
+        CONTEXT — the cached ready-node lists, host-table skeleton, and
+        lowered-group skeletons that let a repeat-shaped interactive
+        eval skip the node scan and lowering entirely (solver.py)."""
         if self._resident is not None:
             return
-        from ..scheduler.tpu import ResidentClusterState
+        from ..scheduler.tpu import ResidentClusterState, resolve_device
 
-        if (getattr(self.config, "mesh_devices", 0) or 0) <= 1:
-            self._resident = ResidentClusterState()
-            return
-        from ..scheduler.tpu.sharding import solver_mesh
-
-        try:
-            self._resident = ResidentClusterState(
-                mesh=solver_mesh(self.config.mesh_devices)
-            )
-        except RuntimeError as exc:
-            logger.error(
-                "mesh_devices=%d unusable (%s); falling back to the "
-                "single-chip solver — fix NOMAD_TPU_MESH_DEVICES or "
-                "the backend's device count",
-                self.config.mesh_devices, exc,
-            )
-            self.config.mesh_devices = 0
-            self._resident = ResidentClusterState()
+        resolve_device()
+        self._resident = ResidentClusterState.for_config(self.config)
 
     def start(self) -> None:
+        self.prepare()
         # Fresh Event + queue per incarnation (see Worker.start).
         self._stop = threading.Event()
         self._commit_q = queue_mod.Queue(maxsize=1)
@@ -484,6 +473,10 @@ class TPUBatchWorker:
             "lane_ledger_len": len(self._lane_ledger),
             "submit_ewma_s": round(self.backpressure.submit_ewma_s, 6),
             "lane_priority": self.lane_priority,
+            "resident": (
+                self._resident.describe()
+                if self._resident is not None else None
+            ),
         }
 
     # -- solve stage ----------------------------------------------------
@@ -828,7 +821,7 @@ class TPUBatchWorker:
                     time.perf_counter() - t0,
                 )
                 return remote, snapshot, None
-        self._ensure_resident()
+        self.prepare()
         pending = solve_eval_batch_begin(
             snapshot, self.planner, evals, self.config, used_chain=chain,
             resident=self._resident,

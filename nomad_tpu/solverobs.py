@@ -73,6 +73,7 @@ class _Kernel:
     __slots__ = (
         "sigs", "compiles", "cache_hits", "steady_recompiles",
         "first_compile_ns", "steady_compile_ns", "last_sig", "evicted",
+        "platforms",
     )
 
     def __init__(self) -> None:
@@ -85,6 +86,10 @@ class _Kernel:
         self.steady_compile_ns = 0
         self.last_sig: Optional[tuple] = None
         self.evicted = 0
+        # platforms the outputs of this kernel's compiled programs were
+        # committed to (sampled at compile events): the proof a solve
+        # ran where the operator thinks it ran
+        self.platforms: set = set()
 
     def to_wire(self) -> dict:
         return {
@@ -98,6 +103,7 @@ class _Kernel:
             "last_signature": (
                 list(self.last_sig) if self.last_sig is not None else None
             ),
+            "platforms": sorted(self.platforms),
         }
 
 
@@ -181,6 +187,29 @@ class SolverObservatory:
             signature=str(signature),
         )
         return True
+
+    def record_outputs(self, kernel: str, out) -> None:
+        """Where a freshly compiled program put its results: the
+        platform of every device holding an output array (`out` is the
+        jit call's array or tuple of arrays). Called on compile events
+        only — an executable is bound to its devices."""
+        platforms = set()
+        for arr in out if isinstance(out, (tuple, list)) else (out,):
+            devices = getattr(arr, "devices", None)
+            if devices is not None:  # a custom solve_fn may return numpy
+                platforms.update(d.platform for d in devices())
+        with self._lock:
+            k = self._kernels.get(kernel)
+            if k is not None:
+                k.platforms |= platforms
+
+    def signatures(self) -> dict[str, list]:
+        """Every live ledger signature per kernel, in first-seen order
+        (the wire form carries only their count and the last one)."""
+        with self._lock:
+            return {
+                name: list(k.sigs) for name, k in self._kernels.items()
+            }
 
     def compiles(self, prefix: str = "") -> int:
         with self._lock:
@@ -393,7 +422,7 @@ def _install(obs: SolverObservatory) -> SolverObservatory:
     the test/bench isolation hook, mirroring metrics._install_registry."""
     global _global, record_call, record_batch, note_asks, note_table
     global record_transfer, record_shards, sample_device_memory, snapshot
-    global compiles, steady_recompiles
+    global compiles, steady_recompiles, record_outputs, signatures
     old = _global
     _global = obs
     record_call = obs.record_call
@@ -406,6 +435,8 @@ def _install(obs: SolverObservatory) -> SolverObservatory:
     snapshot = obs.snapshot
     compiles = obs.compiles
     steady_recompiles = obs.steady_recompiles
+    record_outputs = obs.record_outputs
+    signatures = obs.signatures
     return old
 
 
@@ -421,6 +452,8 @@ sample_device_memory = _global.sample_device_memory
 snapshot = _global.snapshot
 compiles = _global.compiles
 steady_recompiles = _global.steady_recompiles
+record_outputs = _global.record_outputs
+signatures = _global.signatures
 
 
 def timed_call(kernel: str, signature: tuple, fn, *args, **kwargs):
@@ -429,5 +462,6 @@ def timed_call(kernel: str, signature: tuple, fn, *args, **kwargs):
     is async and NOT awaited here) and records compile-vs-hit."""
     t0 = time.monotonic_ns()
     out = fn(*args, **kwargs)
-    record_call(kernel, signature, time.monotonic_ns() - t0)
+    if record_call(kernel, signature, time.monotonic_ns() - t0):
+        record_outputs(kernel, out)
     return out

@@ -111,9 +111,13 @@ class SimFleet:
         watch_period_s: float = 2.0,
         real_watchers: int = 0,
         latency_cap: int = 5000,
+        datacenters: tuple = ("dc1",),
     ) -> None:
         self.cluster = cluster
         self.size = size
+        # nodes are dealt round-robin over these (spread-constrained
+        # jobs need more than one)
+        self.datacenters = tuple(datacenters)
         self.hb_frac = hb_frac
         self.watch_period_s = watch_period_s
         self._rng = random.Random(seed ^ 0xF1EE7)
@@ -171,8 +175,10 @@ class SimFleet:
         Retry-After hint like real clients. True once ALL registered."""
         now = time.monotonic()
         with self._cv:
-            for _ in range(self.size):
-                sim = _SimNode(mock.node())
+            for i in range(self.size):
+                sim = _SimNode(mock.node(
+                    datacenter=self.datacenters[i % len(self.datacenters)]
+                ))
                 self._sims[sim.node.id] = sim
                 self._push_locked(now, sim.node.id, ACT_REGISTER)
             self._cv.notify_all()

@@ -24,8 +24,10 @@
 # through the production mesh path), failing if the sharded_scaling
 # gate (>= 0.7x linear) or the zero-full-reupload/recompile-bound
 # gates regress. Skip the sweep with SLOW_SUITE_NO_SHARDED=1 (e.g. on
-# a box mid-perf-capture, where a concurrent sweep would skew
-# BENCH_r0N numbers).
+# a box mid-perf-capture, where a concurrent sweep would skew the
+# capture). Every bench run here is a process of its own, one after
+# another — a chip belongs to one process at a time — and the sharded
+# sweep asks for the CPU itself (8 virtual devices).
 #
 # Exit code: nonzero on any pytest failure or sharded-gate failure.
 # Budget ~30+ minutes.
@@ -46,7 +48,6 @@ if [ "${SLOW_SUITE_NO_INTERACTIVE:-0}" != "1" ]; then
 import json, os, subprocess, sys
 
 env = dict(os.environ, BENCH_CONFIG="smoke_interactive")
-env.setdefault("BENCH_SKIP_TPU_PROBE", "1")
 proc = subprocess.run(
     [sys.executable, "bench.py"], env=env, capture_output=True, text=True
 )

@@ -439,23 +439,33 @@ def test_e2e_worker_mesh_path_sharded_observability(tmp_path, monkeypatch):
 
 
 @pytest.mark.multichip
-def test_worker_mesh_misconfig_degrades_to_single_chip():
-    """NOMAD_TPU_MESH_DEVICES beyond the backend's device count must
-    not wedge the solve loop (raise -> nack -> redeliver forever): the
-    worker logs the misconfig, clears mesh_devices, and builds a
-    single-chip resident so placement proceeds."""
+def test_worker_mesh_wider_than_backend_refuses_to_start():
+    """mesh_devices beyond the backend's device count is refused when
+    the worker starts — not degraded to a single chip at the first
+    solve, where a misconfigured server would go on accepting jobs it
+    solves somewhere other than where it was told to. A mesh that fits
+    starts and shards the resident state; prepare() is idempotent."""
+    import jax
+
     from nomad_tpu.scheduler.context import SchedulerConfig
+    from nomad_tpu.scheduler.tpu.scheduler import _mesh_for
     from nomad_tpu.server.worker import TPUBatchWorker
 
-    cfg = SchedulerConfig(backend="tpu", mesh_devices=1024)
-    worker = TPUBatchWorker(server=None, config=cfg)
-    worker._ensure_resident()
-    assert worker._resident is not None
-    assert worker._resident.mesh is None  # degraded, not sharded
-    assert cfg.mesh_devices == 0  # scheduler _mesh_for won't re-raise
-    # idempotent: a second solve keeps the built resident
+    wide = SchedulerConfig(backend="tpu", mesh_devices=jax.device_count() + 1)
+    worker = TPUBatchWorker(server=None, config=wide)
+    with pytest.raises(RuntimeError, match="NOMAD_TPU_MESH_DEVICES"):
+        worker.start()
+    assert worker._thread is None and worker._resident is None
+    assert wide.mesh_devices == jax.device_count() + 1  # nothing cleared
+    with pytest.raises(RuntimeError, match="NOMAD_TPU_MESH_DEVICES"):
+        _mesh_for(wide, None)  # the per-solve seam does not degrade either
+
+    fits = SchedulerConfig(backend="tpu", mesh_devices=jax.device_count())
+    worker = TPUBatchWorker(server=None, config=fits)
+    worker.prepare()
     resident = worker._resident
-    worker._ensure_resident()
+    assert resident.mesh.n_dev == jax.device_count()
+    worker.prepare()
     assert worker._resident is resident
 
 
