@@ -27,6 +27,7 @@ from typing import Callable, Optional
 from urllib.parse import parse_qs, urlparse
 
 from .. import codec, metrics
+from .. import trace as _trace
 from ..server.server import ConflictError
 from ..state.store import (
     TABLE_ALLOCS,
@@ -67,6 +68,16 @@ class HTTPError(Exception):
 
 
 _json_default = codec.json_default
+
+
+def _note_eval(eval_id):
+    """A job write's handler got an eval id back: record it on the
+    request's `http` trace, so `recorder().list(eval_id=...)` returns
+    the request's trace beside the eval's and its batch's."""
+    ctx = _trace.current()
+    if ctx is not None and isinstance(eval_id, str) and eval_id:
+        ctx.set_attr("eval_id", eval_id)
+    return eval_id
 
 
 class HTTPAgentServer:
@@ -408,7 +419,7 @@ class HTTPAgentServer:
 
         def jobs_register(p, q, body, tok):
             job = codec.from_wire(body["Job"])
-            return self.rpc_region("Job.register", {"job": job})
+            return _note_eval(self.rpc_region("Job.register", {"job": job}))
 
         def job_get(p, q, body, tok):
             ns = q.get("namespace", ["default"])[0]
@@ -425,10 +436,10 @@ class HTTPAgentServer:
         def job_delete(p, q, body, tok):
             ns = q.get("namespace", ["default"])[0]
             purge = q.get("purge", ["false"])[0] == "true"
-            return self.rpc_region(
+            return _note_eval(self.rpc_region(
                 "Job.deregister",
                 {"namespace": ns, "job_id": p["id"], "purge": purge},
-            )
+            ))
 
         def job_allocs(p, q, body, tok):
             ns = q.get("namespace", ["default"])[0]
@@ -1307,8 +1318,6 @@ class HTTPAgentServer:
             # /v1/traces: the tracing ring buffer (trace.py) — newest
             # first, filterable by eval/job id and trace name. Follows
             # the /v1/metrics pattern: agent-local observability surface.
-            from .. import trace as _trace
-
             try:
                 limit = int(q.get("limit", ["50"])[0])
             except ValueError:
@@ -1321,8 +1330,6 @@ class HTTPAgentServer:
             )
 
         def trace_get(p, q, body, tok):
-            from .. import trace as _trace
-
             t = _trace.recorder().get(p["id"])
             if t is None:
                 raise HTTPError(404, f"trace {p['id']} not found")
@@ -1464,8 +1471,6 @@ class HTTPAgentServer:
             # finished traces, expanded through the link graph so an
             # eval's timeline reaches its plan, allocs, and nodes.
             from .. import blackbox as _bb
-            from .. import trace as _trace
-
             kind = p["kind"]
             if kind not in _bb.TIMELINE_KINDS:
                 raise HTTPError(
@@ -2403,6 +2408,30 @@ class HTTPAgentServer:
                 logger.debug("http: " + fmt, *args)
 
             def _dispatch(self, method: str) -> None:
+                # Write requests open a trace when tracing is on, as
+                # soon as the request line and headers are read: the
+                # RPC fabric forwards the context, so a submit on a
+                # follower stitches through to the leader's raft apply
+                # (trace.py). `http.handle` is the server's side of the
+                # client's round trip: body read -> last byte written.
+                hctx = None
+                if method != "GET":
+                    hctx = _trace.start_trace(
+                        "http", method=method,
+                        path=self.path.partition("?")[0],
+                    )
+                if hctx is None:
+                    self._serve(method)
+                    return
+                try:
+                    with _trace.use(hctx), _trace.span(hctx, "http.handle"):
+                        self._serve(method)
+                finally:
+                    # a failed write must not be recorded as status=ok
+                    # — the surface exists to debug exactly these
+                    hctx.finish("error" if "error" in hctx.attrs else "ok")
+
+            def _serve(self, method: str) -> None:
                 parsed = urlparse(self.path)
                 query = parse_qs(parsed.query)
                 _REQ_REGION.set(query.get("region", [""])[0])
@@ -2514,8 +2543,7 @@ class HTTPAgentServer:
                         t0 = time.perf_counter()
                         try:
                             self._run_route(
-                                fn, match, query, raw_body, token, method,
-                                parsed,
+                                fn, match, query, raw_body, token
                             )
                         finally:
                             metrics.observe(
@@ -2557,57 +2585,46 @@ class HTTPAgentServer:
                     logger.exception("http handler failed")
                     self._reply(500, {"error": f"{type(e).__name__}: {e}"})
 
-            def _run_route(
-                self, fn, match, query, raw_body, token, method, parsed
-            ) -> None:
-                body = json.loads(raw_body or b"{}")
-                # Write requests open a trace when tracing is on:
-                # the RPC fabric forwards the context, so a
-                # submit on a follower stitches through to the
-                # leader's raft apply (trace.py).
-                hctx = None
-                if method != "GET":
-                    from .. import trace as _trace
-
-                    hctx = _trace.start_trace(
-                        "http", method=method, path=parsed.path
-                    )
-                if hctx is not None:
-                    try:
-                        with _trace.use(hctx):
-                            result = fn(
-                                match.groupdict(), query, body, token
-                            )
-                    except BaseException as e:
-                        # a failed write must not be recorded as
-                        # status=ok — the surface exists to debug
-                        # exactly these
-                        hctx.set_attr("error", type(e).__name__)
-                        hctx.finish("error")
-                        raise
-                    hctx.finish()
-                else:
+            def _run_route(self, fn, match, query, raw_body, token) -> None:
+                hctx = _trace.current()  # the request's (_dispatch)
+                # the body's JSON parse: one span a request — the body
+                # read before it and a handler's codec -> structs after
+                # it stay in `http.handle`'s own time
+                with _trace.span(hctx, "http.decode"):
+                    body = json.loads(raw_body or b"{}")
+                try:
                     result = fn(match.groupdict(), query, body, token)
+                except BaseException as e:
+                    if hctx is not None:
+                        hctx.set_attr("error", type(e).__name__)
+                    raise
                 index = None
                 if isinstance(result, tuple):
                     result, index = result
                 if isinstance(result, RawResponse):
-                    self.send_response(200)
-                    self.send_header(
-                        "Content-Type", result.content_type
-                    )
-                    self.send_header(
-                        "Content-Length", str(len(result.data))
-                    )
-                    self.end_headers()
-                    self.wfile.write(result.data)
+                    with _trace.span(hctx, "http.reply"):
+                        self.send_response(200)
+                        self.send_header(
+                            "Content-Type", result.content_type
+                        )
+                        self.send_header(
+                            "Content-Length", str(len(result.data))
+                        )
+                        self.end_headers()
+                        self.wfile.write(result.data)
                     return
-                self._reply(200, codec.to_wire(result), index)
+                with _trace.span(hctx, "http.reply"):
+                    self._reply(200, codec.to_wire(result), index)
 
             def _reply(self, status: int, payload,
                        index: Optional[int] = None,
                        retry_after: Optional[float] = None):
                 data = json.dumps(payload, default=_json_default).encode()
+                if status >= 400:
+                    hctx = _trace.current()
+                    if hctx is not None:
+                        # rejected before or by the handler: not "ok"
+                        hctx.attrs.setdefault("error", f"http {status}")
                 self.send_response(status)
                 self.send_header("Content-Type", "application/json")
                 if retry_after is not None:

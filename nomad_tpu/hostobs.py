@@ -478,6 +478,12 @@ class HostProfiler:
         else:
             self.gc_dropped += 1
         self.gc_collected += int(info.get("collected", 0))
+        # onto the collecting thread's trace, if it runs under one: a
+        # pause inside solve.dispatch is `gc.pause`, not self time
+        # (lock-free like the append above; one flag test when off)
+        from . import trace
+
+        trace.stage_attrs("gc.pause", dt, generation=gen)
 
     def note_gc_section(self, dur_ns: int) -> None:
         """gctune.paused_gc outermost-exit hook: how long the collector

@@ -17,7 +17,6 @@ Two operating modes:
 from __future__ import annotations
 
 import logging
-import time
 from typing import Optional
 
 from ... import solverobs, trace
@@ -346,10 +345,10 @@ class PendingEvalBatch:
         # preemption to the plans a second time.
         if not self._finished:
             outcome = self._pending.finish()
-            with paused_gc():
-                t0 = time.monotonic_ns()
+            with paused_gc(), trace.span(
+                trace.current(), "plan.assemble", cpu=True
+            ):
                 _attach_outcome(self.state, self.evals, self.plans, outcome)
-                trace.stage("plan.assemble", time.monotonic_ns() - t0)
             self._finished = True
         return self.plans
 
@@ -384,9 +383,8 @@ class PendingEvalBatch:
         solver = BatchSolver(self.state, cfg)
         with paused_gc():
             outcome = solver.solve(self._asks)
-            t0 = time.monotonic_ns()
-            _attach_outcome(self.state, self.evals, self.plans, outcome)
-            trace.stage("plan.assemble", time.monotonic_ns() - t0)
+            with trace.span(trace.current(), "plan.assemble", cpu=True):
+                _attach_outcome(self.state, self.evals, self.plans, outcome)
         self._solver = solver
         self._finished = True
         return self.plans
@@ -413,9 +411,10 @@ def solve_eval_batch_begin(
     lane placements the chain tensor never saw."""
     config = config or SchedulerConfig()
     with paused_gc():
-        t0 = time.monotonic_ns()
-        plans, asks = _reconcile_eval_batch(state, planner, evals, config)
-        trace.stage("reconcile", time.monotonic_ns() - t0)
+        with trace.span(trace.current(), "reconcile", cpu=True):
+            plans, asks = _reconcile_eval_batch(
+                state, planner, evals, config
+            )
         # asks-per-batch telemetry: how much work one solver dispatch
         # carries (occupancy's numerator lives solver-side; this is the
         # demand side the broker drained into the batch)

@@ -21,7 +21,7 @@ from __future__ import annotations
 import logging
 import time
 from dataclasses import dataclass, field, fields as dataclass_fields
-from typing import Optional
+from typing import NamedTuple, Optional
 
 import numpy as np
 
@@ -93,6 +93,23 @@ class SolveOutcome:
     # Per-ALLOC because one eval can mix host-path asks (sticky groups)
     # with dense-kernel asks in the same batch.
     pre_appended: set = field(default_factory=set)
+
+
+class _Lowered(NamedTuple):
+    """BatchSolver._lower_batch's payload for the dense paths."""
+
+    nodes: list
+    table: object
+    groups: list
+    base_of: dict  # group idx -> unrestricted base (spread retry)
+    usage_of: object  # None off the usage-aggregate path
+    adj: dict
+    total_requests: int
+    used: np.ndarray
+    tier_limit: np.ndarray
+    use_preempt: bool
+    compact: bool
+    micro: bool
 
 
 def _merge_outcomes(a: SolveOutcome, b: SolveOutcome) -> SolveOutcome:
@@ -628,6 +645,7 @@ class BatchSolver:
         # (set when the resident host-table path produced this solve's
         # table; None disables the cache for the solve)
         self._lower_vers = None
+        self._lower_cache_hits = 0  # lowered-skeleton hits, this solve
         # (node_ids tuple, used_dev) — the PREVIOUS batch's post-solve
         # usage tensor, still on device. While that batch's commit is in
         # flight, the committed aggregate hasn't caught up, so a
@@ -728,283 +746,42 @@ class BatchSolver:
 
     def _solve_gen(self, asks: list[GroupAsk]):
         out = SolveOutcome()
-        self._batch_has_cores = any(
-            t.resources.cores > 0
-            for ask in asks
-            for tg in [ask.job.lookup_task_group(ask.tg_name)]
-            if tg is not None
-            for t in tg.tasks
-        )
         self._outcome = out
+        self._batch_has_cores = False
         if not asks:
             return out
-        # Asks needing per-request node preference — sticky-disk
-        # replacements (prefer the previous node) and reschedules with a
-        # node penalty (avoid it) — take the host path; the dense kernel
-        # only expresses per-GROUP bias. The rest of the batch solves
-        # dense, with the host partition's placements counted against
-        # node capacity. A custom solve_fn keeps the whole batch (its
-        # topology logic must not be bypassed; preference degrades to
-        # none there).
-        if self.solve_fn is solve_placement:
-            from ..reconcile import PlacementRun
-
-            sticky_idx = set()
-            for i, ask in enumerate(asks):
-                if isinstance(ask.requests, PlacementRun):
-                    # shared-proto fresh fills carry no previous alloc
-                    # or penalty node by construction — and iterating
-                    # the run here would mint every row it exists to
-                    # avoid
-                    continue
-                tg = ask.job.lookup_task_group(ask.tg_name)
-                sticky = (
-                    tg is not None
-                    and tg.ephemeral_disk.sticky
-                    and any(r.previous_alloc is not None for r in ask.requests)
-                )
-                if sticky or any(r.penalty_node for r in ask.requests):
-                    sticky_idx.add(i)
-            if sticky_idx:
-                sticky_asks = [a for i, a in enumerate(asks) if i in sticky_idx]
-                host_out = self._solve_host(sticky_asks)
-                rest = [a for i, a in enumerate(asks) if i not in sticky_idx]
-                if not rest:
-                    return host_out
-                # the rest-solve must see the host partition's results:
-                # its placements consume capacity; its plans feed the
-                # host fast path's cross-eval accounting
-                self._partition_placed = [
-                    a
-                    for allocs_ in host_out.placements.values()
-                    for a in allocs_
-                ]
-                self._partition_plans = [
-                    a.plan for a in sticky_asks if a.plan is not None
-                ]
-                try:
-                    dense_out = self.solve(rest)
-                finally:
-                    self._partition_placed = []
-                    self._partition_plans = []
-                return _merge_outcomes(host_out, dense_out)
-        total_requests = sum(len(a.requests) for a in asks)
-        # A custom solve_fn (e.g. the mesh-sharded solver) must never be
-        # silently bypassed — the fast path exists for the default kernel's
-        # device round-trip only (same precedent as the compact path).
-        small = (
-            total_requests <= self.config.small_batch_threshold
-            and self.solve_fn is solve_placement
-        )
-        # Small batches prefer the MICROSOLVE: the dense pipeline with
-        # the numpy kernel (microsolve.py) — zero device round-trip,
-        # shared lowering/materialization semantics. Ineligible shapes
-        # (cores asks, a preemption-capable batch, a sharded mesh, or a
-        # node universe past the n·g threshold) fall back to the host
-        # iterator stack exactly as before.
-        micro_wanted = (
-            small
-            and self.mesh is None
-            and self.config.micro_solve_threshold > 0
-            and not self._batch_has_cores
-        )
-        if small and not micro_wanted:
-            return self._solve_host_timed(asks, total_requests)
-        # Priority order: higher-priority jobs consume capacity first
-        # (mirrors the eval broker's priority dequeue).
-        asks = sorted(asks, key=lambda a: -a.job.priority)
-
-        # One node universe per batch. Union of the jobs' datacenters,
-        # scanning the node table once per DISTINCT dc set, not per ask —
-        # and skipping the union dict entirely in the common one-dc-set
-        # case (it was a million dict writes at c2m scale).
-        dc_cache: dict[tuple, list] = {}
-        for ask in asks:
-            key = tuple(ask.job.datacenters)
-            if key not in dc_cache:
-                if self.resident is not None:
-                    # warm node-list cache keyed by the nodes-table
-                    # index (ResidentClusterState.ready_nodes)
-                    dc_cache[key] = self.resident.ready_nodes(
-                        self.state, key
-                    )[0]
-                else:
-                    dc_cache[key] = ready_nodes_in_dcs(
-                        self.state, ask.job.datacenters
-                    )[0]
-        if len(dc_cache) == 1:
-            nodes = next(iter(dc_cache.values()))
-        else:
-            all_nodes = {}
-            for nodes_ in dc_cache.values():
-                for node in nodes_:
-                    all_nodes[node.id] = node
-            nodes = list(all_nodes.values())
-        if not nodes:
-            for ask in asks:
-                self._fail_all(out, ask, {})
+        kind, low = self._lower_batch(asks, out)
+        if kind == "done":
             return out
-
-        # Capacity freed by this batch's plans (stops/destructive updates)
-        # is usable: plan application re-verifies, so optimistic batching
-        # treats all batch stops as vacated (reference: the host oracle's
-        # ProposedAllocs does the same per plan, context.go:120).
-        stopped_ids: set[str] = set()
-        for ask in asks:
-            if ask.plan is not None:
-                for allocs_ in ask.plan.node_update.values():
-                    stopped_ids.update(a.id for a in allocs_)
-        # the materializer's per-node seeds (ports/devices/cores/cpu)
-        # must see the SAME vacated capacity as the dense table, or an
-        # in-place replacement of a full node can never materialize
-        self._stopped_ids = stopped_ids
-
-        placed_by_node: dict[str, list] = {}
-        for a in self._partition_placed:
-            placed_by_node.setdefault(a.node_id, []).append(a)
-
-        def live_allocs(nid: str):
-            return [
+        if kind == "host":
+            return self._solve_host_timed(*low)
+        if kind == "sticky":
+            sticky_idx = low
+            sticky_asks = [a for i, a in enumerate(asks) if i in sticky_idx]
+            host_out = self._solve_host(sticky_asks)
+            rest = [a for i, a in enumerate(asks) if i not in sticky_idx]
+            if not rest:
+                return host_out
+            # the rest-solve must see the host partition's results:
+            # its placements consume capacity; its plans feed the
+            # host fast path's cross-eval accounting
+            self._partition_placed = [
                 a
-                for a in self.state.allocs_by_node_terminal(nid, False)
-                if a.id not in stopped_ids
-            ] + placed_by_node.get(nid, [])
-
-        # Aggregate fast path: when the batch can neither preempt (no
-        # tier tensors needed) nor ask for dedicated cores (no core
-        # pools), per-node utilization comes straight from the store's
-        # incremental aggregate — O(nodes), not O(allocs) — with this
-        # batch's vacated stops and the host partition's placements
-        # applied as per-node adjustments.
-        preempt_possible = self.solve_preempt_fn is not None and any(
-            self.config.preemption_enabled(a.job.type) for a in asks
-        )
-        if preempt_possible and hasattr(self.state, "alloc_priority_tiers"):
-            # Exact O(1) refinement: preemption can only trigger when some
-            # committed alloc's priority sits PRIORITY_DELTA below a batch
-            # job's — the store's priority-count aggregate proves absence
-            # without walking allocs (the common all-priority-50 cluster).
-            maxprio = max(
-                a.job.priority
-                for a in asks
-                if self.config.preemption_enabled(a.job.type)
-            )
-            tiers = list(self.state.alloc_priority_tiers())
-            # same-batch host-partition placements are preemptible too
-            # (they're in the dense table's live view)
-            tiers.extend(
-                a.job.priority if a.job is not None else 50
-                for a in self._partition_placed
-            )
-            preempt_possible = any(
-                maxprio - p >= PRIORITY_DELTA for p in tiers
-            )
-        if micro_wanted and preempt_possible:
-            # preemption needs the tier kernel (or the host stack's
-            # per-request evict pass) — keep the host path for it
-            return self._solve_host_timed(asks, total_requests)
-        usage_of = None
-        if (
-            not self._batch_has_cores
-            and not preempt_possible
-            and hasattr(self.state, "node_usage")
-        ):
-            adj: dict[str, list[int]] = {}
-
-            def _adjust(nid: str, r, sign: int) -> None:
-                d = adj.get(nid)
-                if d is None:
-                    d = adj[nid] = [0, 0, 0]
-                d[0] += sign * r.cpu
-                d[1] += sign * r.memory_mb
-                d[2] += sign * r.disk_mb
-
-            for sid in stopped_ids:
-                stored = self.state.alloc_by_id(sid)
-                if stored is not None and not stored.terminal_status():
-                    _adjust(
-                        stored.node_id, stored.comparable_resources(), -1
-                    )
-            for a in self._partition_placed:
-                _adjust(a.node_id, a.comparable_resources(), +1)
-            if self.extra_usage:
-                # interactive-lane ledger (worker.py): placements the
-                # priority lane committed past the chain basis — deltas,
-                # so they compose with both the set-scatter and the
-                # chained-add paths below
-                for nid, vec in self.extra_usage.items():
-                    d = adj.get(nid)
-                    if d is None:
-                        d = adj[nid] = [0, 0, 0]
-                    d[0] += vec[0]
-                    d[1] += vec[1]
-                    d[2] += vec[2]
-            state_usage = self.state.node_usage
-            if adj:
-
-                def usage_of(nid: str):
-                    u = state_usage(nid)
-                    d = adj.get(nid)
-                    if d is None:
-                        return u
-                    return (u[0] + d[0], u[1] + d[1], u[2] + d[2])
-
-            else:
-                usage_of = state_usage
-
-        if self.resident is not None and usage_of is not None:
-            # cross-solve host-table cache: same fingerprint discipline
-            # as the resident device tensors (ResidentClusterState)
-            table = self.resident.host_table(nodes, live_allocs, usage_of)
-            # lowered-skeleton cache rides the same fingerprint: valid
-            # only for tables produced by this generation's skeleton
-            self._lower_vers = self.resident._host_vers
-        else:
-            table = build_node_table(nodes, live_allocs, usage_of=usage_of)
-
-        groups: list[LoweredGroup] = []
-        base_of: dict[int, LoweredGroup] = {}  # group idx -> unrestricted base
-        for ask in asks:
-            tg = ask.job.lookup_task_group(ask.tg_name)
-            if tg is None or not ask.requests:
-                continue
-            self.ctx.plan = ask.plan  # plan-aware distinct/property masks
-            grp = self._lower_group_cached(table, ask, tg)
-            for sub in self._split_for_spread(table, ask.job, tg, grp):
-                base_of[len(groups)] = grp
-                groups.append(sub)
-            self.ctx.plan = None
-        if not groups:
-            return out
-        out.groups = len(groups)
-
+                for allocs_ in host_out.placements.values()
+                for a in allocs_
+            ]
+            self._partition_plans = [
+                a.plan for a in sticky_asks if a.plan is not None
+            ]
+            try:
+                dense_out = self.solve(rest)
+            finally:
+                self._partition_placed = []
+                self._partition_plans = []
+            return _merge_outcomes(host_out, dense_out)
+        (nodes, table, groups, base_of, usage_of, adj, total_requests,
+         used, tier_limit, use_preempt, compact, micro) = low
         n = table.n
-        self._victimized: set[str] = set()
-        used = np.clip(table.used, 0, 2**31 - 1).astype(np.int32)
-
-        tier_limit = np.zeros(len(groups), dtype=np.int32)
-        for i, grp in enumerate(groups):
-            tier_limit[i] = self._tier_limit(table, grp)
-        use_preempt = (
-            bool(tier_limit.any()) and self.solve_preempt_fn is not None
-        )
-        # The compact readback contract covers the default single-chip
-        # kernel AND the mesh path (the sharded compact kernel emits the
-        # same [G, maxC] instance list); only the preemption kernels and
-        # custom solve_fns return the dense [G, N] assignment.
-        compact = not use_preempt and self.solve_fn is solve_placement
-        # Microsolve verdict (the interactive fast path): the numpy
-        # kernel replaces the device dispatch when the problem is tiny.
-        # Past the n·g bound the batch keeps its historical host-stack
-        # route — the lowering work above is wasted once, on the rare
-        # small-requests-huge-cluster shape.
-        micro = (
-            micro_wanted
-            and compact
-            and n * len(groups) <= self.config.micro_solve_threshold
-        )
-        if micro_wanted and not micro:
-            return self._solve_host_timed(asks, total_requests)
 
         t0 = now_ns()
         # Resident device tensors: valid only when the usage-aggregate
@@ -1119,16 +896,15 @@ class BatchSolver:
             if not micro:
                 inst, over, used_out = self._run_compact_finish(pending)
             free_base = table.cap - table.used
-            t_mat0 = now_ns()
-            leftovers = self._materialize_compact(
-                table, groups, inst, over, free_base
+            leftovers, mat_ns = self._timed_materialize(
+                self._materialize_compact,
+                table, groups, inst, over, free_base,
             )
-            mat_ns = now_ns() - t_mat0
         else:
             assign, assign_evict, used_out = self._run_kernel_finish(pending)
-            t_mat0 = now_ns()
-            leftovers = self._materialize(table, groups, assign, assign_evict)
-            mat_ns = now_ns() - t_mat0
+            leftovers, mat_ns = self._timed_materialize(
+                self._materialize, table, groups, assign, assign_evict
+            )
 
         # Fallback pass: spread is a soft preference — requests a
         # value-restricted sub-group could not place retry against the
@@ -1162,11 +938,10 @@ class BatchSolver:
                 inst2, over2, used_retry = self._run_micro(
                     table, retry, used2, sum(g.count for g in retry)
                 )
-                t_mat0 = now_ns()
-                leftovers2 = self._materialize_compact(
-                    table, retry, inst2, over2, table.cap - used2
+                leftovers2, mat2_ns = self._timed_materialize(
+                    self._materialize_compact,
+                    table, retry, inst2, over2, table.cap - used2,
                 )
-                mat_ns += now_ns() - t_mat0
             elif compact:
                 inst2, over2, used_retry = self._run_compact(
                     table, retry, used2
@@ -1180,18 +955,18 @@ class BatchSolver:
                 self.chain_out = (
                     tuple(node.id for node in nodes), used_retry
                 )
-                t_mat0 = now_ns()
-                leftovers2 = self._materialize_compact(
-                    table, retry, inst2, over2, table.cap - used2
+                leftovers2, mat2_ns = self._timed_materialize(
+                    self._materialize_compact,
+                    table, retry, inst2, over2, table.cap - used2,
                 )
-                mat_ns += now_ns() - t_mat0
             else:
                 assign2, _, _ = self._run_kernel(
                     table, retry, used2, use_preempt=False
                 )
-                t_mat0 = now_ns()
-                leftovers2 = self._materialize(table, retry, assign2, None)
-                mat_ns += now_ns() - t_mat0
+                leftovers2, mat2_ns = self._timed_materialize(
+                    self._materialize, table, retry, assign2, None
+                )
+            mat_ns += mat2_ns
             for gi, reqs in leftovers2.items():
                 grp = retry[gi]
                 key = (grp.key[0], grp.tg.name)
@@ -1211,9 +986,322 @@ class BatchSolver:
         # Alloc materialization joins the host_prep/device/readback stage
         # registry so the bench's breakdown covers the full commit half.
         metrics.time_ns("nomad.tpu.materialize_seconds", mat_ns)
-        trace.stage("materialize", mat_ns)
         metrics.observe("nomad.tpu.solve_groups", out.groups)
         return out
+
+    @staticmethod
+    def _timed_materialize(materialize, *args):
+        """One materialization pass under its `materialize` span:
+        (leftovers, the pass's ns for nomad.tpu.materialize_seconds)."""
+        t0 = now_ns()
+        with trace.span(trace.current(), "materialize", cpu=True):
+            leftovers = materialize(*args)
+        return leftovers, now_ns() - t0
+
+    def _lower_batch(self, asks: list[GroupAsk], out: SolveOutcome):
+        """Everything solve_begin does on the host BEFORE the batch's
+        path is chosen, under one span, `lower`: the sticky/penalty
+        scan, the small-batch verdict, then (children `lower.table` and
+        `lower.groups`) the node universe and node table, built or
+        refreshed from the resident cache, and every ask lowered to its
+        group tensors; last the path verdict. Returns (kind, payload),
+        which the caller acts on AFTER the span closed: ("dense",
+        _Lowered); ("done", None) — nothing to solve, `out` is final;
+        ("host", (asks, total_requests)) — the host iterator stack takes
+        the batch (past the microsolve's bound its lowering is wasted
+        once); ("sticky", indices of the asks that solve on the host,
+        the rest dense)."""
+        tctx = trace.current()
+        with trace.span(tctx, "lower", cpu=True):
+            self._batch_has_cores = any(
+                t.resources.cores > 0
+                for ask in asks
+                for tg in [ask.job.lookup_task_group(ask.tg_name)]
+                if tg is not None
+                for t in tg.tasks
+            )
+            # Asks needing per-request node preference — sticky-disk
+            # replacements (prefer the previous node) and reschedules
+            # with a node penalty (avoid it) — take the host path; the
+            # dense kernel only expresses per-GROUP bias. The rest of
+            # the batch solves dense, with the host partition's
+            # placements counted against node capacity. A custom
+            # solve_fn keeps the whole batch (its topology logic must
+            # not be bypassed; preference degrades to none there).
+            if self.solve_fn is solve_placement:
+                sticky_idx = self._sticky_asks(asks)
+                if sticky_idx:
+                    return "sticky", sticky_idx
+            total_requests = sum(len(a.requests) for a in asks)
+            # A custom solve_fn (e.g. the mesh-sharded solver) must
+            # never be silently bypassed — the fast path exists for the
+            # default kernel's device round-trip only (same precedent
+            # as the compact path).
+            small = (
+                total_requests <= self.config.small_batch_threshold
+                and self.solve_fn is solve_placement
+            )
+            # Small batches prefer the MICROSOLVE: the dense pipeline
+            # with the numpy kernel (microsolve.py) — zero device
+            # round-trip, shared lowering/materialization semantics.
+            # Ineligible shapes (cores asks, a preemption-capable
+            # batch, a sharded mesh, or a node universe past the n·g
+            # threshold) fall back to the host iterator stack exactly
+            # as before.
+            micro_wanted = (
+                small
+                and self.mesh is None
+                and self.config.micro_solve_threshold > 0
+                and not self._batch_has_cores
+            )
+            if small and not micro_wanted:
+                return "host", (asks, total_requests)
+            # Priority order: higher-priority jobs consume capacity
+            # first (mirrors the eval broker's priority dequeue).
+            asks = sorted(asks, key=lambda a: -a.job.priority)
+            to_host = "host", (asks, total_requests)
+
+            with trace.span(tctx, "lower.table") as tspan:
+                # One node universe per batch. Union of the jobs'
+                # datacenters, scanning the node table once per DISTINCT
+                # dc set, not per ask — and skipping the union dict
+                # entirely in the common one-dc-set case (it was a
+                # million dict writes at c2m scale).
+                dc_cache: dict[tuple, list] = {}
+                for ask in asks:
+                    key = tuple(ask.job.datacenters)
+                    if key not in dc_cache:
+                        if self.resident is not None:
+                            # warm node-list cache keyed by the nodes-
+                            # table index (ResidentClusterState.
+                            # ready_nodes)
+                            dc_cache[key] = self.resident.ready_nodes(
+                                self.state, key
+                            )[0]
+                        else:
+                            dc_cache[key] = ready_nodes_in_dcs(
+                                self.state, ask.job.datacenters
+                            )[0]
+                if len(dc_cache) == 1:
+                    nodes = next(iter(dc_cache.values()))
+                else:
+                    all_nodes = {}
+                    for nodes_ in dc_cache.values():
+                        for node in nodes_:
+                            all_nodes[node.id] = node
+                    nodes = list(all_nodes.values())
+                if not nodes:
+                    for ask in asks:
+                        self._fail_all(out, ask, {})
+                    return "done", None
+                tab = self._lower_table(nodes, asks, micro_wanted)
+                if tab is None:
+                    return to_host
+                table, usage_of, adj = tab
+                tspan.set_attr("nodes", table.n)
+
+            with trace.span(tctx, "lower.groups") as gspan:
+                self._lower_cache_hits = 0
+                groups: list[LoweredGroup] = []
+                # group idx -> unrestricted base
+                base_of: dict[int, LoweredGroup] = {}
+                for ask in asks:
+                    tg = ask.job.lookup_task_group(ask.tg_name)
+                    if tg is None or not ask.requests:
+                        continue
+                    # plan-aware distinct/property masks
+                    self.ctx.plan = ask.plan
+                    grp = self._lower_group_cached(table, ask, tg)
+                    for sub in self._split_for_spread(
+                        table, ask.job, tg, grp
+                    ):
+                        base_of[len(groups)] = grp
+                        groups.append(sub)
+                    self.ctx.plan = None
+                gspan.set_attr("groups", len(groups))
+                gspan.set_attr("cache_hits", self._lower_cache_hits)
+            if not groups:
+                return "done", None
+            out.groups = len(groups)
+
+            self._victimized: set[str] = set()
+            used = np.clip(table.used, 0, 2**31 - 1).astype(np.int32)
+
+            tier_limit = np.zeros(len(groups), dtype=np.int32)
+            for i, grp in enumerate(groups):
+                tier_limit[i] = self._tier_limit(table, grp)
+            use_preempt = (
+                bool(tier_limit.any()) and self.solve_preempt_fn is not None
+            )
+            # The compact readback contract covers the default single-
+            # chip kernel AND the mesh path (the sharded compact kernel
+            # emits the same [G, maxC] instance list); only the
+            # preemption kernels and custom solve_fns return the dense
+            # [G, N] assignment.
+            compact = not use_preempt and self.solve_fn is solve_placement
+            # Microsolve verdict (the interactive fast path): the numpy
+            # kernel replaces the device dispatch when the problem is
+            # tiny. Past the n·g bound the batch keeps its historical
+            # host-stack route — the lowering work above is wasted once,
+            # on the rare small-requests-huge-cluster shape.
+            micro = (
+                micro_wanted
+                and compact
+                and table.n * len(groups) <= self.config.micro_solve_threshold
+            )
+            if micro_wanted and not micro:
+                return to_host
+            return "dense", _Lowered(
+                nodes, table, groups, base_of, usage_of, adj,
+                total_requests, used, tier_limit, use_preempt, compact,
+                micro,
+            )
+
+    @staticmethod
+    def _sticky_asks(asks: list[GroupAsk]) -> set:
+        """Indices of the asks that need per-request node preference."""
+        from ..reconcile import PlacementRun
+
+        sticky_idx = set()
+        for i, ask in enumerate(asks):
+            if isinstance(ask.requests, PlacementRun):
+                # shared-proto fresh fills carry no previous alloc
+                # or penalty node by construction — and iterating
+                # the run here would mint every row it exists to
+                # avoid
+                continue
+            tg = ask.job.lookup_task_group(ask.tg_name)
+            sticky = (
+                tg is not None
+                and tg.ephemeral_disk.sticky
+                and any(r.previous_alloc is not None for r in ask.requests)
+            )
+            if sticky or any(r.penalty_node for r in ask.requests):
+                sticky_idx.add(i)
+        return sticky_idx
+
+    def _lower_table(self, nodes: list, asks: list[GroupAsk],
+                     micro_wanted: bool):
+        """`lower.table` over the batch's node universe: (table,
+        usage_of, adj) — or None: a small batch that may preempt, the
+        host stack's."""
+        # Capacity freed by this batch's plans (stops/destructive updates)
+        # is usable: plan application re-verifies, so optimistic batching
+        # treats all batch stops as vacated (reference: the host oracle's
+        # ProposedAllocs does the same per plan, context.go:120).
+        stopped_ids: set[str] = set()
+        for ask in asks:
+            if ask.plan is not None:
+                for allocs_ in ask.plan.node_update.values():
+                    stopped_ids.update(a.id for a in allocs_)
+        # the materializer's per-node seeds (ports/devices/cores/cpu)
+        # must see the SAME vacated capacity as the dense table, or an
+        # in-place replacement of a full node can never materialize
+        self._stopped_ids = stopped_ids
+
+        placed_by_node: dict[str, list] = {}
+        for a in self._partition_placed:
+            placed_by_node.setdefault(a.node_id, []).append(a)
+
+        def live_allocs(nid: str):
+            return [
+                a
+                for a in self.state.allocs_by_node_terminal(nid, False)
+                if a.id not in stopped_ids
+            ] + placed_by_node.get(nid, [])
+
+        # Aggregate fast path: when the batch can neither preempt (no
+        # tier tensors needed) nor ask for dedicated cores (no core
+        # pools), per-node utilization comes straight from the store's
+        # incremental aggregate — O(nodes), not O(allocs) — with this
+        # batch's vacated stops and the host partition's placements
+        # applied as per-node adjustments.
+        preempt_possible = self.solve_preempt_fn is not None and any(
+            self.config.preemption_enabled(a.job.type) for a in asks
+        )
+        if preempt_possible and hasattr(self.state, "alloc_priority_tiers"):
+            # Exact O(1) refinement: preemption can only trigger when some
+            # committed alloc's priority sits PRIORITY_DELTA below a batch
+            # job's — the store's priority-count aggregate proves absence
+            # without walking allocs (the common all-priority-50 cluster).
+            maxprio = max(
+                a.job.priority
+                for a in asks
+                if self.config.preemption_enabled(a.job.type)
+            )
+            tiers = list(self.state.alloc_priority_tiers())
+            # same-batch host-partition placements are preemptible too
+            # (they're in the dense table's live view)
+            tiers.extend(
+                a.job.priority if a.job is not None else 50
+                for a in self._partition_placed
+            )
+            preempt_possible = any(
+                maxprio - p >= PRIORITY_DELTA for p in tiers
+            )
+        if micro_wanted and preempt_possible:
+            # preemption needs the tier kernel (or the host stack's
+            # per-request evict pass) — keep the host path for it
+            return None
+        usage_of = None
+        adj: dict[str, list[int]] = {}
+        if (
+            not self._batch_has_cores
+            and not preempt_possible
+            and hasattr(self.state, "node_usage")
+        ):
+
+            def _adjust(nid: str, r, sign: int) -> None:
+                d = adj.get(nid)
+                if d is None:
+                    d = adj[nid] = [0, 0, 0]
+                d[0] += sign * r.cpu
+                d[1] += sign * r.memory_mb
+                d[2] += sign * r.disk_mb
+
+            for sid in stopped_ids:
+                stored = self.state.alloc_by_id(sid)
+                if stored is not None and not stored.terminal_status():
+                    _adjust(
+                        stored.node_id, stored.comparable_resources(), -1
+                    )
+            for a in self._partition_placed:
+                _adjust(a.node_id, a.comparable_resources(), +1)
+            if self.extra_usage:
+                # interactive-lane ledger (worker.py): placements the
+                # priority lane committed past the chain basis — deltas,
+                # so they compose with both the set-scatter and the
+                # chained-add paths below
+                for nid, vec in self.extra_usage.items():
+                    d = adj.get(nid)
+                    if d is None:
+                        d = adj[nid] = [0, 0, 0]
+                    d[0] += vec[0]
+                    d[1] += vec[1]
+                    d[2] += vec[2]
+            state_usage = self.state.node_usage
+            if adj:
+
+                def usage_of(nid: str):
+                    u = state_usage(nid)
+                    d = adj.get(nid)
+                    if d is None:
+                        return u
+                    return (u[0] + d[0], u[1] + d[1], u[2] + d[2])
+
+            else:
+                usage_of = state_usage
+
+        if self.resident is not None and usage_of is not None:
+            # cross-solve host-table cache: same fingerprint discipline
+            # as the resident device tensors (ResidentClusterState)
+            table = self.resident.host_table(nodes, live_allocs, usage_of)
+            # lowered-skeleton cache rides the same fingerprint: valid
+            # only for tables produced by this generation's skeleton
+            self._lower_vers = self.resident._host_vers
+        else:
+            table = build_node_table(nodes, live_allocs, usage_of=usage_of)
+        return table, usage_of, adj
 
     def _solve_host_timed(self, asks: list[GroupAsk],
                           total_requests: int) -> SolveOutcome:
@@ -1221,11 +1309,11 @@ class BatchSolver:
         from ... import metrics
 
         t0 = now_ns()
-        out = self._solve_host(asks)
+        with trace.span(trace.current(), "host_solve", cpu=True):
+            out = self._solve_host(asks)
         out.solve_ns = now_ns() - t0
         metrics.time_ns("nomad.tpu.solve_seconds", out.solve_ns)
         metrics.observe("nomad.tpu.small_batch_requests", total_requests)
-        trace.stage("host_solve", out.solve_ns)
         return out
 
     def _run_micro(self, table, groups: list[LoweredGroup], used_n,
@@ -1242,27 +1330,27 @@ class BatchSolver:
         t0 = now_ns()
         self.used_micro = True
         n = table.n
-        maxc = max(1, max(int(grp.count) for grp in groups)) if groups \
-            else 1
-        inst, over, used_out = solve_placement_compact_micro(
-            table.cap,
-            np.asarray(used_n)[:n],
-            [
-                (
-                    np.asarray(grp.ask, dtype=np.int64),
-                    int(grp.count),
-                    grp.feasible,
-                    grp.bias,
-                    np.asarray(grp.units_cap, dtype=np.int64),
-                )
-                for grp in groups
-            ],
-            maxc,
-        )
+        with trace.span(trace.current(), "micro_solve", cpu=True):
+            maxc = max(1, max(int(grp.count) for grp in groups)) \
+                if groups else 1
+            inst, over, used_out = solve_placement_compact_micro(
+                table.cap,
+                np.asarray(used_n)[:n],
+                [
+                    (
+                        np.asarray(grp.ask, dtype=np.int64),
+                        int(grp.count),
+                        grp.feasible,
+                        grp.bias,
+                        np.asarray(grp.units_cap, dtype=np.int64),
+                    )
+                    for grp in groups
+                ],
+                maxc,
+            )
         micro_ns = now_ns() - t0
         metrics.time_ns("nomad.tpu.micro_seconds", micro_ns)
         metrics.observe("nomad.tpu.micro_batch_requests", total_requests)
-        trace.stage("micro_solve", micro_ns)
         return inst, over, used_out
 
     def _lower_group_cached(self, table, ask: GroupAsk, tg) -> LoweredGroup:
@@ -1286,6 +1374,7 @@ class BatchSolver:
         if cached is not None:
             from .lower import request_names
 
+            self._lower_cache_hits += 1
             ask_vec, feas, bias, ucap, fdims = cached
             sb = spread_bias(self.ctx, table, ask.job, tg)
             if sb is not None:
@@ -1596,18 +1685,23 @@ class BatchSolver:
         from ... import metrics
 
         t_prep0 = now_ns()
+        with trace.span(trace.current(), "host_prep", cpu=True):
+            pending = self._compact_dispatch(
+                table, groups, used_n, dev_state
+            )
+        metrics.time_ns("nomad.tpu.host_prep_seconds", now_ns() - t_prep0)
+        return pending
+
+    def _compact_dispatch(self, table, groups, used_n, dev_state):
+        """_run_compact_async's body: pack, dedupe, upload, queue."""
         n, g = table.n, len(groups)
         np_, gp, cap, used, asks_arr, counts = self._lower_small(table, groups)
         used[:n] = used_n[:n]
         if self.mesh is not None:
-            pending = self._dispatch_mesh_compact(
+            return self._dispatch_mesh_compact(
                 table, groups, np_, gp, cap, used, asks_arr, counts,
                 dev_state,
             )
-            prep_ns = now_ns() - t_prep0
-            metrics.time_ns("nomad.tpu.host_prep_seconds", prep_ns)
-            trace.stage("host_prep", prep_ns)
-            return pending
         feas_rows, feas_idx = self._dedupe_rows(
             [grp.feasible for grp in groups], gp, np_, np.bool_
         )
@@ -1668,9 +1762,6 @@ class BatchSolver:
             ucap_idx,
             max_count=maxc,
         )
-        prep_ns = now_ns() - t_prep0
-        metrics.time_ns("nomad.tpu.host_prep_seconds", prep_ns)
-        trace.stage("host_prep", prep_ns)
         return inst, over, used_out, g, n, time.perf_counter()
 
     def _dispatch_mesh_compact(

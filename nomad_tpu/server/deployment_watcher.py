@@ -18,6 +18,7 @@ import logging
 import threading
 from typing import Optional
 
+from .. import metrics, trace
 from ..structs import Evaluation, generate_uuid, now_ns
 from ..structs.structs import (
     DEPLOYMENT_STATUS_FAILED,
@@ -111,19 +112,35 @@ class DeploymentsWatcher:
     # -- the reconciliation pass ---------------------------------------
 
     def run_once(self) -> int:
-        """Judge every active deployment. Returns number acted upon."""
-        acted = 0
-        for d in self.state.deployments():
-            if d.status == DEPLOYMENT_STATUS_SUCCESSFUL:
-                # A deployment may be completed by the reconciler's plan
-                # (deployment_updates in the committed plan) rather than by
-                # this watcher — job stability still must follow.
-                self._mark_job_stable(d)
-                continue
-            if not d.active() or d.status == "paused":
-                continue
-            if self._judge(d):
-                acted += 1
+        """Judge every active deployment. Returns number acted upon.
+
+        One pass is one `deploywatch.pass` trace (when tracing is on),
+        and always one observation of the deployments it judged — the
+        pass is O(active deployments) and shares the interpreter with
+        the solve and commit threads."""
+        tctx = trace.start_trace("deploywatch.pass", cpu=True)
+        acted = scanned = 0
+        try:
+            with trace.use(tctx):
+                for d in self.state.deployments():
+                    if d.status == DEPLOYMENT_STATUS_SUCCESSFUL:
+                        # A deployment may be completed by the
+                        # reconciler's plan (deployment_updates in the
+                        # committed plan) rather than by this watcher —
+                        # job stability still must follow.
+                        self._mark_job_stable(d)
+                        continue
+                    if not d.active() or d.status == "paused":
+                        continue
+                    scanned += 1
+                    if self._judge(d):
+                        acted += 1
+        finally:
+            if tctx is not None:
+                tctx.set_attr("scanned", scanned)
+                tctx.set_attr("acted", acted)
+                tctx.finish()
+            metrics.observe("nomad.deploywatch.scanned", scanned)
         return acted
 
     def _judge(self, d: Deployment) -> bool:

@@ -711,7 +711,7 @@ class PlanApplier:
         # the process-wide collector off (the raft/store paths pause
         # around their own bursts).
         with paused_gc():
-            with trace.span(tctx, "plan.verify", parent=tparent):
+            with trace.span(tctx, "plan.verify", parent=tparent, cpu=True):
                 result = evaluate_plan(snapshot, plan)
             if result.is_no_op():
                 fut.set_result(result)
@@ -841,7 +841,7 @@ class PlanApplier:
         to_commit: list[tuple[int, PlanResult]] = []
         with paused_gc():
             with trace.span(
-                tctx, "plan.verify", parent=tparent,
+                tctx, "plan.verify", parent=tparent, cpu=True,
                 round=round_no, plans=len(merged_idx),
             ):
                 for i in merged_idx:

@@ -329,21 +329,17 @@ class InmemLog:
         from .. import codec, metrics
         import time as _time
 
-        tracing = trace.enabled() and trace.current() is not None
+        tctx = trace.current()
         apply_t0 = _time.monotonic_ns()
         with paused_gc():
-            t0 = _time.monotonic_ns() if tracing else 0
-            raw = codec.pack(payload)
-            if tracing:
-                trace.stage("raft.encode", _time.monotonic_ns() - t0)
+            with trace.span(tctx, "raft.encode", cpu=True):
+                raw = codec.pack(payload)
             with self._lock:
                 self._index += 1
                 index = self._index
                 self._entries.append((index, msg_type, raw))
-            t0 = _time.monotonic_ns() if tracing else 0
-            self.fsm.apply(index, msg_type, payload)
-            if tracing:
-                trace.stage("fsm.apply", _time.monotonic_ns() - t0)
+            with trace.span(tctx, "fsm.apply", cpu=True):
+                self.fsm.apply(index, msg_type, payload)
         # one observation per raft entry (entries batch many payloads,
         # so this is far off the per-alloc hot loop): encode + append +
         # fsm apply — the commit half of every state mutation

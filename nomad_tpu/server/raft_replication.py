@@ -30,7 +30,7 @@ import time
 from dataclasses import dataclass
 from typing import Callable, Optional
 
-from .. import codec, metrics
+from .. import codec, metrics, trace
 from ..gctune import paused_gc
 from ..rpc import ConnPool
 from .raft import FSM
@@ -196,7 +196,8 @@ class RaftNode:
         # keyed by (index, term) so a deposed leader's truncated indexes
         # can never resolve to a stale payload; the stash clears on
         # step-down.
-        self._direct_payloads: dict[int, tuple[int, object]] = {}
+        # index -> (term, payload, submitter's trace ref or None)
+        self._direct_payloads: dict[int, tuple] = {}
 
         self._stop = threading.Event()
         self._threads: list[threading.Thread] = []
@@ -316,8 +317,12 @@ class RaftNode:
         # Encode OUTSIDE the lock: packing a large plan payload under
         # _lock would stall the replication loops' heartbeats and get the
         # leader deposed. The bytes depend only on the payload.
-        with paused_gc():
+        tctx = trace.current()
+        with paused_gc(), trace.span(tctx, "raft.encode", cpu=True):
             raw = codec.pack(payload)
+        # the apply thread records its `fsm.apply` on the submitter's
+        # trace, under the span the submitter has open (its raft.apply)
+        tref = (tctx, tctx.active_span()) if tctx is not None else None
         with self._lock:
             if self.state != LEADER:
                 raise NotLeaderError(self.leader_addr())
@@ -334,7 +339,7 @@ class RaftNode:
                     # entry this node forgets on restart.
                     self._log.pop()
                     raise
-            self._direct_payloads[index] = (term, payload)
+            self._direct_payloads[index] = (term, payload, tref)
             self._match_index[self.node_id] = index
             for ev in self._repl_wake.values():
                 ev.set()
@@ -810,11 +815,17 @@ class RaftNode:
                             # match); anything else decodes fresh — the FSM
                             # (and through it the state store) owns applied
                             # structs outright either way.
+                            tctx = tparent = None
                             if direct is not None and direct[0] == e.term:
                                 payload = direct[1]
+                                if direct[2] is not None:
+                                    tctx, tparent = direct[2]
                             else:
                                 payload = codec.unpack(e.payload)
-                            self.fsm.apply(e.index, e.msg_type, payload)
+                            with trace.use(tctx), trace.span(
+                                tctx, "fsm.apply", parent=tparent, cpu=True
+                            ):
+                                self.fsm.apply(e.index, e.msg_type, payload)
                         except Exception:
                             logger.exception(
                                 "%s: FSM apply failed at %d",

@@ -664,31 +664,35 @@ class Server:
 
     def job_register(self, job: Job) -> str:
         """Returns the created eval id (reference job_endpoint.go:80)."""
-        self.check_eval_admission(job.namespace)
-        job = self.validate_job_submission(job)
-        self._ensure_namespace(job.namespace)
-        if job.is_periodic():
-            # A malformed cron spec must be rejected at the API, not fire
-            # wild from the dispatcher (reference periodic.go Add validates).
-            import time as _time
+        # one span over admission -> validate -> raft apply (the FSM
+        # enqueues the eval under it): the handler's work of a register
+        with trace.span(trace.current(), "job.register"):
+            self.check_eval_admission(job.namespace)
+            job = self.validate_job_submission(job)
+            self._ensure_namespace(job.namespace)
+            if job.is_periodic():
+                # A malformed cron spec must be rejected at the API, not
+                # fire wild from the dispatcher (reference periodic.go
+                # Add validates).
+                import time as _time
 
-            from .periodic import next_launch
+                from .periodic import next_launch
 
-            next_launch(job.periodic, _time.time())
-        ev = None
-        if not job.is_periodic() and not job.is_parameterized():
-            ev = Evaluation(
-                id=generate_uuid(),
-                namespace=job.namespace,
-                priority=job.priority,
-                type=job.type,
-                triggered_by=EVAL_TRIGGER_JOB_REGISTER,
-                job_id=job.id,
-                status=EVAL_STATUS_PENDING,
-                create_time=now_ns(),
-                modify_time=now_ns(),
-            )
-        self.raft_apply("job_register", (job, ev))
+                next_launch(job.periodic, _time.time())
+            ev = None
+            if not job.is_periodic() and not job.is_parameterized():
+                ev = Evaluation(
+                    id=generate_uuid(),
+                    namespace=job.namespace,
+                    priority=job.priority,
+                    type=job.type,
+                    triggered_by=EVAL_TRIGGER_JOB_REGISTER,
+                    job_id=job.id,
+                    status=EVAL_STATUS_PENDING,
+                    create_time=now_ns(),
+                    modify_time=now_ns(),
+                )
+            self.raft_apply("job_register", (job, ev))
         return ev.id if ev else ""
 
     # -- namespace endpoint --------------------------------------------

@@ -39,6 +39,7 @@ from collections import deque
 from typing import Optional
 
 from .. import metrics
+from ..trace import now_ns
 
 DEFAULT_INBOX_CAP = 4096
 DEFAULT_MAX_WAITERS_PER_NODE = 4
@@ -91,7 +92,9 @@ class AllocWatchHub:
                 if index > self._overflow_index:
                     self._overflow_index = index
             else:
-                self._inbox.append((index, node_ids))
+                # stamped here, observed in _drain: the store write to
+                # its nodes' waiters woken (nomad.watch.route_seconds)
+                self._inbox.append((index, node_ids, now_ns()))
         self._wake.set()
 
     def prime(self, index: int, node_ids: set) -> None:
@@ -126,7 +129,7 @@ class AllocWatchHub:
             return
         woken = 0
         with self._lock:
-            for index, node_ids in batch:
+            for index, node_ids, _t_write in batch:
                 for node_id in node_ids:
                     if index > self._node_index.get(node_id, 0):
                         self._node_index[node_id] = index
@@ -138,6 +141,11 @@ class AllocWatchHub:
                     if overflow > self._node_index[node_id]:
                         self._node_index[node_id] = overflow
                     woken += self._wake_waiters(node_id, overflow)
+        t_routed = now_ns()
+        for _index, _node_ids, t_write in batch:
+            metrics.observe(
+                "nomad.watch.route_seconds", (t_routed - t_write) / 1e9
+            )
         if overflow:
             metrics.incr("nomad.fleet.fanout_overflow")
         if woken:

@@ -441,7 +441,7 @@ class TPUBatchWorker:
                 break
             if item is not None:
                 (batch, _pending, _snapshot, committed, outcome,
-                 _chain, bctx, _t_deq) = item
+                 _chain, bctx, _t_deq, _t_put) = item
                 self._nack_batch(batch)
                 outcome["ok"] = False
                 committed.set()
@@ -491,6 +491,7 @@ class TPUBatchWorker:
 
     def _run(self, stop: threading.Event) -> None:
         broker = self.server.eval_broker
+        idle_since: Optional[int] = None  # trace.now_ns() at going idle
         while not stop.is_set():
             # Drop the previous batch's PendingEvalBatch once its commit
             # lands: on an idle worker it would otherwise pin the solved
@@ -524,15 +525,22 @@ class TPUBatchWorker:
                 ev, token, t_deq = self._held
                 self._held = None
             else:
+                # one idle stretch = one `worker.idle` span: the stamp
+                # survives dequeues that time out empty and is cleared
+                # only when an eval arrives
+                if idle_since is None:
+                    idle_since = trace.now_ns()
                 ev, token = broker.dequeue(
                     self.schedulers, timeout_s=DEQUEUE_TIMEOUT_S
                 )
             if ev is None:
                 continue
+            idle = None
             if t_deq is None:
-                t_deq = time.perf_counter()
+                t_deq = trace.now_ns()
+                idle, idle_since = (idle_since, t_deq), None
             if self._interactive(ev):
-                self._run_interactive(ev, token, t_deq)
+                self._run_interactive(ev, token, t_deq, idle)
                 continue
             batch.append((ev, token))
             # Effective batch size under backpressure: plan-queue depth
@@ -548,6 +556,8 @@ class TPUBatchWorker:
             # across the whole batch, so duplicating them per eval would
             # multiply span volume by batch_size for no information.
             bctx = trace.start_trace("tpu.batch")
+            if bctx is not None and idle is not None:
+                bctx.add_span("worker.idle", *idle)
             with trace.span(bctx, "broker.drain"):
                 # opportunistically drain more ready evals without waiting
                 while len(batch) < limit:
@@ -561,7 +571,7 @@ class TPUBatchWorker:
                         # is never baked into this mega-batch — it jumps
                         # the line as its own solve next cycle (held
                         # with its lane clock already running)
-                        self._held = (ev2, token2, time.perf_counter())
+                        self._held = (ev2, token2, trace.now_ns())
                         metrics.incr("nomad.worker.lane.drain_preempted")
                         break
                     batch.append((ev2, token2))
@@ -605,7 +615,9 @@ class TPUBatchWorker:
                 try:
                     self._commit_q.put(
                         (batch, pending, snapshot, committed,
-                         outcome, chained_on, bctx, t_deq),
+                         outcome, chained_on, bctx, t_deq,
+                         # `commit.queue` starts here (_commit_loop)
+                         trace.now_ns() if bctx is not None else 0),
                         timeout=0.2,
                     )
                     handed_off = True
@@ -630,8 +642,8 @@ class TPUBatchWorker:
                 basis = chained_on[1] if chained_on else snapshot.index
                 self._prev = (pending, committed, outcome, basis)
 
-    def _run_interactive(self, ev: Evaluation, token: str,
-                         t_deq: float) -> None:
+    def _run_interactive(self, ev: Evaluation, token: str, t_deq: int,
+                         idle: Optional[tuple] = None) -> None:
         """The interactive lane: solve one eval alone — no drain, no
         mega-batch — and commit INLINE on the solve thread, jumping
         ahead of the in-flight batch sitting in the commit queue. Small
@@ -644,6 +656,8 @@ class TPUBatchWorker:
         batch = [(ev, token)]
         bctx = trace.start_trace("tpu.interactive")
         if bctx is not None:
+            if idle is not None:
+                bctx.add_span("worker.idle", *idle)
             bctx.set_attr("eval_id", ev.id)
             bctx.set_attr("job_id", ev.job_id)
             self.server.eval_broker.annotate_trace(
@@ -793,7 +807,7 @@ class TPUBatchWorker:
                 # for unblocks from that index or a capacity event in the
                 # gap is treated as already seen and the eval strands.
                 chained_on = (prev_outcome, prev_basis)
-        t0 = time.perf_counter()
+        t0 = trace.now_ns()
         if faultplane.plane is not None:
             # injected dispatch-stage fault: surfaces through the solve
             # stage's existing failure path (nack + redeliver)
@@ -818,7 +832,7 @@ class TPUBatchWorker:
                 metrics.observe("nomad.tpu.batch_evals", len(evals))
                 metrics.observe(
                     "nomad.tpu.batch_dispatch_seconds",
-                    time.perf_counter() - t0,
+                    (trace.now_ns() - t0) / 1e9,
                 )
                 return remote, snapshot, None
         self.prepare()
@@ -840,7 +854,8 @@ class TPUBatchWorker:
             # landed: this now times ONLY phase A (reconcile + lower +
             # async dispatch) — device wait and materialization moved to
             # the commit stage's device/materialize/commit timers
-            "nomad.tpu.batch_dispatch_seconds", time.perf_counter() - t0
+            "nomad.tpu.batch_dispatch_seconds",
+            (trace.now_ns() - t0) / 1e9,
         )
         return pending, snapshot, chained_on
 
@@ -859,7 +874,9 @@ class TPUBatchWorker:
             if item is None:
                 return
             (batch, pending, snapshot, committed, outcome,
-             chained_on, bctx, t_deq) = item
+             chained_on, bctx, t_deq, t_put) = item
+            if bctx is not None:
+                bctx.add_span("commit.queue", t_put, trace.now_ns())
             try:
                 self._commit(
                     batch, pending, snapshot, committed, outcome,
@@ -887,7 +904,7 @@ class TPUBatchWorker:
 
     def _commit(
         self, batch, pending, snapshot, committed, outcome, chained_on,
-        bctx=None, lane: str = "batch", t_deq: Optional[float] = None,
+        bctx=None, lane: str = "batch", t_deq: Optional[int] = None,
     ) -> None:
         broker = self.server.eval_broker
         if chained_on is not None and chained_on[0].get("ok") is False:
@@ -935,7 +952,7 @@ class TPUBatchWorker:
                     ):
                         plans = pending.solve_host_fallback()
                     used_fallback = True
-                t0 = time.perf_counter()
+                t0 = trace.now_ns()
                 all_full = self._commit_batch(
                     [e for e, _ in batch], plans, snapshot,
                     blocked_basis=chained_on[1] if chained_on else None,
@@ -970,7 +987,7 @@ class TPUBatchWorker:
         # commit_seconds joins the solver's host_prep/device/readback/
         # materialize stage registry: the full commit half of the pipeline
         metrics.observe(
-            "nomad.tpu.commit_seconds", time.perf_counter() - t0
+            "nomad.tpu.commit_seconds", (trace.now_ns() - t0) / 1e9
         )
         if lane == "interactive":
             # lane-ledger record: an interactive commit that landed
@@ -987,7 +1004,7 @@ class TPUBatchWorker:
                     )
                     del self._lane_ledger[:-64]
         if t_deq is not None:
-            lane_dt = time.perf_counter() - t_deq
+            lane_dt = (trace.now_ns() - t_deq) / 1e9
             if lane == "interactive":
                 metrics.observe(
                     "nomad.worker.lane.interactive_seconds", lane_dt

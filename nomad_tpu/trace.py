@@ -9,7 +9,11 @@ raft apply without hand-wired stage timers.
 
 Design:
 
-  * ``Span`` — name, start/end (monotonic ns), parent link, attrs.
+  * ``Span`` — name, start/end (monotonic ns), parent link, attrs, and
+    for compute spans opened with ``cpu=True`` the thread's CPU time
+    between start and end (``cpu_ns``): wall minus cpu is what the
+    thread spent OFF the processor inside the span — blocked, or
+    waiting for the interpreter lock.
   * ``TraceContext`` — one trace: a root span plus children appended from
     any thread (per-context lock). A per-context *thread-local* active-
     span stack gives automatic parenting: ``ctx.span("x")`` nested inside
@@ -68,6 +72,7 @@ __all__ = [
 ]
 
 now_ns = time.monotonic_ns
+_thread_cpu_ns = time.thread_time_ns
 
 # module flag, read without a lock (GIL-atomic; flips are rare operator
 # actions — agent config / SIGHUP reload / tests)
@@ -110,7 +115,10 @@ def set_enabled(on: bool) -> None:
 
 
 class Span:
-    __slots__ = ("name", "span_id", "parent_id", "start_ns", "end_ns", "attrs")
+    __slots__ = (
+        "name", "span_id", "parent_id", "start_ns", "end_ns", "attrs",
+        "cpu_ns", "_cpu0",
+    )
 
     def __init__(
         self,
@@ -120,6 +128,7 @@ class Span:
         start_ns: int = 0,
         end_ns: int = 0,
         attrs: Optional[dict] = None,
+        cpu_ns: Optional[int] = None,
     ) -> None:
         self.name = name
         self.span_id = span_id
@@ -127,6 +136,10 @@ class Span:
         self.start_ns = start_ns
         self.end_ns = end_ns
         self.attrs = attrs
+        # thread CPU time between start and end; None for a span that is
+        # not a cpu span, or not closed yet (_cpu0: the clock at start)
+        self.cpu_ns = cpu_ns
+        self._cpu0: Optional[int] = None
 
     @property
     def duration_ns(self) -> int:
@@ -142,6 +155,8 @@ class Span:
         }
         if self.attrs:
             d["attrs"] = self.attrs
+        if self.cpu_ns is not None:
+            d["cpu"] = self.cpu_ns
         return d
 
     @staticmethod
@@ -153,6 +168,7 @@ class Span:
             int(d.get("start", 0)),
             int(d.get("end", 0)),
             d.get("attrs") or None,
+            d.get("cpu"),
         )
 
 
@@ -203,6 +219,28 @@ class _NoopSpan:
 NOOP_SPAN = _NoopSpan()
 
 
+# span name -> its two histogram names (built once per name, not per span)
+_cpu_metric_names: dict[str, tuple[str, str]] = {}
+
+
+def _observe_cpu(s: Span) -> None:
+    """A closed cpu span's wall time and its off-CPU part (wall - cpu:
+    blocked, or runnable and waiting for the interpreter lock), as
+    ``nomad.trace.wall_seconds.<span>`` / ``offcpu_seconds.<span>``."""
+    names = _cpu_metric_names.get(s.name)
+    if names is None:
+        names = _cpu_metric_names[s.name] = (
+            "nomad.trace.offcpu_seconds." + s.name,
+            "nomad.trace.wall_seconds." + s.name,
+        )
+    from . import metrics  # deferred: metrics' registry imports hostobs
+
+    wall = s.end_ns - s.start_ns
+    s._cpu0 = None  # observed: finish() and a straggler's end both ask
+    metrics.observe(names[0], (wall - s.cpu_ns) / 1e9)
+    metrics.observe(names[1], wall / 1e9)
+
+
 class TraceContext:
     """One trace: a root span plus concurrently-appended children."""
 
@@ -227,7 +265,10 @@ class TraceContext:
         attrs: Optional[dict] = None,
         parent_id: str = "",
         remote: bool = False,
+        cpu: bool = False,
     ) -> None:
+        """cpu=True times the ROOT span's thread CPU too: for a trace
+        that starts and finishes on one thread (a watcher pass)."""
         # pooled ids (structs.generate_uuid): a fresh urandom syscall
         # per trace measured ~0.14ms on the bench box — real overhead
         # against the 0.95x enabled-throughput gate
@@ -251,6 +292,8 @@ class TraceContext:
         self.root = Span(
             name, f"{self._prefix}-0", parent_id, now_ns(), 0, None
         )
+        if cpu:
+            self.root._cpu0 = _thread_cpu_ns()
         self.spans: list[Span] = [self.root]
 
     # -- span lifecycle ------------------------------------------------
@@ -261,21 +304,30 @@ class TraceContext:
             st = self._active.stack = []
         return st
 
-    def _parent_id(self) -> str:
+    def active_span(self) -> Span:
+        """The calling thread's innermost open span on this trace (the
+        root when it has none): the parent to hand to another thread
+        that will record its part of the work here."""
         st = self._stack()
-        return st[-1].span_id if st else self.root.span_id
+        return st[-1] if st else self.root
+
+    def _parent_id(self) -> str:
+        return self.active_span().span_id
 
     def start_span(
         self,
         name: str,
         parent: Optional[Span] = None,
         detached: bool = False,
+        cpu: bool = False,
         **attrs,
     ) -> Span:
         """detached=True skips the active-span stack: for spans opened on
         one thread and ended on another (the broker's queue-wait span),
         where stack discipline would mis-parent the opener's later
-        spans."""
+        spans. cpu=True records the thread's CPU time over the span
+        (``Span.cpu_ns``) — for COMPUTE spans that start and end on one
+        thread; a detached span never carries it."""
         pid = parent.span_id if parent is not None else self._parent_id()
         # lock-free: next() on the shared counter and list.append are
         # both GIL-atomic, and readers (to_wire) snapshot the list —
@@ -287,12 +339,19 @@ class TraceContext:
         )
         self.spans.append(s)
         if not detached:
+            if cpu:
+                s._cpu0 = _thread_cpu_ns()
             self._stack().append(s)
             # host-profiler span correlation: one GIL-atomic dict store
             _thread_spans[threading.get_ident()] = name
         return s
 
     def end_span(self, s: Span) -> None:
+        # cpu before wall, as start_span read wall before cpu: the wall
+        # reads bracket the cpu reads, and cpu_ns is NOT held to the
+        # wall time — cpu > wall (a negative off-CPU time) can only be
+        # the two clocks disagreeing, and stays visible as such
+        cpu1 = _thread_cpu_ns() if s._cpu0 is not None else 0
         s.end_ns = now_ns()
         st = self._stack()
         if st and st[-1] is s:
@@ -300,7 +359,13 @@ class TraceContext:
         elif s in st:  # out-of-order end (defensive)
             st.remove(s)
         else:
-            return  # detached span: never on the profiler registry
+            # detached span (or one ended off its opener's thread, which
+            # has no CPU reading to give): never on the profiler registry
+            return
+        if s._cpu0 is not None:
+            s.cpu_ns = cpu1 - s._cpu0
+            if self._finished:
+                _observe_cpu(s)  # a straggler: finish() did not see it
         tid = threading.get_ident()
         if st:
             _thread_spans[tid] = st[-1].name
@@ -311,9 +376,12 @@ class TraceContext:
             _thread_spans.pop(tid, None)
 
     def span(
-        self, name: str, parent: Optional[Span] = None, **attrs
+        self, name: str, parent: Optional[Span] = None, cpu: bool = False,
+        **attrs
     ) -> _SpanHandle:
-        return _SpanHandle(self, self.start_span(name, parent=parent, **attrs))
+        return _SpanHandle(
+            self, self.start_span(name, parent=parent, cpu=cpu, **attrs)
+        )
 
     def add_span(
         self,
@@ -375,7 +443,18 @@ class TraceContext:
                 return
             self._finished = True
         if not self.root.end_ns:
+            cpu1 = _thread_cpu_ns() if self.root._cpu0 is not None else 0
             self.root.end_ns = now_ns()
+            if self.root._cpu0 is not None:
+                self.root.cpu_ns = cpu1 - self.root._cpu0
+        # the cpu spans' histograms, in one warm loop here and not two
+        # cold observes inside each span's end (measured: ~12us a span
+        # off the traced path)
+        for s in list(self.spans):
+            # _cpu0: opened HERE — a merged remote segment's cpu spans
+            # were observed where they ran
+            if s._cpu0 is not None and s.cpu_ns is not None:
+                _observe_cpu(s)
         self.attrs.setdefault("status", status)
         if record and not self.remote:
             recorder().record(self)
@@ -590,23 +669,29 @@ def use(ctx: Optional[TraceContext]) -> _Use:
 # -- hot-path helpers ----------------------------------------------------
 
 
-def start_trace(name: str, **attrs) -> Optional[TraceContext]:
-    """New trace when tracing is enabled; None (the no-op path) when not."""
+def start_trace(
+    name: str, cpu: bool = False, **attrs
+) -> Optional[TraceContext]:
+    """New trace when tracing is enabled; None (the no-op path) when not.
+    cpu=True: see :class:`TraceContext`."""
     if not _enabled:
         return None
-    return TraceContext(name, attrs=attrs)
+    return TraceContext(name, attrs=attrs, cpu=cpu)
 
 
 def span(
     ctx: Optional[TraceContext],
     name: str,
     parent: Optional[Span] = None,
+    cpu: bool = False,
     **attrs,
 ):
-    """Open a child span on ctx, or the singleton no-op when ctx is None."""
+    """Open a child span on ctx, or the singleton no-op when ctx is None.
+    cpu=True also records the thread's CPU time (compute spans only:
+    one thread, no blocking by design)."""
     if ctx is None:
         return NOOP_SPAN
-    return ctx.span(name, parent=parent, **attrs)
+    return ctx.span(name, parent=parent, cpu=cpu, **attrs)
 
 
 def stage(name: str, dur_ns: int) -> None:
@@ -795,6 +880,8 @@ def render_tree(trace: dict) -> str:
             extra = "  " + " ".join(
                 f"{k}={v}" for k, v in sorted(shown.items())
             )
+        if s.get("cpu") is not None:
+            extra = f"  cpu {s['cpu'] / 1e6:.3f}ms" + extra
         lines.append(
             f"{prefix}{branch}{s['name']:<24} {dur:9.3f}ms"
             f"  (self {self_ms:.3f}ms){extra}"

@@ -115,6 +115,197 @@ def test_noop_path_allocates_nothing():
     assert before == after
 
 
+def test_noop_path_with_cpu_flag_allocates_nothing():
+    """cpu=True on the disabled path is the same singleton: no span, no
+    clock read, no lock."""
+    assert not trace.enabled()
+    assert trace.start_trace("x", cpu=True) is None
+    assert trace.span(None, "a", cpu=True) is trace.NOOP_SPAN
+    with trace.span(trace.current(), "a", cpu=True) as h:
+        h.set_attr("k", "v")
+    assert trace.NOOP_SPAN.span is None
+
+
+# ---------------------------------------------------------------------------
+# cpu time on compute spans
+# ---------------------------------------------------------------------------
+
+
+def _burn(seconds: float) -> None:
+    end = time.thread_time() + seconds
+    while time.thread_time() < end:
+        sum(i * i for i in range(2000))
+
+
+def test_cpu_span_carries_cpu_ns_within_its_wall_time():
+    ctx = trace.TraceContext("t")
+    with ctx.span("work", cpu=True) as h:
+        _burn(0.02)
+    s = h.span
+    assert s.cpu_ns is not None
+    assert 0.015e9 <= s.cpu_ns <= s.duration_ns
+    # a plain span carries none
+    with ctx.span("plain") as h2:
+        pass
+    assert h2.span.cpu_ns is None
+
+
+def test_cpu_span_reads_near_zero_across_a_sleep():
+    ctx = trace.TraceContext("t")
+    with ctx.span("nap", cpu=True) as h:
+        time.sleep(0.05)
+    s = h.span
+    assert s.duration_ns >= 0.045e9
+    assert s.cpu_ns is not None and s.cpu_ns < 0.01e9
+    # wall minus cpu is the off-CPU time: here, the whole sleep
+    assert s.duration_ns - s.cpu_ns >= 0.04e9
+
+
+def test_cpu_is_absent_for_detached_spans():
+    """A detached span ends on another thread: no one thread's CPU
+    clock spans it, so cpu=True is ignored."""
+    import threading
+
+    ctx = trace.TraceContext("t")
+    s = ctx.start_span("queue.wait", detached=True, cpu=True)
+    th = threading.Thread(target=ctx.end_span, args=(s,))
+    th.start()
+    th.join(5)
+    assert not th.is_alive()
+    assert s.end_ns >= s.start_ns and s.cpu_ns is None
+    assert "cpu" not in s.to_wire()
+
+
+def test_cpu_span_ended_off_its_thread_gives_no_reading():
+    import threading
+
+    ctx = trace.TraceContext("t")
+    s = ctx.start_span("strays", cpu=True)
+    th = threading.Thread(target=ctx.end_span, args=(s,))
+    th.start()
+    th.join(5)
+    assert not th.is_alive()
+    assert s.cpu_ns is None
+    ctx.end_span(s)  # the opener unwinds its own stack
+
+
+def test_cpu_survives_the_wire_and_renders():
+    ctx = trace.TraceContext("t")
+    with ctx.span("work", cpu=True):
+        _burn(0.005)
+    with ctx.span("plain"):
+        pass
+    ctx.finish(record=False)
+    wire = ctx.to_wire()
+    by = {s["name"]: s for s in wire["spans"]}
+    assert by["work"]["cpu"] > 0 and "cpu" not in by["plain"]
+    back = trace.Span.from_wire(by["work"])
+    assert back.cpu_ns == by["work"]["cpu"]
+    assert trace.Span.from_wire(by["plain"]).cpu_ns is None
+    assert back.to_wire() == by["work"]
+    tree = trace.render_tree(wire)
+    work_line = next(ln for ln in tree.splitlines() if "work" in ln)
+    assert "cpu " in work_line
+    assert "cpu " not in next(
+        ln for ln in tree.splitlines() if "plain" in ln
+    )
+
+
+def test_cpu_root_for_a_one_thread_trace():
+    trace.set_enabled(True)
+    ctx = trace.start_trace("pass", cpu=True)
+    time.sleep(0.02)
+    ctx.finish(record=False)
+    assert ctx.root.cpu_ns is not None
+    assert ctx.root.cpu_ns < ctx.root.duration_ns
+    assert trace.start_trace("plain").root.cpu_ns is None
+
+
+def test_cpu_spans_observe_wall_and_offcpu_seconds_at_finish():
+    """Each cpu span of a finished trace is one observation of
+    nomad.trace.wall_seconds.<span> and one of offcpu_seconds.<span>;
+    plain spans and merged remote segments add none."""
+    from nomad_tpu import metrics
+
+    reg = metrics.Registry()
+    old = metrics._install_registry(reg)
+    try:
+        cap = reg.enable_timing_capture(cap=64)
+        ctx = trace.TraceContext("t")
+        with ctx.span("nap", cpu=True):
+            time.sleep(0.03)
+        with ctx.span("nap", cpu=True):
+            pass
+        with ctx.span("plain"):
+            pass
+        # a remote segment's cpu span was observed where it ran
+        ctx.merge_remote(
+            [{"name": "far", "id": "r-1", "parent": "", "start": 5,
+              "end": 9, "cpu": 3}], None
+        )
+        assert reg.drain_timings(cap) == {}  # nothing before finish
+        ctx.finish(record=False)
+        got = reg.drain_timings(cap)
+    finally:
+        metrics._install_registry(old)
+    assert set(got) == {
+        "nomad.trace.wall_seconds.nap", "nomad.trace.offcpu_seconds.nap"
+    }
+    wall = got["nomad.trace.wall_seconds.nap"]
+    off = got["nomad.trace.offcpu_seconds.nap"]
+    assert len(wall) == len(off) == 2
+    assert all(0 <= o <= w for o, w in zip(off, wall))
+    assert off[0] >= 0.02 and wall[0] >= 0.03
+
+
+def test_cpu_ns_is_the_thread_clock_not_held_to_the_wall_time(monkeypatch):
+    """A thread clock that disagrees with the wall clock shows: cpu_ns
+    passes the span's wall time and the off-CPU observation is negative
+    — nothing forces the off-CPU shares between 0 and 100."""
+    from nomad_tpu import metrics
+
+    # read at: root start, span start, span end, root end
+    ticks = iter((0, 0, 5_000_000_000, 7_000_000_000))
+    monkeypatch.setattr(trace, "_thread_cpu_ns", lambda: next(ticks))
+    reg = metrics.Registry()
+    old = metrics._install_registry(reg)
+    try:
+        cap = reg.enable_timing_capture(cap=8)
+        ctx = trace.TraceContext("t", cpu=True)
+        with ctx.span("fast", cpu=True) as h:
+            pass
+        ctx.finish(record=False)
+        got = reg.drain_timings(cap)
+    finally:
+        metrics._install_registry(old)
+    assert h.span.cpu_ns == 5_000_000_000 > h.span.duration_ns
+    assert ctx.root.cpu_ns == 7_000_000_000 > ctx.root.duration_ns
+    assert got["nomad.trace.offcpu_seconds.fast"][0] < -4.9
+    assert got["nomad.trace.offcpu_seconds.t"][0] < -6.9
+
+
+def test_wall_clock_reads_bracket_the_cpu_clock_reads(monkeypatch):
+    """start: wall then cpu; end: cpu then wall — so on agreeing clocks
+    cpu <= wall holds by the order of the reads, not by a clamp."""
+    order = []
+    real_wall, real_cpu = trace.now_ns, trace._thread_cpu_ns
+    monkeypatch.setattr(
+        trace, "now_ns", lambda: (order.append("wall"), real_wall())[1]
+    )
+    monkeypatch.setattr(
+        trace, "_thread_cpu_ns", lambda: (order.append("cpu"), real_cpu())[1]
+    )
+    ctx = trace.TraceContext("t", cpu=True)
+    assert order == ["wall", "cpu"]
+    del order[:]
+    with ctx.span("work", cpu=True):
+        pass
+    assert order == ["wall", "cpu", "cpu", "wall"]
+    del order[:]
+    ctx.finish(record=False)
+    assert order[:2] == ["cpu", "wall"]
+
+
 # ---------------------------------------------------------------------------
 # ring buffer bounds
 # ---------------------------------------------------------------------------
@@ -521,6 +712,249 @@ def test_e2e_c2m_batch_trace_acceptance(tmp_path):
         assert cmd_operator_trace(args) == 0
     finally:
         agent.shutdown()
+
+
+def test_gc_pause_lands_on_the_collecting_threads_trace():
+    """A collection that runs inside a traced span is a `gc.pause` child
+    of that span (hostobs' gc callback), not the span's self time."""
+    import gc
+
+    from nomad_tpu import hostobs
+
+    prof = hostobs.HostProfiler()
+    prof.start()
+    try:
+        trace.set_enabled(True)
+        ctx = trace.start_trace("t")
+        with trace.use(ctx), trace.span(ctx, "solve.dispatch") as h:
+            gc.collect()
+        ctx.finish(record=False)
+        gc.collect()  # under no trace: nothing to land on, no error
+    finally:
+        prof.stop()
+    pauses = [s for s in ctx.spans if s.name == "gc.pause"]
+    assert pauses and all(p.parent_id == h.span.span_id for p in pauses)
+    assert all(p.attrs["generation"] == 2 and p.attrs["pretimed"]
+               for p in pauses)
+    assert all(h.span.start_ns <= p.start_ns <= p.end_ns <= h.span.end_ns
+               for p in pauses)
+
+
+# ---------------------------------------------------------------------------
+# the host time of a served deploy: front door, queues, lowering
+# ---------------------------------------------------------------------------
+
+
+SERVED_NODES = 1024
+
+
+@pytest.fixture(scope="module")
+def served_deploy(tmp_path_factory):
+    """One job registered over HTTP on a dev agent with the TPU batch
+    worker and tracing on; the traces it left, keyed by trace name."""
+    from nomad_tpu.agent import Agent, AgentConfig
+    from nomad_tpu.api.client import NomadClient
+    from nomad_tpu.structs.node_class import compute_node_class
+
+    trace.recorder().clear()
+    agent = Agent(AgentConfig(
+        server_enabled=True, dev_mode=True, use_tpu_batch_worker=True,
+        trace_enabled=True,
+        data_dir=str(tmp_path_factory.mktemp("served") / "agent"),
+    ))
+    agent.start()
+    try:
+        srv = agent.server.server
+        # no client heartbeats these nodes: a TTL past the test's life
+        # keeps them ready however slowly a loaded box registers them
+        srv.heartbeaters.min_ttl_s = 600.0
+        # enough nodes that a span's work outweighs the fixed cost of
+        # opening it (the coverage test below holds spans to 90 %)
+        for i in range(SERVED_NODES):
+            n = mock.node()
+            n.datacenter = ["dc1", "dc2"][i % 2]
+            n.computed_class = compute_node_class(n)
+            srv.node_register(n)
+        api = NomadClient(f"http://127.0.0.1:{agent.http_addr[1]}")
+        # two deploys warm the path (imports, caches); the third is read
+        for job in _c2m_style_jobs(3, 4):
+            # the worker goes idle before the job arrives: its wait is
+            # the batch's `worker.idle`
+            time.sleep(0.3)
+            eval_id = api.jobs.register(job)
+            assert eval_id
+            assert wait_until(
+                lambda: len(srv.state.allocs_by_job("default", job.id)) >= 4,
+                60,
+            ), "the deploy never placed"
+        rec = trace.recorder()
+        assert wait_until(lambda: len(rec.list(eval_id=eval_id)) >= 3, 20), \
+            rec.list(eval_id=eval_id)
+        listed = rec.list(eval_id=eval_id)
+        yield {
+            "eval_id": eval_id,
+            "listed": listed,
+            "traces": {t["name"]: rec.get(t["id"]) for t in listed},
+        }
+    finally:
+        agent.shutdown()
+        trace.set_enabled(False)
+        trace.recorder().clear()
+
+
+def _span_names(t: dict) -> set:
+    return {s["name"] for s in t["spans"]}
+
+
+def test_http_trace_names_the_front_door(served_deploy):
+    t = served_deploy["traces"]["http"]
+    assert t["attrs"]["method"] == "PUT" and t["attrs"]["path"] == "/v1/jobs"
+    assert t["attrs"]["status"] == "ok"
+    for name in ("http.handle", "http.decode", "job.register", "raft.apply",
+                 "http.reply"):
+        assert name in _span_names(t), trace.render_tree(t)
+    by_id = {s["id"]: s for s in t["spans"]}
+    handle = next(s for s in t["spans"] if s["name"] == "http.handle")
+    # the handler's work and the reply sit under http.handle, which
+    # covers the request from its body to the last byte written
+    for name in ("job.register", "http.reply"):
+        s = next(x for x in t["spans"] if x["name"] == name)
+        assert by_id[s["parent"]]["name"] == "http.handle"
+        assert handle["start"] <= s["start"] and s["end"] <= handle["end"]
+    # raft.encode (the request's thread) and fsm.apply (raft's apply
+    # thread, on the submitter's trace) are cpu spans under raft.apply
+    for name in ("raft.encode", "fsm.apply"):
+        s = next(x for x in t["spans"] if x["name"] == name)
+        assert by_id[s["parent"]]["name"] == "raft.apply" and "cpu" in s
+
+
+def test_one_eval_id_finds_the_http_eval_and_batch_traces(served_deploy):
+    names = {t["name"] for t in served_deploy["listed"]}
+    assert {"http", "eval"} <= names
+    assert names & {"tpu.batch", "tpu.interactive"}
+    assert served_deploy["traces"]["http"]["attrs"]["eval_id"] == \
+        served_deploy["eval_id"]
+
+
+def test_batch_trace_names_the_idle_wait_and_the_lowering(served_deploy):
+    t = served_deploy["traces"].get("tpu.batch") \
+        or served_deploy["traces"]["tpu.interactive"]
+    names = _span_names(t)
+    for name in ("worker.idle", "solve.dispatch", "reconcile",
+                 "lower", "lower.table", "lower.groups", "commit.queue",
+                 "commit.finish", "plan.submit"):
+        assert name in names, trace.render_tree(t)
+    by_id = {s["id"]: s for s in t["spans"]}
+    lower = next(s for s in t["spans"] if s["name"] == "lower")
+    assert by_id[lower["parent"]]["name"] == "solve.dispatch"
+    assert "cpu" in lower and lower["cpu"] <= lower["end"] - lower["start"]
+    for child, attrs in (("lower.table", {"nodes"}),
+                         ("lower.groups", {"groups", "cache_hits"})):
+        s = next(x for x in t["spans"] if x["name"] == child)
+        assert s["parent"] == lower["id"]
+        assert attrs <= set(s["attrs"])
+    table = next(s for s in t["spans"] if s["name"] == "lower.table")
+    assert table["attrs"]["nodes"] == SERVED_NODES
+    # the idle wait ended when the eval arrived: before the batch began
+    idle = next(s for s in t["spans"] if s["name"] == "worker.idle")
+    assert idle["end"] - idle["start"] >= 0.2e9
+    assert idle["end"] <= t["start"] + 5e6
+
+
+def test_solve_dispatch_is_covered_by_its_own_children(served_deploy):
+    """`trace.coverage`, one level down: the direct children of
+    solve.dispatch account for >= 90 % of it."""
+    t = served_deploy["traces"].get("tpu.batch") \
+        or served_deploy["traces"]["tpu.interactive"]
+    sd = next(s for s in t["spans"] if s["name"] == "solve.dispatch")
+    kids = [s for s in t["spans"] if s["parent"] == sd["id"]]
+    covered = trace._interval_union_ns(
+        [(max(k["start"], sd["start"]), min(k["end"], sd["end"]))
+         for k in kids]
+    )
+    share = covered / max(1, sd["end"] - sd["start"])
+    assert share >= 0.90, (share, trace.render_tree(t))
+
+
+def test_deploywatch_pass_is_a_trace_with_scanned_and_its_histograms():
+    from nomad_tpu import metrics
+    from nomad_tpu.server.deployment_watcher import DeploymentsWatcher
+    from nomad_tpu.state.store import StateStore
+    from nomad_tpu.structs.structs import Deployment, DeploymentState
+
+    state = StateStore()
+    job = mock.job()
+    state.upsert_job(10, job)
+    for i, status in enumerate(("running", "running", "successful")):
+        d = Deployment(
+            id=f"d-{i}", namespace=job.namespace, job_id=job.id,
+            job_version=job.version, status=status,
+            task_groups={"web": DeploymentState(desired_total=2)},
+        )
+        state.upsert_deployment(11 + i, d)
+    applied = []
+    w = DeploymentsWatcher(state, lambda *a: applied.append(a))
+    reg = metrics.Registry()
+    old = metrics._install_registry(reg)
+    try:
+        cap = reg.enable_timing_capture(cap=64)
+        assert w.run_once() == 0  # tracing off: `scanned` all the same
+        trace.set_enabled(True)
+        w.run_once()
+        got = reg.drain_timings(cap)
+    finally:
+        metrics._install_registry(old)
+    assert got["nomad.deploywatch.scanned"] == [2, 2]
+    # the traced pass alone left the cpu span's two histograms: they
+    # and the trace hold a pass's wall time, and nothing else does
+    assert set(got) == {
+        "nomad.deploywatch.scanned",
+        "nomad.trace.wall_seconds.deploywatch.pass",
+        "nomad.trace.offcpu_seconds.deploywatch.pass",
+    }
+    assert len(got["nomad.trace.wall_seconds.deploywatch.pass"]) == 1
+    assert len(got["nomad.trace.offcpu_seconds.deploywatch.pass"]) == 1
+    passes = trace.recorder().list(name="deploywatch.pass")
+    assert len(passes) == 1
+    assert passes[0]["attrs"]["scanned"] == 2
+    assert passes[0]["attrs"]["acted"] == 0
+    t = trace.recorder().get(passes[0]["id"])
+    assert t["spans"][0]["name"] == "deploywatch.pass"
+    assert "cpu" in t["spans"][0]
+
+
+def test_watch_route_seconds_is_observed_once_per_routed_write():
+    from types import SimpleNamespace
+
+    from nomad_tpu import metrics
+    from nomad_tpu.server.watch_hub import AllocWatchHub
+    from nomad_tpu.state.store import TABLE_ALLOCS
+
+    state = SimpleNamespace(subscribe=lambda fn: None)
+    hub = AllocWatchHub(state)
+    reg = metrics.Registry()
+    old = metrics._install_registry(reg)
+    try:
+        hub.stop()  # route by hand: the fan-out thread would race us
+        cap = reg.enable_timing_capture(cap=64)
+        alloc = SimpleNamespace(node_id="n1")
+        for index in (5, 6, 7):
+            hub._on_store_write(index, TABLE_ALLOCS, [alloc], "upsert")
+        # not an alloc write, and a write that touches no node: unrouted
+        hub._on_store_write(8, "jobs", [alloc], "upsert")
+        hub._on_store_write(9, TABLE_ALLOCS, [SimpleNamespace(node_id="")],
+                            "upsert")
+        time.sleep(0.01)
+        hub._drain()
+        hub._drain()  # an empty drain observes nothing
+        got = reg.drain_timings(cap)
+    finally:
+        metrics._install_registry(old)
+    routed = got["nomad.watch.route_seconds"]
+    assert len(routed) == 3
+    assert all(0.005 <= r < 5.0 for r in routed)
+    assert routed[0] >= routed[1] >= routed[2]  # stamped at the write
+    assert hub.index_of("n1") == 7
 
 
 OVERHEAD_SCRIPT = r"""
