@@ -34,6 +34,9 @@ from .raft_replication import NotLeaderError
 logger = logging.getLogger("nomad_tpu.worker")
 
 DEQUEUE_TIMEOUT_S = 0.5
+# how long a drain that follows a batch of several evals waits for one
+# more before it gives up (TPUBatchWorker._run)
+STRAGGLER_WAIT_S = 0.01
 
 
 def _retriable_device_error(e: BaseException) -> bool:
@@ -492,6 +495,7 @@ class TPUBatchWorker:
     def _run(self, stop: threading.Event) -> None:
         broker = self.server.eval_broker
         idle_since: Optional[int] = None  # trace.now_ns() at going idle
+        coalescing = False  # the previous batch held more than one eval
         while not stop.is_set():
             # Drop the previous batch's PendingEvalBatch once its commit
             # lands: on an idle worker it would otherwise pin the solved
@@ -559,10 +563,17 @@ class TPUBatchWorker:
             if bctx is not None and idle is not None:
                 bctx.add_span("worker.idle", *idle)
             with trace.span(bctx, "broker.drain"):
-                # opportunistically drain more ready evals without waiting
+                # Take what is ready; never wait on an empty broker after
+                # a batch of one — a quiet cluster pays nothing here. After
+                # a batch of several, evals are arriving together: wait
+                # for a straggler, because what coalesces here solves
+                # conflict-free in ONE batch, and what does not solves in
+                # overlapping small batches that the host paths cannot
+                # chain (the applier trims the loser; PERF.md § 6, PR 25).
+                wait_s = STRAGGLER_WAIT_S if coalescing else 0
                 while len(batch) < limit:
                     ev2, token2 = broker.dequeue(
-                        self.schedulers, timeout_s=0.01
+                        self.schedulers, timeout_s=wait_s
                     )
                     if ev2 is None:
                         break
@@ -575,6 +586,7 @@ class TPUBatchWorker:
                         metrics.incr("nomad.worker.lane.drain_preempted")
                         break
                     batch.append((ev2, token2))
+            coalescing = len(batch) > 1
             if bctx is not None:
                 bctx.set_attr("evals", len(batch))
                 bctx.set_attr("eval_ids", [e.id for e, _ in batch])
