@@ -1,7 +1,8 @@
 """`bulk`: a backlog of identical service jobs landed at once.
 
 `jobs` jobs of `count` allocs each are registered back to back over
-`PUT /v1/jobs` from `submitters` threads at the start of the window;
+`PUT /v1/jobs` from `submitters` threads, bodies encoded beforehand and
+all threads released on one barrier at the start of the window;
 the window then watches the backlog drain. The work is fixed: every
 seed registers the same jobs (under other ids, on a cluster whose node
 ids the seed made).
@@ -39,7 +40,15 @@ def run(ctx) -> None:
         prepared.append((ctx.new_op(job.id, count, kind="job"),
                          jobs.encode(job)))
 
+    # every submitter is up and waiting when the window opens, and all
+    # are released at once: no thread's start-up is inside the window
+    gate = threading.Barrier(subs + 1)
+
     def submit(k: int) -> None:
+        try:
+            gate.wait()
+        except threading.BrokenBarrierError:
+            return  # the window never opened
         for op, body in prepared[k::subs]:
             if time.monotonic() >= ctx.t_end:
                 return  # never sent: not attempted
@@ -48,9 +57,14 @@ def run(ctx) -> None:
     threads = [threading.Thread(target=submit, args=(k,),
                                 name=f"bench-submit-{k}")
                for k in range(subs)]
-    ctx.open_window()
     for t in threads:
         t.start()
+    try:
+        ctx.open_window()
+    except BaseException:
+        gate.abort()
+        raise
+    gate.wait()
     for t in threads:
         t.join()
     # the window watches the backlog drain: to its end, or to the last

@@ -20,6 +20,8 @@ import threading
 import time
 import uuid
 
+from benchmarks.harness import spec
+
 REGISTER_DEADLINE_S = 180.0
 
 
@@ -27,33 +29,74 @@ class SetupFailure(Exception):
     """Set-up could not bring the cluster to the state a run needs."""
 
 
-class Fleet:
-    """`n` identical nodes made from the seed, registered and kept alive.
+def deal_classes(classes: list[dict], n: int, seed: int) -> list[int]:
+    """Which class each of `n` nodes belongs to: every class gets its
+    `share` (or `count`, read as a weight) of the fleet by largest
+    remainder, dealt over the nodes in an order the seed picks. One
+    class draws nothing."""
+    if len(classes) == 1:
+        return [0] * n
+    weights = [float(c.get("count", c.get("share", 0))) for c in classes]
+    total = sum(weights)
+    if total <= 0 or min(weights) < 0:
+        raise SetupFailure("node_classes need a positive share or count")
+    exact = [w * n / total for w in weights]
+    counts = [int(x) for x in exact]
+    by_remainder = sorted(range(len(classes)),
+                          key=lambda k: (counts[k] - exact[k], k))
+    for k in by_remainder[:n - sum(counts)]:
+        counts[k] += 1
+    deal = [k for k, c in enumerate(counts) for _ in range(c)]
+    random.Random(seed ^ 0xC1A55).shuffle(deal)
+    return deal
 
-    Node ids come from the seed, and so does the order in which nodes are
-    dealt over the datacenters: two runs of one seed register the same
-    cluster."""
+
+class Fleet:
+    """`n` nodes made from the seed, registered and kept alive.
+
+    Node ids come from the seed, and so do the class of each node and
+    the order in which a class's nodes are dealt over its datacenters:
+    two runs of one seed register the same cluster."""
 
     def __init__(self, cs, config: dict, n: int, seed: int,
                  driver_threads: int = 16) -> None:
         from nomad_tpu import mock
+        from nomad_tpu.structs import compute_node_class
+        from nomad_tpu.structs.structs import (
+            NodeDeviceInstance, NodeDeviceResource)
 
         self.cs = cs
         self.rng = random.Random(seed ^ 0xF1EE7)
-        dcs = list(config["datacenters"])
-        shape = config["node"]
+        classes = spec.node_classes(config)
+        deal = deal_classes(classes, n, seed)
+        dealt = [0] * len(classes)  # nodes of each class so far
         self.nodes = []
         for i in range(n):
+            shape = classes[deal[i]]
+            dcs = list(shape.get("datacenters") or config["datacenters"])
             node = mock.node(
                 id=str(uuid.UUID(int=self.rng.getrandbits(128), version=4)),
                 name=f"bench-{i}",
-                datacenter=dcs[i % len(dcs)],
+                datacenter=dcs[dealt[deal[i]] % len(dcs)],
             )
+            dealt[deal[i]] += 1
+            if shape.get("name"):
+                node.node_class = shape["name"]
             res = node.resources
             res.cpu = shape["cpu_mhz"]
             res.memory_mb = shape["memory_mb"]
             res.disk_mb = shape["disk_mb"]
+            res.devices = [
+                NodeDeviceResource(
+                    vendor=d["vendor"], type=d["type"], name=d["name"],
+                    instances=[NodeDeviceInstance(id=f"{d['name']}-{k}")
+                               for k in range(int(d["count"]))],
+                    attributes=dict(d.get("attributes", {})))
+                for d in shape.get("devices", ())]
             node.attributes.update(shape.get("attributes", {}))
+            # as a client does after fingerprinting: the program memoizes
+            # feasibility by this digest of what the node offers
+            node.computed_class = compute_node_class(node)
             self.nodes.append(node)
         self._by_id = {node.id: node for node in self.nodes}
         self._lock = threading.Lock()

@@ -26,7 +26,7 @@ import threading
 import time
 from dataclasses import dataclass, field
 
-from benchmarks.harness import jobs
+from benchmarks.harness import jobs, spec
 from benchmarks.harness.observer import JobWatch, Observer
 
 DRAIN_DEADLINE_S = 60.0
@@ -38,6 +38,7 @@ class Op:
     asked: int
     kind: str
     watch: JobWatch
+    ask: dict  # what every alloc of the job asks, as it was sent
     sent: bool = False
     status: int = 0
     answer: object = None
@@ -70,8 +71,10 @@ class RunContext:
         self.t_end = float("inf")
         self.on_open = lambda: None
 
-    def new_op(self, job_id: str, asked: int, kind: str) -> Op:
-        op = Op(job_id, asked, kind, self.observer.watch(job_id, asked))
+    def new_op(self, job_id: str, asked: int, kind: str,
+               job_class: str | None = None) -> Op:
+        op = Op(job_id, asked, kind, self.observer.watch(job_id, asked),
+                spec.job_class(self.config, job_class)["ask"])
         with self._lock:
             self.ops.append(op)
         return op
@@ -101,18 +104,22 @@ class RunContext:
         op.watch.done.wait(open_for + DRAIN_DEADLINE_S)
 
 
-def settle(cluster, ctx: RunContext, deadline_s: float = DRAIN_DEADLINE_S):
+def settle(cluster, ctx: RunContext, deadline_s: float = DRAIN_DEADLINE_S,
+           may_remain: tuple = ()):
     """After the window: nothing more is sent; wait until every acked
     operation is visible and the broker, the plan queue and the blocked
     evals are empty — stopping a server while follow-up evals commit
-    raises raft-apply timeouts (PERF.md, PR 21). Returns what was still
+    raises raft-apply timeouts (PERF.md, PR 21). `may_remain` lists what
+    the configuration says stays for good (evals blocked on a cluster
+    filled to capacity); nothing else is ignored. Returns what was still
     in flight at the deadline, empty when the system settled."""
     end = time.monotonic() + deadline_s
     left: dict = {}
     while True:
         pending = [op for op in ctx.ops
                    if op.acked and not op.watch.done.is_set()]
-        left = {k: v for k, v in cluster.in_flight().items() if v}
+        left = {k: v for k, v in cluster.in_flight().items()
+                if v and k not in may_remain}
         if pending:
             left["ops_not_visible"] = len(pending)
         if not ctx.observer.idle():

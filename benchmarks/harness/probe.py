@@ -57,6 +57,11 @@ def _host_threads() -> dict:
             "top_sites": snap["top_sites"]}
 
 
+def _batches(reg) -> dict:
+    raw = reg.histogram_raw("nomad.tpu.batch_evals") or {}
+    return {"count": raw.get("count", 0), "sum": raw.get("sum", 0)}
+
+
 class Probe:
     """Open at the window's start, close at its end; `samples` is then
     what the reducers read."""
@@ -66,6 +71,7 @@ class Probe:
         self._capture = None
         self._c0: dict = {}
         self._mark = 0
+        self._b0: dict = {}
         self._host0: dict = {}
         self.samples: dict = {}
 
@@ -75,6 +81,7 @@ class Probe:
         self._mark = compiles_so_far()
         reg = metrics.registry()
         self._c0 = dict(reg.snapshot()["counters"])
+        self._b0 = _batches(reg)
         if self.traced:
             trace.configure(max_traces=65536, enabled_=True)
             trace.recorder().clear()
@@ -88,6 +95,7 @@ class Probe:
         c1 = reg.snapshot()["counters"]
         counters = {k: v - self._c0.get(k, 0) for k, v in c1.items()
                     if v != self._c0.get(k, 0)}
+        b1 = _batches(reg)
         timings: dict = {}
         spans: dict = {}
         batches: list = []
@@ -127,4 +135,8 @@ class Probe:
             "spans": spans,
             "batches": batches,
             "compiles": compiles_since(self._mark),
+            # how the worker split the window's evals into solves: read
+            # in untraced runs too, it explains a backlog's rate
+            "batches_in_window": {k: b1[k] - self._b0[k]
+                                  for k in ("count", "sum")},
         }

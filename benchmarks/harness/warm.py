@@ -3,7 +3,9 @@
 Two steps, both counted as set-up.
 
 1. A dry solve for every batch shape the generator lists (`shapes()`:
-   how many evals a drained batch can hold, at which count). It runs the
+   how many evals a drained batch can hold, at which count, and where
+   the generator sends another than the default job, of which
+   `job_class` and `priority`). It runs the
    worker's own solve entry (`solve_eval_batch_begin` with the worker's
    resident state and configuration, then `finish`) on jobs that exist
    only in an overlay over a snapshot, and throws the plans away:
@@ -69,7 +71,8 @@ def dry_solves(server, config: dict, shapes: list[dict]) -> int:
         for _ in range(int(shape["evals"])):
             serial += 1
             job = jobs.make_job(config, f"warm-dry-{serial}",
-                                int(shape["count"]))
+                                int(shape["count"]), shape.get("priority"),
+                                shape.get("job_class"))
             batch[job.id] = job
         evals = [mock.eval_for_job(j) for j in batch.values()]
         solve_eval_batch_begin(

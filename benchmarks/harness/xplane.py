@@ -163,7 +163,8 @@ def idle_gaps(trace: dict, t0: float, t1: float,
               host_spans: list[tuple[str, float, float]], k: int = 10):
     """The time in which no device ran an operation, by what the host
     was doing: every instant of a gap goes to the host span that covers
-    it and started last (the innermost), or to `unattributed`.
+    it and started last (the innermost), or to `unattributed`. The `k`
+    longest, `unattributed` always among them.
     host_spans — (name, start, end) on the trace's clock."""
     busy_ivs = union(clip(
         [(s, e) for dev in trace["devices"].values()
@@ -197,4 +198,9 @@ def idle_gaps(trace: dict, t0: float, t1: float,
         active = [sp for sp in active if sp[1] > a]
         name = max(active)[2] if active else "unattributed"
         total[name] = total.get(name, 0.0) + (b - a) / 1e9
-    return sorted(total.items(), key=lambda kv: -kv[1])[:k]
+    ranked = sorted(total.items(), key=lambda kv: -kv[1])
+    # what no span covers is always shown: where it is not among the
+    # longest it takes the last place (the line admits `k` entries)
+    if "unattributed" in dict(ranked[k:]):
+        ranked[k - 1:] = [("unattributed", total["unattributed"])]
+    return ranked[:k]

@@ -6,10 +6,11 @@
 ONE process, which holds the chip. It starts one dev server agent with
 the TPU batch worker (the `-tpu-scheduler` construction path, as
 chip_smoke.py does; no chip is an error, there is no CPU fallback),
-registers the configuration's fleet over `Node.register`, warms the
-cell's own shapes, measures for `--seconds`, drains what it started,
-checks the store against the plain reference, and prints the result as
-ONE JSON line, last on stdout. `--trace 0` reports the cell's end-to-end
+registers the configuration's fleet over `Node.register` and its standing
+load over the front door, warms the cell's own shapes, measures for
+`--seconds`, drains what it started, holds the store to the reference
+rules the configuration's guarantees name, and prints the result as ONE
+JSON line, last on stdout. `--trace 0` reports the cell's end-to-end
 metrics, `--trace 1` its per-layer metrics from the program's spans and
 counters and a profiler trace of the window. Failures by cause go to
 stderr and to `benchmarks/out/<cell>.<seed>.json`.
@@ -101,7 +102,8 @@ def run(args, t_process: float) -> dict:
     from benchmarks.harness import jobs, ops, probe, warm
     from benchmarks.harness.cluster import Cluster, SetupFailure
     from benchmarks.harness.observer import Observer
-    from benchmarks.reference import density, store_check
+    from benchmarks.reference import density
+    from benchmarks.reference.snapshot import snapshot
 
     probe.install_compile_listener()
     params = dict(cell.traffic)
@@ -110,13 +112,20 @@ def run(args, t_process: float) -> dict:
         params.update(params.get("rehearsal", {}))
         n_nodes = REHEARSAL_NODES
     generator = cell.generator()
-    per_node = density.allocs_per_node(
-        {k: cell.config["node"][k] for k in ("cpu_mhz", "memory_mb", "disk_mb")},
-        cell.config["ask"])
-    if per_node != int(cell.config["allocs_per_node"]):
+    rules = cell.rules()
+    may_remain = tuple(cell.config.get("may_remain", ()))
+    fits = [density.allocs_per_node(node, jc["ask"])
+            for node in spec_mod.node_classes(cell.config)
+            for jc in spec_mod.job_classes(cell.config).values()]
+    # the arithmetic ideal of `packing_share` is true only where every
+    # node and every ask is the same
+    per_node = fits[0] if len(fits) == 1 else None
+    if per_node is not None and \
+            per_node != int(cell.config["allocs_per_node"]):
         raise BenchFailure(
             f"the configuration says {cell.config['allocs_per_node']} allocs "
             f"fit a node; its node and ask give {per_node}")
+    least_fit = min((f for f in fits if f > 0), default=1)
 
     cluster = Cluster(cell.config, n_nodes, args.seed)
     observer = None
@@ -139,25 +148,42 @@ def run(args, t_process: float) -> dict:
                              seed=args.seed, seconds=seconds,
                              http=cluster.http, observer=observer)
 
+        # -- what runs on the cluster before the window ---------------
+        for op, body in _standing_load(cell.config, cluster.fleet.nodes, ctx):
+            ctx.send(op, body)
+            if op.acked:
+                ctx.await_visible(op)
+            if not op.watch.done.is_set():
+                raise BenchFailure(
+                    f"set-up: the standing job {op.job_id} did not become "
+                    f"visible ({op.visible} of {op.asked} allocs, status "
+                    f"{op.status})")
+        t_standing = time.monotonic()
+
         # -- warm the cell's own shapes -------------------------------
         shapes = generator.shapes(params, cell.config)
         n_dry = warm.dry_solves(server, cell.config, shapes)
         dcs = len(cell.config["datacenters"])
         max_rows = min(n_nodes, 2 * max(
-            s["evals"] * (-(-s["count"] // per_node) + dcs) for s in shapes))
+            s["evals"] * (-(-s["count"] // least_fit) + dcs) for s in shapes))
         buckets = warm.scatter_buckets(server, max_rows)
-        for i, count in enumerate(generator.warm_jobs(params)):
+        for i, w in enumerate(generator.warm_jobs(params)):
+            # a count, or (count, job_class, priority)
+            count, job_class, priority = (w, None, None) \
+                if isinstance(w, int) else w
+            if priority is None:
+                priority = int(params["priority"])
             job = jobs.make_job(cell.config, f"warm-{args.seed}-{i}", count,
-                                int(params["priority"]))
-            op = ctx.new_op(job.id, count, kind="warm")
+                                priority, job_class)
+            op = ctx.new_op(job.id, count, "warm", job_class)
             ctx.send(op, jobs.encode(job))
             if op.acked:
                 ctx.await_visible(op)
-        left = ops.settle(cluster, ctx)
+        left = ops.settle(cluster, ctx, may_remain=may_remain)
         if left:
             raise BenchFailure(f"set-up: the warm-up deploys did not settle: "
                                f"{left}")
-        warm_ops, ctx.ops = ctx.ops, []
+        setup_ops, ctx.ops = ctx.ops, []
         t_warm = time.monotonic()
 
         # -- the window ----------------------------------------------
@@ -191,17 +217,17 @@ def run(args, t_process: float) -> dict:
             profiling = False
 
         # -- drain, then judge ---------------------------------------
-        left = ops.settle(cluster, ctx)
+        left = ops.settle(cluster, ctx, may_remain=may_remain)
         causes = ops.judge(ctx, server.state)
-        snap = store_check.snapshot(server.state)
-        done = [op for op in warm_ops + ctx.ops
-                if op.acked and op.watch.done.is_set()]
-        faults = store_check.check(
-            snap, {op.job_id: op.asked for op in done}, cell.config["ask"])
-        if observer.never_visible:
-            faults.append(f"{observer.never_visible} node watches never saw "
-                          "a commit that touched them")
-        pack = density.packing(snap, per_node)
+        snap = snapshot(server.state,
+                        {"never_visible": observer.never_visible})
+        expected = {op.job_id: (op.asked, op.ask)
+                    for op in setup_ops + ctx.ops
+                    if op.acked and op.watch.done.is_set()}
+        faults_by_rule = {name: rule.check(snap, expected, cell.config)
+                          for name, rule in rules}
+        faults = [f for said in faults_by_rule.values() for f in said]
+        pack = density.packing(snap, per_node) if per_node else None
         mem = (jax.devices()[0].memory_stats() or {})
         counters = pr.samples["counters"]
     finally:
@@ -224,7 +250,7 @@ def run(args, t_process: float) -> dict:
     values: dict[str, float] = {"setup_s": report["setup_s"]}
     n_failed_ops, attempted = _account(sent, faults, observer, seconds,
                                        values)
-    if pack["packing_share"] is not None:
+    if pack is not None and pack["packing_share"] is not None:
         values["packing_share"] = pack["packing_share"]
         if pack["packing_share"] > 100.0 + 1e-9:
             faults.append(
@@ -236,6 +262,15 @@ def run(args, t_process: float) -> dict:
     on_chip = device.platform == "tpu"
     correct = (not faults and not compiles and (on_chip or args.rehearsal)
                and n_failed_ops == 0 and not left)
+    # every number `correct` compares, beside its limit
+    checks = {f"faults.{name}": {"value": len(said), "limit": 0}
+              for name, said in faults_by_rule.items()}
+    checks["failed"] = {"value": n_failed_ops, "limit": 0}
+    checks["compiles_in_window"] = {"value": len(compiles), "limit": 0}
+    checks["left_in_flight"] = {"value": sum(left.values()), "limit": 0}
+    if "packing_share" in values:
+        checks["packing_share"] = {"value": values["packing_share"],
+                                   "limit": 100.0}
 
     samples = dict(pr.samples, client=client)
     samples["derived"] = {"kernel_path_s": _kernel_path_s(samples["batches"])}
@@ -285,6 +320,9 @@ def run(args, t_process: float) -> dict:
         duplicate_alloc_ids_seen=observer.duplicate_ids,
         fleet_errors=cluster.fleet.errors, packing=pack, window=window,
         setup={"registered_s": t_registered - t_process,
+               "standing_s": t_standing - t_process,
+               "standing_jobs": sum(op.kind == "standing"
+                                    for op in setup_ops),
                "warmed_s": t_warm - t_process, "dry_solves": n_dry,
                "scatter_buckets": buckets},
         ops={"sent": len(sent),
@@ -296,6 +334,13 @@ def run(args, t_process: float) -> dict:
                                    "nomad.tpu.small_batch_requests"))}
         if args.trace else None,
         host_threads=pr.samples["host"],
+        batches_in_window=pr.samples["batches_in_window"],
+        batch_evals=[b["evals"] for b in samples["batches"]]
+        if args.trace else None,
+        # (seconds since the window opened, allocs visible): one point a
+        # routed commit, thinned to 200 — how a backlog drained
+        visible_timeline=observer.timeline[::max(
+            1, len(observer.timeline) // 200)] + observer.timeline[-1:],
         max_batch_evals=max(pr.samples["timings"].get(
             "nomad.tpu.batch_evals", [0])) if args.trace else None,
         device=device_out, metrics=metrics_out,
@@ -308,8 +353,34 @@ def run(args, t_process: float) -> dict:
             "device": device_out}
     if breakdown is not None:
         line["breakdown"] = breakdown
+    line["checks"] = checks  # last in the line
     report["line"] = line
     return report
+
+
+def _standing_load(config: dict, fleet_nodes: list, ctx):
+    """The configuration's `standing` entries as (op, body), in order:
+    `jobs` jobs of `count` allocs of a job class at a priority, or as
+    many as fill `fill_share` of what the fleet holds of that class's
+    ask. Sent one after another through the front door, each waited for,
+    so the cluster is the same for every seed."""
+    from benchmarks.harness import jobs
+    from benchmarks.reference import density
+
+    for k, entry in enumerate(config.get("standing", ())):
+        job_class, count = entry.get("job_class"), int(entry["count"])
+        n_jobs = entry.get("jobs")
+        if n_jobs is None:
+            ask = spec_mod.job_class(config, job_class)["ask"]
+            room = sum(density.allocs_per_node(
+                {"cpu_mhz": n.resources.cpu, "memory_mb": n.resources.memory_mb,
+                 "disk_mb": n.resources.disk_mb}, ask) for n in fleet_nodes)
+            n_jobs = int(float(entry["fill_share"]) * room) // count
+        for i in range(int(n_jobs)):
+            job = jobs.make_job(config, f"standing-{ctx.seed}-{k}-{i}", count,
+                                entry.get("priority"), job_class)
+            yield (ctx.new_op(job.id, count, "standing", job_class),
+                   jobs.encode(job))
 
 
 def _account(sent, faults, observer, seconds, values):
@@ -467,9 +538,12 @@ def main(argv=None, t_process: float = None) -> int:
         "failures_by_cause", "store_faults", "not_settled",
         "compiles_in_window", "watched_counters", "plans_trimmed",
         "ops_completed_by_more_than_one_commit", "packing", "setup", "ops",
-        "path_counts")}
+        "path_counts", "batches_in_window")}
     print(f"benchmarks/run.py: {json.dumps(summary, default=str)}",
           file=sys.stderr)
+    for name, c in line["checks"].items():
+        print(f"benchmarks/run.py: check {name} {c['value']} limit "
+              f"{c['limit']}", file=sys.stderr)
     print(json.dumps(line), flush=True)
     return 0
 

@@ -8,7 +8,7 @@ from pathlib import Path
 
 import pytest
 
-from bench_helpers import with_candidates
+from bench_helpers import plant, with_candidates
 from benchmarks import run as bench_run
 
 ROOT = Path(__file__).resolve().parents[2]
@@ -57,8 +57,16 @@ def test_cell_rehearsal_prints_the_contracts_line(capsys, cell, trace,
     line, report = rehearse(
         capsys, cell, trace,
         bench_dir=candidate_tree if cell in CANDIDATES else None)
-    want = {"correct", "attempted", "failed", "metrics", "device"}
-    assert set(line) == want  # `breakdown` only with a device trace
+    # `breakdown` only with a device trace; what was compared comes last
+    assert list(line) == ["correct", "attempted", "failed", "metrics",
+                          "device", "checks"]
+    for name, c in line["checks"].items():
+        assert set(c) == {"value", "limit"}
+        assert c["value"] == c["limit"], name  # also packing_share: 100
+    guarantees = json.loads((ROOT / "benchmarks" / "configs" / (
+        cell.rsplit(".", 1)[0] + ".json")).read_text())["guarantees"]
+    assert {n for n in line["checks"] if n.startswith("faults.")} == {
+        f"faults.{g['rule']}" for g in guarantees}
     assert line["correct"] is True, report
     assert line["failed"] == 0 and line["attempted"] > 0
     assert set(line["device"]) == {"platform", "kind", "count",
@@ -84,6 +92,30 @@ def test_cell_rehearsal_prints_the_contracts_line(capsys, cell, trace,
         assert f"compiles_in_window.{cell.rsplit('.', 1)[1]}" in line["metrics"]
     elif cell.endswith(".bulk"):
         assert 0 < line["metrics"]["packing_share"]["value"] <= 100.0
+
+
+@pytest.mark.parametrize("fault, cell, rule", [
+    ("ask_altered", "c1m-5k.deploys", "asks_carried"),
+    ("ask_altered", "c2m-10k.bulk", "asks_carried"),
+    ("all_on_one_node", "c2m-10k.bulk", "node_capacity"),
+    ("all_on_one_node", "c2m-10k.deploys", "node_capacity"),
+])
+def test_a_fault_planted_under_the_timed_path_reads_correct_false(
+        capsys, fault, cell, rule):
+    """The control: the program commits an answer altered where it is
+    produced (the store takes another grant, or another node, than the
+    plan applier verified), the rest of the run is driven as it is, and
+    the run's own reference rules find it."""
+    undo = plant(fault)
+    try:
+        line, report = rehearse(capsys, cell, 0, seed=3_000_000_031)
+    finally:
+        undo()
+    assert line["correct"] is False and line["failed"] >= 1
+    assert line["checks"][f"faults.{rule}"]["value"] >= 1, report
+    assert line["checks"]["compiles_in_window"]["value"] == 0
+    # with no fault planted the same cells read `correct` true: the
+    # rehearsals above
 
 
 def test_one_operator_puts_one_eval_in_every_batch(capsys):

@@ -13,6 +13,8 @@ import shutil
 import time
 from pathlib import Path
 
+import pytest
+
 from bench_helpers import with_candidates
 from benchmarks import run as bench_run
 
@@ -80,6 +82,164 @@ def test_a_new_cell_is_files_and_entries_and_no_edit(tmp_path, capsys):
     assert {k: after[k] for k in before} == before  # nothing there was edited
 
 
+CLASS_LOOP = '''"""A closed loop of one operator that deals the mix's `deploys` in turn:
+(job class, count, priority) each."""
+import time
+
+from benchmarks.harness import jobs
+
+
+def shapes(params, config):
+    return [{"evals": 1, "count": c, "job_class": jc, "priority": p}
+            for jc, c, p in params["deploys"]]
+
+
+def warm_jobs(params):
+    return [(c, jc, p) for jc, c, p in params["deploys"]]
+
+
+def run(ctx):
+    deploys = ctx.params["deploys"]
+    ctx.open_window()
+    i = 0
+    while time.monotonic() < ctx.t_end:
+        jc, count, priority = deploys[i % len(deploys)]
+        job = jobs.make_job(ctx.config, f"d-{ctx.seed}-{i}", count, priority,
+                            jc)
+        op = ctx.new_op(job.id, count, "small", jc)
+        ctx.send(op, jobs.encode(job))
+        if op.acked:
+            ctx.await_visible(op)
+        i += 1
+'''
+
+CLASS_CAP_RULE = '''"""A node of a class that states `max_allocs` holds at most that many
+live allocs."""
+from collections import Counter
+
+
+def check(snap, expected, config):
+    cap = {c["name"]: c["max_allocs"] for c in config["node_classes"]
+           if "max_allocs" in c}
+    held = Counter(a["node"] for a in snap["allocs"])
+    over = [n["id"] for n in snap["nodes"]
+            if held[n["id"]] > cap.get(n["class"], held[n["id"]])]
+    return [f"{len(over)} nodes hold more allocs than their class admits, "
+            f"e.g. {over[0]}"] if over else []
+'''
+
+WEB = {"cpu_mhz": 250, "memory_mb": 128, "disk_mb": 300}
+BATCH = {"cpu_mhz": 1000, "memory_mb": 4096, "disk_mb": 300}
+
+
+def two_class_deployment(small_cap: int) -> dict:
+    linux = {"kernel.name": "linux"}
+    base = json.loads((ROOT / "benchmarks" / "configs" / "c2m-10k.json")
+                      .read_text())
+    return {
+        "name": "two-class", "source": "a test's own", "chips": 1,
+        "nodes": 256, "datacenters": ["east", "west"],
+        "node_classes": [
+            {"name": "small", "share": 0.75, "cpu_mhz": 4000,
+             "memory_mb": 8192, "disk_mb": 102400, "attributes": linux,
+             "max_allocs": small_cap},
+            {"name": "large", "share": 0.25, "cpu_mhz": 16000,
+             "memory_mb": 65536, "disk_mb": 204800, "attributes": linux,
+             "datacenters": ["east"]}],
+        "job_classes": {
+            "web": {"ask": WEB, "spread": base["spread"],
+                    "constraints": base["constraints"]},
+            "batch": {"ask": BATCH, "type": "batch", "priority": 30}},
+        "standing": [
+            {"job_class": "batch", "fill_share": 0.25, "count": 16},
+            {"job_class": "web", "jobs": 2, "count": 12, "priority": 60}],
+        "may_remain": ["blocked_evals"],
+        "guarantees": base["guarantees"] + [
+            {"rule": "class_alloc_cap",
+             "says": "a small node holds at most its class's max_allocs"}],
+        "reduced": [], "assumed": {},
+    }
+
+
+@pytest.mark.parametrize("small_cap, sound", [(16, True), (0, False)])
+def test_a_new_deployment_is_files_and_entries_and_no_edit(
+        tmp_path, capsys, small_cap, sound):
+    """Two node classes, two job classes of different asks, standing
+    load, a further rule and `may_remain`: a deployment that is not C1M
+    at other numbers is still files and entries. With the added rule's
+    limit set where the placement must break it, `correct` is false."""
+    bench_dir = tmp_path / "benchmarks"
+    shutil.copytree(ROOT / "benchmarks", bench_dir, ignore=shutil.ignore_patterns(
+        "out", "__pycache__", "*.pb"))
+    (bench_dir / "out").mkdir()
+    before = digest(bench_dir)
+
+    config = two_class_deployment(small_cap)
+    (bench_dir / "configs" / "two-class.json").write_text(json.dumps(config))
+    (bench_dir / "generators" / "class_loop.py").write_text(CLASS_LOOP)
+    (bench_dir / "reference" / "rules" / "class_alloc_cap.py").write_text(
+        CLASS_CAP_RULE)
+    (bench_dir / "traffic" / "mixed.json").write_text(json.dumps({
+        "generator": "class_loop", "priority": 50,
+        "deploys": [["web", 8, 50], ["batch", 3, None], ["web", 40, 70]]}))
+    (bench_dir / "layer_metrics" / "batch_evals_mean.mixed.json").write_text(
+        json.dumps({"name": "batch_evals_mean.mixed", "layer": "worker",
+                    "unit": "evals", "moves": "e2e_p50_ms",
+                    "traffic": ["mixed"], "reducer": "mean",
+                    "reads": {"timings": ["nomad.tpu.batch_evals"]}}))
+    bench = json.loads(json.dumps(BENCH))
+    bench["configs"].append({
+        "name": "two-class", "source": "a test's own", "reduced": [],
+        "file": "benchmarks/configs/two-class.json", "why": "added as data"})
+    bench["workloads"].append({
+        "name": "two-class.mixed", "config": "two-class", "traffic": "mixed",
+        "chips": 1, "why": "added as data"})
+    bench["per_layer"].append({
+        "name": "batch_evals_mean.mixed", "unit": "evals",
+        "better": "higher", "source": "program_counter", "layer": "worker",
+        "moves": "e2e_p50_ms", "workloads": ["two-class.mixed"]})
+    for m in bench["end_to_end"]:
+        if m["name"].startswith("e2e_"):
+            m["workloads"].append("two-class.mixed")
+    (tmp_path / "BENCHMARK.json").write_text(json.dumps(bench))
+
+    rc = bench_run.main(
+        ["--workload", "two-class.mixed", "--seed", "3000000021", "--seconds",
+         "1.5", "--trace", "1", "--rehearsal", "--bench-dir", str(bench_dir)],
+        time.monotonic())
+    line = json.loads(capsys.readouterr().out.strip().splitlines()[-1])
+    report = json.loads(
+        (bench_dir / "out" / "two-class.mixed.3000000021.json").read_text())
+    assert rc == 0
+    assert list(line)[-1] == "checks"
+    assert set(line["checks"]) == {
+        f"faults.{g['rule']}" for g in config["guarantees"]} | {
+        "failed", "compiles_in_window", "left_in_flight"}
+    if sound:
+        assert line["correct"] is True and line["failed"] == 0, report
+        assert all(c["value"] == c["limit"] for c in line["checks"].values())
+    else:
+        assert line["correct"] is False and line["failed"] >= 1
+        assert line["checks"]["faults.class_alloc_cap"] == {
+            "value": 1, "limit": 0}
+        assert any("more allocs than their class admits" in f
+                   for f in report["store_faults"])
+        # the rule that was made to fail is the only one that speaks
+        assert len(report["store_faults"]) == 1
+    assert set(line["metrics"]) == {"batch_evals_mean.mixed"}
+    # the standing load went through the front door during set-up: 192
+    # small nodes hold 2 batch asks (by memory) and 64 large ones 16, a
+    # quarter of 1,408 is 352 allocs, 22 jobs of 16; and two web jobs
+    assert report["setup"]["standing_jobs"] == 24
+    assert report["setup"]["registered_s"] <= report["setup"]["standing_s"] \
+        <= report["setup"]["warmed_s"]
+    assert report["ops"]["by_kind"]["small"] >= 3
+    # no arithmetic ideal where nodes or asks differ: no packing_share
+    assert report["packing"] is None
+    after = digest(bench_dir)
+    assert {k: after[k] for k in before} == before  # nothing there was edited
+
+
 def test_benchmark_json_has_exactly_the_contracts_keys():
     assert set(BENCH) == {"command", "paths", "run_seconds", "configs",
                           "workloads", "end_to_end", "per_layer"}
@@ -99,7 +259,11 @@ def test_benchmark_json_has_exactly_the_contracts_keys():
             assert key in on_file, (c["name"], key)
     for w in BENCH["workloads"]:
         assert set(w) == {"name", "config", "traffic", "chips", "why"}
-        assert w["chips"] == 1
+        assert w["chips"] in (1, 4)
+    # four chips only where one chip's runs spread too widely to be
+    # admitted (PERF.md, section 2): the backlog of the north-star shape
+    assert [w["name"] for w in BENCH["workloads"] if w["chips"] == 4] == [
+        "c2m-10k.bulk"]
     for m in BENCH["end_to_end"]:
         assert set(m) - {"workloads"} == {"name", "unit", "better", "bound",
                                           "source"}

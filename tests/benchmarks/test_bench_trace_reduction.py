@@ -97,6 +97,27 @@ def test_idle_gaps_go_to_the_host_span_that_covers_them(recorded):
     assert both["inner"] + both["solve"] == pytest.approx(gaps["solve"])
 
 
+@pytest.mark.parametrize("n_spans, uncovered_ns, names", [
+    # beyond the tenth: it takes the last place, and s9 gives way
+    (12, 5, [f"s{i}" for i in range(9)] + ["unattributed"]),
+    # among the longest anyway: nothing is pushed out
+    (12, 5000, ["unattributed"] + [f"s{i}" for i in range(9)]),
+    (3, 5, ["s0", "s1", "s2", "unattributed"]),
+])
+def test_what_no_span_covers_is_always_among_the_idle_gaps(
+        n_spans, uncovered_ns, names):
+    """A window the device idles through, host spans of falling length
+    side by side, and some ns that no span covers."""
+    quiet = {"devices": {"dev": {"ops": []}}}
+    spans, at = [], 0
+    for i in range(n_spans):
+        spans.append((f"s{i}", at, at + 1000 - 10 * i))
+        at += 1000 - 10 * i
+    gaps = xplane.idle_gaps(quiet, 0, at + uncovered_ns, spans)
+    assert [n for n, _ in gaps] == names
+    assert dict(gaps)["unattributed"] == pytest.approx(uncovered_ns / 1e9)
+
+
 def test_roofline_share_of_the_recorded_solves(recorded):
     trace, stamps, offset, spans = recorded
     t0, t1 = trace["sync_ns"], stamps["end_mono_ns"] + offset
