@@ -497,6 +497,24 @@ def run(args) -> tuple[dict, dict]:
             if waves["C"]["evicted"] <= 0:
                 fail("wave C placed without evicting anything: the "
                      "preempt kernel did not run")
+            # what the benchmark's preempt-fill cell holds a run to
+            # (reference/rules/preemption_lowest_first.py): one victim a
+            # placement that needed one, none above the lowest tier
+            # standing — the cluster is full of priority 20 and 50, so
+            # every victim of the priority-70 wave is a fill job's
+            took = metrics.registry().snapshot()["counters"]
+            needing = int(took.get("nomad.tpu.preempt.placed", 0))
+            above = int(took.get("nomad.tpu.preempt.evicted_above_lowest", 0))
+            not_fill = sorted({a.job_id for a in state.allocs()
+                               if a.desired_status == "evict"
+                               and not a.job_id.startswith("fill-")})
+            waves["C"].update(needed_a_victim=needing,
+                              evicted_above_lowest=above)
+            if waves["C"]["evicted"] != needing or above or not_fill:
+                fail(f"wave C evicted {waves['C']['evicted']} allocs for "
+                     f"{needing} placements that needed a victim, {above} "
+                     "of them above the lowest tier standing; evicted "
+                     f"jobs that are no fill job: {not_fill[:3]}")
 
         store = check_store(state)
         worker = srv.tpu_worker.stats_snapshot()

@@ -283,68 +283,80 @@ def solve_placement_compact(
 # ---------------------------------------------------------------------------
 
 
-def _place_group_preempt(cap, used_exist, prefix_used, carry, xs):
-    """Two-phase scan step (reference analog: generic_sched.go:773
+def _place_group_preempt(cap, used_exist, prefix_used, carry, xs,
+                         fill=_waterfill, total=jnp.sum):
+    """One scan step of the preemption kernel: a waterfill pass for each
+    row of the tier prefix (reference analog: generic_sched.go:773
     selectNextOption's run-again-with-preemption + preemption.go's
-    priority-tier candidate grouping, tensorized):
+    lowest-priority-first candidate order, tensorized):
 
-      phase 1: normal waterfill against remaining real capacity;
-      phase 2: the unplaced remainder retries with capacity EXPANDED by
-        the usage of preemptible priority tiers (tiers strictly more
-        than PRIORITY_DELTA below the group's job priority — `klim`
-        indexes the cumulative tier-usage prefix).
+      pass 0: normal waterfill against remaining real capacity
+        (prefix_used[0] is all-zero);
+      pass k >= 1: the still unplaced remainder retries with capacity
+        EXPANDED by the usage of the k LOWEST priority tiers, as long as
+        the group may preempt that many (`klim`: tiers at least
+        PRIORITY_DELTA below the group's job priority). A pass finishes
+        over the whole cluster before the next one opens, so a tier is
+        touched only once every lower one is spent wherever the group
+        can go — the reference takes victims lowest priority first
+        (Preemptor) and prefers nodes whose victims are of lower
+        priority (PreemptionScoringIterator); pooling all tiers in one
+        pass made every full node tie and the victims' tiers arbitrary.
 
-    The carry tracks `freed` — preemptible usage already claimed by
-    earlier (higher-priority) groups in this batch — so two groups can
-    never double-spend the same victim capacity. Phase-2 placements are
-    returned separately (`take2`): the host picks exact victim allocs
-    per node and emits plan.node_preemptions.
+    The carry tracks `freed` — preemptible usage already claimed, by
+    earlier passes and by earlier (higher-priority) groups in this batch
+    — so two groups can never double-spend the same victim capacity; on
+    a node it stands for the lowest tiers first, which is the order the
+    host picks exact victims in. Placements of the passes that may evict
+    are returned separately: the host picks victim allocs per node and
+    emits plan.node_preemptions.
+
+    `fill` and `total` are the waterfill and the sum over the node axis:
+    the node-sharded variant passes its all-gathered fill and a psum, so
+    both run THIS math.
     """
     used_new, freed = carry
     ask, count, feas_g, bias_g, ucap, klim = xs
+    cap_f = cap.astype(jnp.float32)
+    ask_f = ask.astype(jnp.float32)
 
-    avail_exist = used_exist - freed  # existing usage still standing
-    used_total = avail_exist + used_new
+    def tier_pass(state, tier):
+        used_new, freed, taken, evicting, remaining = state
+        k, prefix_k = tier
+        used_total = used_exist - freed + used_new  # what still stands
+        normal_free = cap - used_total
+        preemptible = jnp.where(
+            k <= klim, jnp.maximum(prefix_k - freed, 0), 0
+        )  # [N, R]
+        units = _units_for(
+            normal_free + preemptible, ask, ucap - taken, feas_g, remaining
+        )
+        score = _score_nodes(
+            cap_f,
+            jnp.maximum(used_total - preemptible, 0).astype(jnp.float32),
+            ask_f,
+            bias_g,
+        )
+        score = jnp.where(units > 0, score, NEG_INF)
+        take = fill(score, units, remaining)
+        claimed = take[:, None] * ask[None, :]
+        # how much of the pass eats into victims (vs leftover free)
+        overflow = jnp.maximum(claimed - jnp.maximum(normal_free, 0), 0)
+        return (
+            used_new + claimed,
+            freed + jnp.minimum(overflow, preemptible),
+            taken + take,
+            evicting + jnp.where(k > 0, take, 0),
+            remaining - total(take),
+        ), None
 
-    # phase 1: normal placement
-    units1 = _units_for(cap - used_total, ask, ucap, feas_g, count)
-    score1 = _score_nodes(
-        cap.astype(jnp.float32),
-        used_total.astype(jnp.float32),
-        ask.astype(jnp.float32),
-        bias_g,
+    zeros = jnp.zeros_like(ucap)
+    tiers = jnp.arange(prefix_used.shape[0], dtype=klim.dtype)
+    (used_new, freed, taken, evicting, _), _ = lax.scan(
+        tier_pass, (used_new, freed, zeros, zeros, count),
+        (tiers, prefix_used),
     )
-    score1 = jnp.where(units1 > 0, score1, NEG_INF)
-    take1 = _waterfill(score1, units1, count)
-    used_new = used_new + take1[:, None] * ask[None, :]
-    used_total = used_total + take1[:, None] * ask[None, :]
-    remaining = count - jnp.sum(take1)
-
-    # phase 2: preemptible capacity (klim = 0 → prefix is all-zero)
-    preemptible = jnp.maximum(
-        lax.dynamic_index_in_dim(prefix_used, klim, 0, keepdims=False) - freed,
-        0,
-    )  # [N, R]
-    normal_free = cap - used_total
-    units2 = _units_for(
-        normal_free + preemptible, ask, ucap - take1, feas_g, remaining
-    )
-    score2 = _score_nodes(
-        cap.astype(jnp.float32),
-        jnp.maximum(used_total - preemptible, 0).astype(jnp.float32),
-        ask.astype(jnp.float32),
-        bias_g,
-    )
-    score2 = jnp.where(units2 > 0, score2, NEG_INF)
-    take2 = _waterfill(score2, units2, remaining)
-
-    # How much of phase 2 actually eats into victims (vs leftover free).
-    overflow = jnp.maximum(
-        take2[:, None] * ask[None, :] - jnp.maximum(normal_free, 0), 0
-    )
-    freed = freed + jnp.minimum(overflow, preemptible)
-    used_new = used_new + take2[:, None] * ask[None, :]
-    return (used_new, freed), (take1 + take2, take2)
+    return (used_new, freed), (taken, evicting)
 
 
 @jax.jit
@@ -470,9 +482,9 @@ def make_sharded_solver_preempt(mesh: Mesh, axis: str = "nodes"):
     units_cap, tier_limit) -> (assign [G,N], assign_evict [G,N], used').
     The tier prefix tensors are sharded over the node axis alongside
     cap/used (each device owns its nodes' preemptible-capacity prefixes);
-    per phase, only the [N] score and unit vectors ride ICI. The two-phase
-    math mirrors _place_group_preempt exactly, so single-chip and sharded
-    solves are equivalence-tested against each other
+    per tier pass, only the [N] score and unit vectors ride ICI. The scan
+    step IS _place_group_preempt, given the all-gathered waterfill and a
+    psum, so single-chip and sharded solves are bit-equal
     (tests/test_tpu_solver.py).
     """
 
@@ -485,62 +497,15 @@ def make_sharded_solver_preempt(mesh: Mesh, axis: str = "nodes"):
             my = lax.axis_index(axis)
             n_local = cap_l.shape[0]
 
-            def step(carry, xs):
-                used_new, freed = carry
-                ask, count, feas_g, bias_g, ucap, klim = xs
-                avail_exist = usede_l - freed
-                used_total = avail_exist + used_new
-
-                # phase 1: normal placement on remaining real capacity
-                units1 = _units_for(cap_l - used_total, ask, ucap, feas_g, count)
-                score1 = _score_nodes(
-                    cap_l.astype(jnp.float32),
-                    used_total.astype(jnp.float32),
-                    ask.astype(jnp.float32),
-                    bias_g,
-                )
-                score1 = jnp.where(units1 > 0, score1, NEG_INF)
-                take1 = _sharded_waterfill(
-                    score1, units1, count, axis, my, n_local
-                )
-                used_new = used_new + take1[:, None] * ask[None, :]
-                used_total = used_total + take1[:, None] * ask[None, :]
-                # remaining must be the GLOBAL remainder: sum local takes
-                placed1 = lax.psum(jnp.sum(take1), axis)
-                remaining = count - placed1
-
-                # phase 2: retry the remainder on preemptible-tier capacity
-                preemptible = jnp.maximum(
-                    lax.dynamic_index_in_dim(prefix_l, klim, 0, keepdims=False)
-                    - freed,
-                    0,
-                )
-                normal_free = cap_l - used_total
-                units2 = _units_for(
-                    normal_free + preemptible, ask, ucap - take1, feas_g,
-                    remaining,
-                )
-                score2 = _score_nodes(
-                    cap_l.astype(jnp.float32),
-                    jnp.maximum(used_total - preemptible, 0).astype(
-                        jnp.float32
-                    ),
-                    ask.astype(jnp.float32),
-                    bias_g,
-                )
-                score2 = jnp.where(units2 > 0, score2, NEG_INF)
-                take2 = _sharded_waterfill(
-                    score2, units2, remaining, axis, my, n_local
-                )
-
-                overflow = jnp.maximum(
-                    take2[:, None] * ask[None, :]
-                    - jnp.maximum(normal_free, 0),
-                    0,
-                )
-                freed = freed + jnp.minimum(overflow, preemptible)
-                used_new = used_new + take2[:, None] * ask[None, :]
-                return (used_new, freed), (take1 + take2, take2)
+            step = functools.partial(
+                _place_group_preempt, cap_l, usede_l, prefix_l,
+                # the decision is replicated from the all-gathered [N]
+                # vectors; the remainder must be the GLOBAL one
+                fill=lambda score, units, count: _sharded_waterfill(
+                    score, units, count, axis, my, n_local
+                ),
+                total=lambda take: lax.psum(jnp.sum(take), axis),
+            )
 
             zeros = jnp.zeros_like(cap_l)
             (used_new, freed), (takes, takes_evict) = lax.scan(

@@ -162,6 +162,9 @@ def build_node_table(
     # usage bucketed by the owning job's priority → preemption tiers
     by_prio: dict[int, np.ndarray] = {}
     cores_free = np.zeros(n, dtype=np.int64)
+    # id(AllocatedResources) -> (cpu, mem, disk, reserved cores); every
+    # keyed object is held by a live alloc of the snapshot being walked
+    grants: dict[int, tuple] = {}
     for i, node in enumerate(nodes):
         index_of[node.id] = i
         avail = node.available_resources()
@@ -177,20 +180,40 @@ def build_node_table(
             u = usage_of(node.id)
             used[i] = (u[0], u[1], u[2])
             continue
+        # The full walk (a batch that may preempt, or asks for cores):
+        # one pass over every live alloc of the cluster, so a node's sums
+        # are kept in plain ints and written once, and the grant of an
+        # alloc is read once for every AllocatedResources OBJECT — the
+        # fast-mint path shares one among all instances of a group.
+        node_tiers: dict[int, list[int]] = {}
+        reserved = 0
         for alloc in allocs_by_node(node.id):
-            r = alloc.comparable_resources()
-            vec = (r.cpu, r.memory_mb, r.disk_mb)
-            used[i] += vec
-            if alloc.resources is not None:
-                cores_free[i] -= sum(
-                    len(tr.reserved_cores)
-                    for tr in alloc.resources.tasks.values()
-                )
+            res = alloc.resources
+            got = grants.get(id(res)) if res is not None else None
+            if got is None:
+                r = alloc.comparable_resources()
+                got = (r.cpu, r.memory_mb, r.disk_mb,
+                       0 if res is None else sum(
+                           len(tr.reserved_cores)
+                           for tr in res.tasks.values()))
+                if res is not None:
+                    grants[id(res)] = got
+            reserved += got[3]
             prio = alloc.job.priority if alloc.job is not None else 50
+            acc = node_tiers.get(prio)
+            if acc is None:
+                node_tiers[prio] = [got[0], got[1], got[2]]
+            else:
+                acc[0] += got[0]
+                acc[1] += got[1]
+                acc[2] += got[2]
+        cores_free[i] -= reserved
+        for prio, acc in node_tiers.items():
             tier = by_prio.get(prio)
             if tier is None:
                 tier = by_prio[prio] = np.zeros((n, NUM_RES), dtype=np.int64)
-            tier[i] += vec
+            tier[i] = acc
+            used[i] += acc
     tier_prios = sorted(by_prio)
     tier_used = (
         np.stack([by_prio[p] for p in tier_prios])

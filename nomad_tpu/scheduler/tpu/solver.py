@@ -855,6 +855,15 @@ class BatchSolver:
                     )
                 dev_state = (None, used_dev)
                 self.chain_accepted = True
+        if (
+            compact and not micro
+            and self._readback_bound(table.cap, used, groups, n) == 0
+        ):
+            # A full cluster: by the exact host arrays no group can
+            # place one instance (never the verdict of a solve that
+            # consumed a chain, whose bound is the groups' counts), so
+            # the batch is failed here and the device never sees it.
+            return self._fail_full_cluster(groups, base_of, n, t0)
         if micro:
             inst, over, used_out = self._run_micro(
                 table, groups, used, total_requests
@@ -973,11 +982,7 @@ class BatchSolver:
                 prev = final_unplaced.get(key)
                 final_unplaced[key] = (grp, (prev[1] if prev else []) + reqs)
 
-        # Failure metrics from the FINAL unplaced set (both passes).
-        for (eval_id, tg_name), (grp, reqs) in final_unplaced.items():
-            metric = group_alloc_metric(grp, n)
-            metric.coalesced_failures = len(reqs) - 1
-            out.failures.setdefault(eval_id, {})[tg_name] = metric
+        self._record_failures(final_unplaced, n)
         # solve_ns excludes any pipeline gap between the two phases
         out.solve_ns = phase_a_ns + (now_ns() - t0)
         from ... import metrics
@@ -986,6 +991,42 @@ class BatchSolver:
         # Alloc materialization joins the host_prep/device/readback stage
         # registry so the bench's breakdown covers the full commit half.
         metrics.time_ns("nomad.tpu.materialize_seconds", mat_ns)
+        metrics.observe("nomad.tpu.solve_groups", out.groups)
+        return out
+
+    def _record_failures(self, final_unplaced: dict, n: int) -> None:
+        """Failure metrics from the FINAL unplaced set (both passes)."""
+        for (eval_id, tg_name), (grp, reqs) in final_unplaced.items():
+            metric = group_alloc_metric(grp, n)
+            metric.coalesced_failures = len(reqs) - 1
+            self._outcome.failures.setdefault(eval_id, {})[tg_name] = metric
+
+    def _fail_full_cluster(self, groups: list[LoweredGroup], base_of: dict,
+                           n: int, t0: int) -> SolveOutcome:
+        """The outcome of a compact solve on a cluster with no room for
+        one instance of any group, without the kernel: every request
+        unplaced, a value-restricted sub-group counted under its base as
+        the spread-relaxation retry would have left it. What the device
+        would have compiled for is worse than its round trip: a batch of
+        evals that re-place evicted allocs has its own group count, row
+        counts and readback width (PERF.md § 6, PR 27)."""
+        from ... import metrics
+
+        out = self._outcome
+        final_unplaced: dict[tuple, tuple[LoweredGroup, list]] = {}
+        for gi, grp in enumerate(groups):
+            if not grp.requests:
+                continue
+            key = (grp.key[0], grp.tg.name)
+            prev = final_unplaced.get(key)
+            final_unplaced[key] = (
+                base_of[gi] if grp.restricted else grp,
+                (prev[1] if prev else []) + list(grp.requests),
+            )
+        self._record_failures(final_unplaced, n)
+        out.solve_ns = now_ns() - t0
+        metrics.incr("nomad.tpu.full_cluster_solves")
+        metrics.time_ns("nomad.tpu.solve_seconds", out.solve_ns)
         metrics.observe("nomad.tpu.solve_groups", out.groups)
         return out
 
@@ -1889,6 +1930,10 @@ class BatchSolver:
             a.nbytes for a in (cap, used, asks_arr, counts, feas, bias, ucap)
         ))
         if use_preempt:
+            import jax
+
+            from ... import metrics
+
             tl = np.zeros(gp, dtype=np.int32)
             tl[:g] = tier_limit[:g]
             tier_limit = tl
@@ -1897,21 +1942,31 @@ class BatchSolver:
             # kernel must not recompile every time the number of
             # distinct alloc priorities in the cluster changes.
             tp = max(4, -(-(t + 1) // 4) * 4)
-            prefix = np.zeros((tp, np_, 3), dtype=np.int32)
-            if t:
-                cum = np.cumsum(
-                    np.clip(table.tier_used, 0, 2**31 - 1), axis=0
+            tctx = trace.current()
+            with trace.span(tctx, "preempt.prefix", cpu=True, tiers=t):
+                prefix = np.zeros((tp, np_, 3), dtype=np.int32)
+                if t:
+                    cum = np.cumsum(
+                        np.clip(table.tier_used, 0, 2**31 - 1), axis=0
+                    )
+                    prefix[1 : t + 1, :n] = cum.astype(np.int32)
+                    # padded tail repeats the full sum so any (unused)
+                    # out-of-range index still reads a valid prefix
+                    prefix[t + 1 :, :n] = cum[-1].astype(np.int32)
+                solverobs.record_transfer(
+                    "h2d", prefix.nbytes + tier_limit.nbytes
                 )
-                prefix[1 : t + 1, :n] = cum.astype(np.int32)
-                # padded tail repeats the full sum so any (unused)
-                # out-of-range index still reads a valid prefix
-                prefix[t + 1 :, :n] = cum[-1].astype(np.int32)
-            solverobs.record_transfer("h2d", prefix.nbytes + tier_limit.nbytes)
+                if tctx is not None:
+                    # a traced solve uploads HERE and awaits it, so that
+                    # the span is the whole cost of the prefix; untraced
+                    # it rides the dispatch like every other input
+                    prefix = jax.block_until_ready(jax.device_put(prefix))
             # factory-built preempt variants (mesh-sharded) ledger under
             # their own name so per-mesh recompiles are attributable
             kname = getattr(
                 self.solve_preempt_fn, "__name__", "solve_placement_preempt"
             )
+            metrics.observe("nomad.tpu.preempt.groups", g)
             assign, assign_evict, used_out = solverobs.timed_call(
                 kname, (kname, np_, gp, tp), self.solve_preempt_fn,
                 cap, used, prefix, asks_arr, counts, feas, bias, ucap,
@@ -1926,25 +1981,37 @@ class BatchSolver:
         return assign, None, used_out, g, n, time.perf_counter()
 
     def _run_kernel_finish(self, pending):
-        """Block on the dispatched dense kernel and read back. The
-        on-device slice happens before the host transfer: the pad region
-        is zeros and the link to the chip is the slow resource."""
+        """Block on the dispatched dense kernel and read back: the same
+        two stages as the compact path (`device.wait`, then `readback`
+        over BOTH dense arrays of the preempt kernel). The padded
+        [gp, np_] arrays cross the link whole and are cut on the host:
+        an on-device slice is a program of its own for every distinct
+        group count."""
+        import jax
+
+        from ... import metrics
+
         assign, assign_evict, used_out, g, n, t_disp = pending
         t_dev0 = now_ns()
+        jax.block_until_ready(used_out)
         self._inject_rtt(t_disp)
+        dev_ns = now_ns() - t_dev0
+        metrics.time_ns("nomad.tpu.device_seconds", dev_ns)
+        trace.stage("device.wait", dev_ns)
+        t_rb0 = now_ns()
         result = (
-            np.asarray(assign[:g, :n]),
-            None if assign_evict is None else np.asarray(assign_evict[:g, :n]),
+            np.asarray(assign)[:g, :n],
+            None if assign_evict is None
+            else np.asarray(assign_evict)[:g, :n],
             used_out,
         )
-        # dense path: blocking transfer includes the device wait, so the
-        # two land as one combined stage span
-        rb_ns = now_ns() - t_dev0
-        trace.stage("device.readback", rb_ns)
+        rb_ns = now_ns() - t_rb0
+        metrics.time_ns("nomad.tpu.readback_seconds", rb_ns)
+        trace.stage("readback", rb_ns)
         solverobs.record_transfer(
             "d2h",
-            result[0].nbytes
-            + (result[1].nbytes if result[1] is not None else 0),
+            assign.nbytes
+            + (assign_evict.nbytes if assign_evict is not None else 0),
             dur_ns=rb_ns, span=True,
         )
         solverobs.sample_device_memory()
@@ -2268,10 +2335,24 @@ class BatchSolver:
         (lowest priority tier first, then closest resource distance —
         the host Preemptor's rules) and reported on outcome.preemptions.
         """
+        from ... import metrics
+
         n = table.n
         free = self._free
         out = self._outcome
         leftovers: dict[int, list] = {}
+        # the preempt path's own account: placements that needed a
+        # victim, victims by their job's priority, and the time spent
+        # choosing them (Σ _pick_victims — one call a placed instance,
+        # between two _build_alloc calls, so a pre-timed stage)
+        n_preempting = 0
+        evicted_by_prio: dict[int, int] = {}
+        above_lowest = 0
+        pick_ns = 0
+        # [T, n, 3] preemptible usage still standing, by tier index
+        tier_left = (np.array(table.tier_used, dtype=np.int64)
+                     if assign_evict is not None else None)
+        tier_of = {p: k for k, p in enumerate(table.tier_prios)}
         for gi, grp in enumerate(groups):
             eval_id = grp.key[0]
             placements = out.placements.setdefault(eval_id, [])
@@ -2279,6 +2360,7 @@ class BatchSolver:
             unplaced: list = []
             a0, a1, a2 = (int(grp.ask[0]), int(grp.ask[1]), int(grp.ask[2]))
             node_indices = np.nonzero(assign[gi, :n])[0]
+            grp_by_tier: dict[int, int] = {}  # this group's victims
             for ni in node_indices:
                 node = table.nodes[ni]
                 take = int(assign[gi, ni])
@@ -2293,7 +2375,9 @@ class BatchSolver:
                     victims: list = []
                     if row[0] < a0 or row[1] < a1 or row[2] < a2:
                         if evict_budget > 0:
+                            t_pick = now_ns()
                             victims = self._pick_victims(table, ni, grp) or []
+                            pick_ns += now_ns() - t_pick
                         if not victims:
                             unplaced.append(req)  # out of exact capacity
                             continue
@@ -2303,6 +2387,7 @@ class BatchSolver:
                         continue
                     if victims:
                         evict_budget -= 1
+                        n_preempting += 1
                         alloc.preempted_allocations = [v.id for v in victims]
                         pre = out.preemptions.setdefault(eval_id, [])
                         for v in victims:
@@ -2312,6 +2397,14 @@ class BatchSolver:
                             row[1] += r.memory_mb
                             row[2] += r.disk_mb
                             pre.append((v, alloc.id))
+                            prio = v.job.priority if v.job is not None else 50
+                            evicted_by_prio[prio] = \
+                                evicted_by_prio.get(prio, 0) + 1
+                            k = tier_of.get(prio)
+                            if k is not None:
+                                tier_left[k, ni] -= (
+                                    r.cpu, r.memory_mb, r.disk_mb)
+                                grp_by_tier[k] = grp_by_tier.get(k, 0) + 1
                     row[0] -= a0
                     row[1] -= a1
                     row[2] -= a2
@@ -2319,7 +2412,47 @@ class BatchSolver:
             unplaced.extend(req_iter)  # instances the kernel never placed
             if unplaced:
                 leftovers[gi] = unplaced
+            if max(grp_by_tier, default=0) > 0:
+                above_lowest += self._victims_above_lowest(
+                    grp, tier_left, grp_by_tier)
+        if assign_evict is not None:
+            n_evicted = sum(evicted_by_prio.values())
+            metrics.incr("nomad.tpu.preempt.placed", n_preempting)
+            metrics.incr("nomad.tpu.preempt.evicted", n_evicted)
+            metrics.incr(
+                "nomad.tpu.preempt.evicted_above_lowest", above_lowest)
+            trace.stage_attrs(
+                "preempt.victims", pick_ns, placed=n_preempting,
+                evicted=n_evicted, above_lowest=above_lowest,
+                by_priority={str(p): c
+                             for p, c in sorted(evicted_by_prio.items())},
+            )
         return leftovers
+
+    def _victims_above_lowest(self, grp: LoweredGroup, tier_left,
+                              by_tier: dict[int, int]) -> int:
+        """How many of a group's victims were taken from a tier while a
+        LOWER preemptible tier still stood where the group could have
+        gone: on a node its feasibility admits, enough of the lower
+        tiers beside the node's free capacity to hold one more instance.
+        Judged once the group is materialized (what stands then stood
+        all the while); unit caps are not looked at. 0 is the kernel's
+        promise (lowest tier first, cluster-wide)."""
+        ask = np.asarray(grp.ask[:3], dtype=np.int64)
+        free = np.asarray(self._free, dtype=np.int64)
+        above = 0
+        for k, count in by_tier.items():
+            if k == 0:
+                continue
+            lower = tier_left[:k].sum(axis=0)  # [n, 3]
+            could = (
+                grp.feasible
+                & lower.any(axis=1)
+                & ((free + lower) >= ask[None, :]).all(axis=1)
+            )
+            if could.any():
+                above += count
+        return above
 
     def _pick_victims(self, table, ni: int, grp: LoweredGroup):
         """Exact victim selection for one instance on one node: free
@@ -2368,8 +2501,21 @@ class BatchSolver:
                 and freed[1] >= shortage[1]
                 and freed[2] >= shortage[2]
             ):
-                return picks
-        return None
+                break
+        else:
+            return None
+        # no more victims than the shortage needs: drop, highest
+        # priority first, every pick the others cover without (the
+        # reference's filterSuperset; with equal asks the greedy walk
+        # above already stops at one)
+        for a in reversed(picks[:-1]):
+            r = a.comparable_resources()
+            rest = (freed[0] - r.cpu, freed[1] - r.memory_mb,
+                    freed[2] - r.disk_mb)
+            if all(rest[i] >= shortage[i] for i in range(3)):
+                picks.remove(a)
+                freed = list(rest)
+        return picks
 
     def _live_allocs(self, node_id: str):
         """Non-terminal allocs minus this batch's plan-stops — the same

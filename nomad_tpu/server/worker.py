@@ -665,6 +665,16 @@ class TPUBatchWorker:
         placement that the live chain tensor never saw is fed back to
         the next chained solve as usage deltas (_solve_batch)."""
         metrics.incr("nomad.worker.lane.interactive")
+        if ev.create_time:
+            # the lane's OTHER clock: from the eval's creation (the
+            # job's register, wall clock) to here — the broker, the
+            # solve thread busy lowering a mega-batch that no arrival
+            # interrupts, and the time held. interactive_seconds (below,
+            # in _commit) starts only at the dequeue.
+            metrics.observe(
+                "nomad.worker.lane.wait_seconds",
+                max(time.time_ns() - ev.create_time, 0) / 1e9,
+            )
         batch = [(ev, token)]
         bctx = trace.start_trace("tpu.interactive")
         if bctx is not None:
@@ -1002,6 +1012,11 @@ class TPUBatchWorker:
             "nomad.tpu.commit_seconds", (trace.now_ns() - t0) / 1e9
         )
         if lane == "interactive":
+            if not all_full:
+                # the applier cut the lane's plan against a commit that
+                # landed first (the in-flight batch's, usually): the
+                # eval is retried for what it lost
+                metrics.incr("nomad.worker.lane.trimmed")
             # lane-ledger record: an interactive commit that landed
             # while a mega-batch chain is in flight is invisible to the
             # chained used' tensor — remember its per-node deltas so the
