@@ -1049,9 +1049,13 @@ class BatchSolver:
         which the caller acts on AFTER the span closed: ("dense",
         _Lowered); ("done", None) — nothing to solve, `out` is final;
         ("host", (asks, total_requests)) — the host iterator stack takes
-        the batch (past the microsolve's bound its lowering is wasted
-        once); ("sticky", indices of the asks that solve on the host,
-        the rest dense)."""
+        the batch: a small batch past the microsolve's bound leaves as
+        soon as its node universe and its asks prove it past (nothing
+        lowered, `nomad.tpu.lower_skipped`); only one whose spread
+        splits carry it past is lowered first; ("sticky", indices of
+        the asks that solve on the host, the rest dense)."""
+        from ... import metrics
+
         tctx = trace.current()
         with trace.span(tctx, "lower", cpu=True):
             self._batch_has_cores = any(
@@ -1112,17 +1116,7 @@ class BatchSolver:
                 for ask in asks:
                     key = tuple(ask.job.datacenters)
                     if key not in dc_cache:
-                        if self.resident is not None:
-                            # warm node-list cache keyed by the nodes-
-                            # table index (ResidentClusterState.
-                            # ready_nodes)
-                            dc_cache[key] = self.resident.ready_nodes(
-                                self.state, key
-                            )[0]
-                        else:
-                            dc_cache[key] = ready_nodes_in_dcs(
-                                self.state, ask.job.datacenters
-                            )[0]
+                        dc_cache[key] = self._ready_nodes(key)[0]
                 if len(dc_cache) == 1:
                     nodes = next(iter(dc_cache.values()))
                 else:
@@ -1135,6 +1129,13 @@ class BatchSolver:
                     for ask in asks:
                         self._fail_all(out, ask, {})
                     return "done", None
+                if micro_wanted and self._past_micro_bound(nodes, asks):
+                    # the host stack takes it whatever the lowering
+                    # would say, and uses none of it
+                    metrics.observe(
+                        "nomad.tpu.lower_skipped", total_requests
+                    )
+                    return to_host
                 tab = self._lower_table(nodes, asks, micro_wanted)
                 if tab is None:
                     return to_host
@@ -1183,8 +1184,10 @@ class BatchSolver:
             # Microsolve verdict (the interactive fast path): the numpy
             # kernel replaces the device dispatch when the problem is
             # tiny. Past the n·g bound the batch keeps its historical
-            # host-stack route — the lowering work above is wasted once,
-            # on the rare small-requests-huge-cluster shape.
+            # host-stack route. What reaches this line past the bound
+            # is a batch that only its spread splits carried there
+            # (nodes × asks was within it, above): its lowering is
+            # wasted once.
             micro = (
                 micro_wanted
                 and compact
@@ -1197,6 +1200,27 @@ class BatchSolver:
                 total_requests, used, tier_limit, use_preempt, compact,
                 micro,
             )
+
+    def _past_micro_bound(self, nodes: list, asks: list[GroupAsk]) -> bool:
+        """The microsolve's n·g bound, as soon as it is decided: every
+        ask with a task group and a request lowers to at least one
+        group, so nodes × such asks is a lower bound of table.n ×
+        len(groups) — True only where the verdict after lowering would
+        be the host stack too."""
+        return len(nodes) * sum(
+            1 for ask in asks
+            if ask.requests
+            and ask.job.lookup_task_group(ask.tg_name) is not None
+        ) > self.config.micro_solve_threshold
+
+    def _ready_nodes(self, datacenters: tuple) -> tuple[list, dict]:
+        """(ready nodes, per-datacenter counts) of one datacenter set,
+        for the lowering and the host stack alike: the resident state's
+        warm list (keyed by the nodes-table index) where there is one,
+        a walk of the nodes table otherwise."""
+        if self.resident is not None:
+            return self.resident.ready_nodes(self.state, datacenters)
+        return ready_nodes_in_dcs(self.state, list(datacenters))
 
     @staticmethod
     def _sticky_asks(asks: list[GroupAsk]) -> set:
@@ -1478,8 +1502,7 @@ class BatchSolver:
             key = tuple(ask.job.datacenters)
             cached = dc_cache.get(key)
             if cached is None:
-                cached = ready_nodes_in_dcs(self.state, ask.job.datacenters)
-                dc_cache[key] = cached
+                cached = dc_cache[key] = self._ready_nodes(key)
             nodes, dc_counts = cached
             if not nodes:
                 self._fail_all(out, ask, dc_counts)
