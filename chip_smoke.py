@@ -33,7 +33,7 @@ It writes two lines to stdout, and only when every check passed. The
 first is the report, one JSON object: mode, versions, per-wave counts,
 compiles and signatures, density, parity, cache hits. Its figures are
 set-up facts (counts, compile seconds, wall per wave) — nothing is
-divided by time; the benchmark is bench.py's business. The LAST line is
+divided by time; speed is benchmarks/run.py's business. The LAST line is
 the verdict the driver reads, exactly
 `{"ok": true, "device": {"platform": ..., "kind": ..., "count": ...}}`,
 the device as jax reports it.
@@ -59,7 +59,7 @@ from collections import Counter
 from importlib import metadata
 
 DCS = ("dc1", "dc2", "dc3", "dc4")
-CPU_MHZ, MEM_MB = 250, 128  # the c2m ask (bench.py add_jobs)
+CPU_MHZ, MEM_MB = 250, 128  # the c2m ask (benchmarks/configs/c2m-10k.json)
 
 # count = allocs per job; sample = wave-A jobs placed alone first
 FULL = dict(nodes=10_000, count=1000, jobs_a=100, sample=4, jobs_b=50,
@@ -142,7 +142,8 @@ class _OneServerCluster:
 
 
 def c2m_job(job_id: str, count: int, priority: int = 50):
-    """bench.py add_jobs' constrained c2m job."""
+    """The constrained c2m job: kernel-name constraint, datacenter
+    spread (nomad_tpu.testing.build_cluster's, over HTTP)."""
     from nomad_tpu import mock
     from nomad_tpu.structs import Constraint, Spread
 
@@ -177,7 +178,7 @@ def live_allocs(state, job) -> list:
 
 
 def density_of(state, jobs) -> tuple[int, int]:
-    """(live allocs, nodes they touch) — bench.py's density()."""
+    """(live allocs, nodes they touch)."""
     placed, nodes = 0, set()
     for job in jobs:
         for a in live_allocs(state, job):

@@ -18,8 +18,9 @@ Every hook is a single module-attribute check when no plane is
 installed; nothing here touches production behavior until
 ``install(FaultPlane(seed=...))``.
 
-``bench.py`` refuses to gate while :func:`env_knobs_active` is
-non-empty, so injected faults can never pollute a BENCH capture.
+``benchmarks/run.py`` and ``chip_smoke.py`` refuse to run while
+:func:`env_knobs_active` is non-empty, so no number is taken under
+injected faults.
 
 The scenario harness (ChaosCluster: scripted kill/partition/heal with
 the no-acked-write-lost / no-duplicate-alloc / convergence invariants)
@@ -62,8 +63,8 @@ def active() -> bool:
 
 def env_knobs_active() -> list[str]:
     """Names of NOMAD_TPU_INJECT_* env knobs currently set non-zero,
-    plus a sentinel for an installed fault plane — the bench gate
-    refuses to certify a capture while any of these are live."""
+    plus a sentinel for an installed fault plane — ``benchmarks/run.py``
+    and ``chip_smoke.py`` refuse to run while any of these are live."""
     out = [
         k
         for k, v in os.environ.items()

@@ -38,10 +38,10 @@ class SchedulerConfig:
         memory_oversubscription: bool = False,
         backend: str = "host",  # host | tpu — which placement backend to use
         small_batch_threshold: int = 48,
-        inject_device_latency_s: Optional[float] = None,
+        inject_device_latency_s: float = 0.0,
         soa_placements: Optional[bool] = None,
         mesh_devices: Optional[int] = None,
-        micro_solve_threshold: Optional[int] = None,
+        micro_solve_threshold: int = 8192,
     ) -> None:
         import os
 
@@ -49,12 +49,7 @@ class SchedulerConfig:
         # batch whose node-count x group-count product is at or below
         # this solves with the numpy compact kernel (scheduler/tpu/
         # microsolve.py) — dense-path semantics, zero device round-trip.
-        # 0 disables (every small batch keeps the host iterator stack);
-        # NOMAD_TPU_MICRO_NG overrides.
-        if micro_solve_threshold is None:
-            micro_solve_threshold = int(
-                os.environ.get("NOMAD_TPU_MICRO_NG", "8192") or 0
-            )
+        # 0 disables (every small batch keeps the host iterator stack).
         self.micro_solve_threshold = micro_solve_threshold
 
         # Multi-chip: shard the solve's node axis over this many devices
@@ -92,12 +87,8 @@ class SchedulerConfig:
         # Simulated device round-trip added to every dense kernel solve
         # (docs/pipeline.md): a sleep model of a serially-busy device,
         # so the worker's solve/commit overlap can be exercised on
-        # XLA:CPU (ROADMAP D1 removes it). Settable per-config or via
-        # NOMAD_TPU_INJECT_DEVICE_LATENCY_S.
-        if inject_device_latency_s is None:
-            inject_device_latency_s = float(
-                os.environ.get("NOMAD_TPU_INJECT_DEVICE_LATENCY_S", "0") or 0
-            )
+        # XLA:CPU (ROADMAP D1 removes it). No environment variable sets
+        # it: two tests pass it, and benchmarks/ reads it to refuse it.
         self.inject_device_latency_s = inject_device_latency_s
 
     def preemption_enabled(self, scheduler_type: str) -> bool:

@@ -1,8 +1,8 @@
 """Where the solver runs, and where its compiled programs are kept.
 
-Two decisions every solver-side entry point (the TPU worker, bench.py,
-chip_smoke.py) shares, made here once so none of them can make it
-differently:
+Two decisions every solver-side entry point (the TPU worker,
+benchmarks/run.py, chip_smoke.py) shares, made here once so none of
+them can make it differently:
 
   * `resolve_device()` — the backend is whatever jax resolves, and a
     resolution that lands on XLA:CPU is an ERROR unless `jax_platforms`

@@ -11,7 +11,7 @@ Two operating modes:
     scheduler); the solver batch is just that one eval's groups.
   * solve_eval_batch() — the high-throughput path: many pending evals
     solved in ONE kernel invocation, emitting one plan per eval. The
-    server's TPU worker (and bench.py) drive this.
+    server's TPU worker drives this.
 """
 
 from __future__ import annotations

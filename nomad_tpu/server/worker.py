@@ -293,8 +293,8 @@ class TPUBatchWorker:
     commit — the same depth-1 optimistic overlap the reference plan
     applier runs (plan_apply.go:54-63), won here at the worker layer
     where the GIL releases during the device round-trip. `pipeline=False`
-    degrades to the old solve-then-commit loop (the bench's
-    non-overlapped comparator)."""
+    degrades to the old solve-then-commit loop (tests drive it as a
+    one-thread loop)."""
 
     def __init__(
         self,

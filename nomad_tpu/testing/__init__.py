@@ -1,3 +1,3 @@
 from . import chaos  # noqa: F401  (scenario harness + fault-plane re-export)
-from .harness import Harness, RejectPlanHarness
+from .harness import Harness, RejectPlanHarness, build_cluster
 from .waits import wait_for_state

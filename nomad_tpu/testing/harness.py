@@ -81,3 +81,51 @@ class RejectPlanHarness(Harness):
         self.plans.append(plan)
         result = PlanResult(refresh_index=self.state.latest_index())
         return result, self.state.snapshot()
+
+
+def build_cluster(n_nodes: int, n_jobs: int, count: int, constrained: bool,
+                  job_prefix: str = "job", cpu: int = 250, mem: int = 128):
+    """A :class:`Harness` holding ``n_nodes`` mock nodes (4,000 MHz /
+    8,192 MB, dealt round-robin over 4 datacenters) and ``n_jobs`` mock
+    jobs of one group of ``count`` asking ``cpu`` MHz / ``mem`` MB;
+    ``constrained`` adds the c2m job's kernel-name constraint and
+    datacenter spread. Returns ``(harness, jobs)``."""
+    from .. import mock
+    from ..gctune import paused_gc
+    from ..structs import Constraint, Spread
+    from ..structs.node_class import compute_node_class
+
+    dcs = ["dc1", "dc2", "dc3", "dc4"]
+    # One bounded allocation burst (the nodes + the job set), frozen on
+    # exit: the built cluster IS resident heap, so it goes straight to
+    # the permanent generation instead of being young-gen-scanned (with
+    # every gc callback, jax's included) at the first post-build
+    # collection (gctune.paused_gc).
+    with paused_gc(freeze_on_exit=True):
+        h = Harness()
+        for i in range(n_nodes):
+            n = mock.node()
+            n.datacenter = dcs[i % len(dcs)]
+            n.resources.cpu = 4000
+            n.resources.memory_mb = 8192
+            n.computed_class = compute_node_class(n)
+            h.state.upsert_node(h.next_index(), n)
+        jobs = []
+        for j in range(n_jobs):
+            job = mock.job(id=f"{job_prefix}-{j}")
+            job.datacenters = dcs
+            tg = job.task_groups[0]
+            tg.count = count
+            tg.tasks[0].resources.cpu = cpu
+            tg.tasks[0].resources.memory_mb = mem
+            tg.tasks[0].resources.networks = []
+            if constrained:
+                job.constraints.append(
+                    Constraint("${attr.kernel.name}", "linux", "=")
+                )
+                job.spreads = [
+                    Spread(attribute="${node.datacenter}", weight=50)
+                ]
+            h.state.upsert_job(h.next_index(), job)
+            jobs.append(job)
+    return h, jobs

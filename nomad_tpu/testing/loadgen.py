@@ -17,8 +17,8 @@ as a well-behaved SDK would), records what was offered vs accepted vs
 throttled, and finally drains + reads the end-to-end latency
 histograms from the production metrics registry.
 
-:func:`run_soak` is the one-call harness bench.py's ``soak`` config and
-the tier-1 mini-soak test share: boot a durable ChaosCluster under a
+:func:`run_soak` is the one-call harness the tier-1 mini-soak and the
+slow-marked 10-minute soak share: boot a durable ChaosCluster under a
 seeded FaultPlane schedule, configure the overload knobs, run the
 generator, then assert the ChaosCluster invariants (no acked write
 lost, no duplicate alloc, convergence) and report shed/throttle/latency
@@ -501,7 +501,7 @@ class LoadGen:
 
 # ---------------------------------------------------------------------------
 # The soak harness: ChaosCluster + seeded fault schedule + LoadGen +
-# invariants. Shared by bench.py's `soak` config and the tier-1 mini-soak.
+# invariants. Shared by the tier-1 mini-soak and the 10-minute soak.
 # ---------------------------------------------------------------------------
 
 

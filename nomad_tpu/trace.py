@@ -63,7 +63,6 @@ __all__ = [
     "set_current",
     "set_enabled",
     "span",
-    "stack_self_times",
     "stage",
     "stage_attrs",
     "start_trace",
@@ -405,8 +404,9 @@ class TraceContext:
     ) -> Span:
         """A stage measured as a duration ending now. Marked pretimed:
         the recording thread's active-span stack never held it, so the
-        host profiler attributed those samples to the ENCLOSING span —
-        :func:`stack_self_times` needs to tell the two apart."""
+        host profiler attributed those samples to the ENCLOSING span;
+        a comparison with the profiler runs :func:`self_times` over the
+        spans without the marker."""
         end = now_ns()
         attrs = dict(attrs) if attrs else {}
         attrs.setdefault("pretimed", 1)
@@ -632,7 +632,7 @@ def set_current(ctx: Optional[TraceContext]) -> Optional[TraceContext]:
     # host-profiler span correlation: with no child span open yet, the
     # thread's work belongs to the trace ROOT (a solve running under
     # `use(ctx)` before any stage span opens must attribute to
-    # "tpu.batch"/"bench.batch", not "-")
+    # "tpu.batch", not "-")
     tid = threading.get_ident()
     if ctx is None:
         _thread_spans.pop(tid, None)
@@ -792,24 +792,6 @@ def self_times(trace: dict) -> dict[str, int]:
         )
         out[s["name"]] = out.get(s["name"], 0) + max(0, dur - child_cover)
     return out
-
-
-def stack_self_times(trace: dict) -> dict[str, int]:
-    """:func:`self_times` over the STACK-PARENTED spans only: pre-timed
-    stage spans (``add_stage`` — host_prep, readback, materialize, the
-    solver.compile/transfer attributions) are dropped before the child-
-    interval subtraction. This is the trace-side quantity comparable to
-    the host profiler's span attribution: a sampler attributes the
-    wall time of a pre-timed stage to the span the recording thread had
-    OPEN (the stage never pushed the stack), so plain self_times — which
-    subtracts the stage from its parent — would disagree with the
-    profiler by exactly the stage's duration (bench span-agreement,
-    docs/profiling.md)."""
-    spans = [
-        s for s in trace.get("spans", ())
-        if not (s.get("attrs") or {}).get("pretimed")
-    ]
-    return self_times({**trace, "spans": spans})
 
 
 def coverage(trace: dict) -> float:

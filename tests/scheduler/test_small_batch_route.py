@@ -365,3 +365,18 @@ def test_the_host_stack_sees_the_same_nodes_with_and_without_a_resident_state(
         assert placed(out) == placed(outs[0])
         assert [a.metrics.nodes_available for a in out.placements[ev.id]] \
             == [want_counts] * 4
+
+
+def test_the_bound_and_the_modelled_device_are_not_read_from_the_environment(
+        monkeypatch):
+    """No caller set either variable: the microsolve's bound is the
+    constant, the device model is off, unless the constructor is told."""
+    monkeypatch.setenv("NOMAD_TPU_MICRO_NG", "17")
+    monkeypatch.setenv("NOMAD_TPU_INJECT_DEVICE_LATENCY_S", "0.5")
+    config = SchedulerConfig()
+    assert config.micro_solve_threshold == 8192
+    assert config.inject_device_latency_s == 0.0
+    told = SchedulerConfig(micro_solve_threshold=BOUND,
+                           inject_device_latency_s=0.25)
+    assert told.micro_solve_threshold == BOUND
+    assert told.inject_device_latency_s == 0.25

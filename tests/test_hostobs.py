@@ -626,9 +626,7 @@ def test_e2e_host_attribution_acceptance(tmp_path, capsys):
     threads profile as DISTINCT roles, samples carry worker span names,
     nomad.host.* / nomad.runtime.* ride /v1/metrics, and the same
     snapshot renders via `operator profile status` and the Host row in
-    `operator top`. (The 15% span-agreement and >= 0.8 coverage gates
-    run in bench.py's host_attribution block, where the sampling window
-    is seconds, not milliseconds.)"""
+    `operator top`."""
     from nomad_tpu.agent import Agent, AgentConfig
     from nomad_tpu.api.client import NomadClient
     from nomad_tpu.cli.main import (
@@ -739,14 +737,13 @@ def test_e2e_host_attribution_acceptance(tmp_path, capsys):
 
 
 OVERHEAD_SCRIPT = r"""
-import json, random, sys, time
-sys.path.insert(0, %r)
+import json, random, time
 
-from bench import build_cluster
 from nomad_tpu import hostobs, mock
 from nomad_tpu.scheduler.tpu import solve_eval_batch
+from nomad_tpu.testing import build_cluster
 
-# The acceptance criterion's two workloads: the bench smoke config
+# The acceptance criterion's two workloads: 10 nodes, one job of 10
 # (host fast path) and a c2m-SHAPED constrained/spread batch (scaled so
 # a clean-subprocess best-of converges inside CI time; the shape — not
 # the node count — decides which code runs). "Profiled" means the
@@ -814,7 +811,7 @@ def test_profiled_throughput_vs_unprofiled_gate():
     attempts = []
     for _ in range(3):
         proc = subprocess.run(
-            [sys.executable, "-c", OVERHEAD_SCRIPT % REPO_ROOT],
+            [sys.executable, "-c", OVERHEAD_SCRIPT],
             capture_output=True,
             text=True,
             timeout=300,

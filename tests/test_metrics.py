@@ -447,20 +447,19 @@ def test_datadog_sink_tags():
 
 
 # ---------------------------------------------------------------------------
-# Throughput gate: histograms vs the pre-change sample path (bench smoke)
+# Throughput gate: histograms vs the pre-change sample path (smoke size)
 # ---------------------------------------------------------------------------
 
 
 HIST_OVERHEAD_SCRIPT = r"""
-import json, random, sys, time
-sys.path.insert(0, %r)
+import json, random, time
 
-from bench import build_cluster
 from nomad_tpu import mock, metrics
 from nomad_tpu.metrics import Registry
 from nomad_tpu.scheduler.tpu import solve_eval_batch
+from nomad_tpu.testing import build_cluster
 
-h, jobs = build_cluster(10, 1, 10, False)  # the bench smoke config
+h, jobs = build_cluster(10, 1, 10, False)  # 10 nodes, one job of 10
 snap = h.snapshot()
 evals = [mock.eval_for_job(j) for j in jobs]
 solve_eval_batch(snap, h, evals)  # warm before either measured side
@@ -500,7 +499,7 @@ print(json.dumps({
 
 
 def test_histogram_throughput_vs_sample_path_smoke():
-    """Acceptance gate: bench-smoke scheduling throughput with the
+    """Acceptance gate: smoke-size scheduling throughput with the
     histogram registry stays >= 0.95x the pre-change count/sum sample
     path (Registry(histograms=False), kept as the comparator). Measured
     in a CLEAN subprocess — inside the full suite, daemon threads left
@@ -518,7 +517,7 @@ def test_histogram_throughput_vs_sample_path_smoke():
     attempts = []
     for _ in range(3):
         proc = subprocess.run(
-            [sys.executable, "-c", HIST_OVERHEAD_SCRIPT % REPO_ROOT],
+            [sys.executable, "-c", HIST_OVERHEAD_SCRIPT],
             capture_output=True,
             text=True,
             timeout=300,

@@ -4,13 +4,12 @@ live fault-injected cluster (nomad_tpu/testing/loadgen.py).
 Tier-1 runs the fast seeded mini-soak (~15s wall: a few seconds of
 traffic at an offered rate far above what the overload knobs admit,
 under background rpc-drop / lost-response / slow-fsync faults), gating
-on the same evidence the bench `soak` config gates on: ChaosCluster
-invariants hold, the cluster converges, admission control demonstrably
-engaged, e2e p99 bounded, and the broker drains once arrivals stop.
+on what `run_soak` reports: ChaosCluster invariants hold, the cluster
+converges, admission control demonstrably engaged, e2e p99 bounded, and
+the broker drains once arrivals stop.
 
 The 10-minute acceptance-shaped soak (partition/heal cycle included) is
-slow-marked; run it with `pytest -m 'soak and slow'` or via
-`BENCH_SOAK_S=600 BENCH_CONFIG=soak python bench.py`.
+slow-marked; run it with `pytest -m 'soak and slow'`.
 """
 
 from __future__ import annotations
@@ -82,9 +81,7 @@ def test_mini_soak_overload_with_faults(tmp_path):
     assert report["fault_schedule"] and report["fired_faults"]
     # cluster observability (clusterobs.py): server CPU was measured
     # and attributed per simulated node, and the per-source ledger
-    # covered the served handler seconds — the bench `soak` config
-    # gates on exactly these stats (server_cpu_per_node bounded,
-    # coverage >= 0.8)
+    # covered the served handler seconds (coverage >= 0.8)
     cpu = report["server_cpu"]
     assert cpu["cpu_seconds"] > 0, cpu
     assert report["server_cpu_per_node"] == cpu["per_node_cpu_seconds"]
@@ -133,7 +130,7 @@ def test_mini_soak_seed_fixes_fault_schedule(tmp_path):
 def test_soak_sustained_10min(tmp_path):
     """The acceptance-shaped soak: 10 minutes of sustained overload
     with node churn, dispatch traffic, background faults, AND a
-    partition/heal cycle. Gates exactly like the bench `soak` config."""
+    partition/heal cycle. Gates exactly like the mini-soak."""
     report = run_soak(
         str(tmp_path),
         duration_s=600.0,

@@ -189,8 +189,8 @@ def test_compile_and_transfer_spans_on_live_trace():
         assert spans["solver.transfer"].attrs["direction"] == "d2h"
         assert spans["solver.transfer"].attrs["bytes"] == 4096
         # stage spans carry the pretimed marker (they never sat on the
-        # active-span stack — trace.stack_self_times / the host
-        # profiler's span attribution depend on telling them apart)
+        # active-span stack — trace.self_times over the spans without
+        # it is what the host profiler's span attribution compares to)
         assert spans["solver.transfer"].attrs["pretimed"] == 1
         assert spans["solver.compile"].attrs["pretimed"] == 1
         # exactly one compile span: the cache hit emitted nothing
@@ -597,21 +597,20 @@ def test_e2e_solver_observability_acceptance(tmp_path, capsys):
 
 
 # ---------------------------------------------------------------------------
-# Overhead gate: instrumented vs uninstrumented throughput (bench smoke)
+# Overhead gate: instrumented vs uninstrumented throughput (smoke size)
 # ---------------------------------------------------------------------------
 
 
 OBS_OVERHEAD_SCRIPT = r"""
 import json, random, statistics, sys, time
-sys.path.insert(0, %r)
 
-from bench import build_cluster
 from nomad_tpu import mock, solverobs
 from nomad_tpu.scheduler.tpu import solve_eval_batch
+from nomad_tpu.testing import build_cluster
 
 # Two workloads, each built AND measured in isolation (a second live
 # cluster's heap during the other's bursts skews the tiny smoke
-# timings): the bench smoke config (host fast path — the acceptance
+# timings): 10 nodes, one job of 10 (host fast path — the acceptance
 # criterion's comparator), and a dense-path batch past
 # small_batch_threshold so the device-side instrumentation
 # (timed_call / record_batch / record_transfer / memory census) is
@@ -639,7 +638,7 @@ def measure(n_nodes, n_jobs, count, pairs=24):
         t0 = time.perf_counter()
         solve_eval_batch(snap, h, evals)
         t1 = min(t1, time.perf_counter() - t0)
-    # Size bursts to ~60ms of wall so scheduler jitter (~ +-20%% on a
+    # Size bursts to ~60ms of wall so scheduler jitter (~ +-20% on a
     # single millisecond solve even on an idle box) averages down
     # WITHIN a burst; adapts to this box's speed-of-the-minute.
     reps = max(5, int(0.06 / max(t1, 1e-4)))
@@ -744,7 +743,7 @@ def _overhead_gate(workloads: set, attempts: int):
             [
                 sys.executable,
                 "-c",
-                OBS_OVERHEAD_SCRIPT % REPO_ROOT,
+                OBS_OVERHEAD_SCRIPT,
                 json.dumps(sorted(remaining)),
             ],
             capture_output=True,

@@ -958,12 +958,11 @@ def test_watch_route_seconds_is_observed_once_per_routed_write():
 
 
 OVERHEAD_SCRIPT = r"""
-import json, sys, time
-sys.path.insert(0, %r)
+import json, time
 
-from bench import build_cluster
 from nomad_tpu import mock, trace
 from nomad_tpu.scheduler.tpu import solve_eval_batch
+from nomad_tpu.testing import build_cluster
 
 h, jobs = build_cluster(200, 10, 30, constrained=True, job_prefix="ovh")
 snap = h.snapshot()
@@ -975,7 +974,7 @@ def once(enabled):
     trace.set_enabled(enabled)
     try:
         evals = [mock.eval_for_job(j) for j in jobs]
-        ctx = trace.start_trace("bench.batch")
+        ctx = trace.start_trace("gate.batch")
         t0 = time.perf_counter()
         with trace.use(ctx):
             solve_eval_batch(snap, h, evals)
@@ -996,13 +995,13 @@ def once(enabled):
 # RAISE a side's samples, never lower its min.
 import random
 
-order = [False, True] * 16
+order = [False, True] * 64
 random.shuffle(order)
 best = {False: float("inf"), True: float("inf")}
 for enabled in order:
     best[enabled] = min(best[enabled], once(enabled))
 ratio = best[False] / best[True]  # >1 means enabled was FASTER
-traces = trace.recorder().list(name="bench.batch")
+traces = trace.recorder().list(name="gate.batch")
 spans = (
     {s["name"] for s in trace.recorder().get(traces[0]["id"])["spans"]}
     if traces
@@ -1037,7 +1036,7 @@ def test_tracing_overhead_within_5pct():
     attempts = []
     for _ in range(3):
         proc = subprocess.run(
-            [sys.executable, "-c", OVERHEAD_SCRIPT % repo],
+            [sys.executable, "-c", OVERHEAD_SCRIPT],
             capture_output=True,
             text=True,
             timeout=300,
