@@ -19,6 +19,9 @@ by construction, not by reimplementation.
 from __future__ import annotations
 
 from dataclasses import dataclass, field
+from bisect import bisect_left
+from itertools import compress
+from operator import is_not
 from typing import Optional
 
 import numpy as np
@@ -136,6 +139,86 @@ class NodeTable:
         return m
 
 
+def alloc_priority(alloc) -> int:
+    """The tier an alloc stands in: its job's priority, 50 without a
+    job (the store's rule for its usage by priority)."""
+    return alloc.job.priority if alloc.job is not None else 50
+
+
+class TierSlabs:
+    """Committed usage by (node, priority) as the dense slabs a NodeTable
+    carries, kept current from the store's index (`node_tier_usage`) by
+    what changed since the last read.
+
+    The store replaces a node's tiers wholesale, so a node whose entry
+    is the SAME tuple as last time has not changed: the comparison runs
+    over the whole cluster without running Python for a node, and only
+    the nodes a plan touched are rewritten. That matters more than the
+    arithmetic suggests: a lowering shares the interpreter with every
+    other thread of the server, and waits its turn again after each
+    stretch of bytecode and each large numpy call. A fresh instance has
+    read nothing, so its first read writes every node that holds
+    anything — the one-off form (no resident state) and the first solve
+    after the node universe changed."""
+
+    def __init__(self, index_of: dict[str, int]) -> None:
+        self.index_of = index_of
+        self.ids = list(index_of)  # node order
+        n = len(self.ids)
+        self.rows: list[tuple] = [()] * n  # a node's tiers as last read
+        self.prios: list[int] = []  # ascending; a slab each
+        # [T, N, cpu mem disk allocs]: a tier is where its allocs are
+        self.slabs = np.zeros((0, n, NUM_RES + 1), dtype=np.int64)
+        self.nodes_in: list[int] = []  # nodes that hold an alloc, by tier
+
+    def _slab(self, prio: int) -> int:
+        k = bisect_left(self.prios, prio)
+        if k == len(self.prios) or self.prios[k] != prio:
+            self.prios.insert(k, prio)
+            self.nodes_in.insert(k, 0)
+            self.slabs = np.insert(self.slabs, k, 0, axis=0)
+        return k
+
+    def read(self, tiers_of, tier_adj: dict) -> tuple[list[int], np.ndarray]:
+        """(tier_prios, tier_used [T, N, NUM_RES]) for one batch.
+        `tiers_of`: the snapshot's bulk reader, node ids -> for each its
+        ((priority, cpu, mem, disk, allocs), ...); `tier_adj`: the
+        batch's own view of a few nodes, node id -> {priority: [cpu,
+        mem, disk, allocs]} to add (its stops negative). The arrays are
+        the caller's own. A priority with no alloc left on any node of
+        the table gets no slab — what the alloc walk of build_node_table
+        yields for the same live view."""
+        rows = tiers_of(self.ids)
+        old, self.rows = self.rows, rows
+        for i in compress(range(len(rows)), map(is_not, rows, old)):
+            for tier in old[i]:
+                k = self._slab(tier[0])
+                self.slabs[k, i] = 0
+                self.nodes_in[k] -= 1
+            for tier in rows[i]:
+                k = self._slab(tier[0])
+                self.slabs[k, i] = tier[1:]
+                self.nodes_in[k] += 1
+        for by_prio in tier_adj.values():
+            for prio in by_prio:  # a tier may be the batch's own alone
+                self._slab(prio)
+        out = self.slabs.copy()
+        nodes_in = list(self.nodes_in)
+        for nid, by_prio in tier_adj.items():
+            i = self.index_of.get(nid)
+            if i is None:
+                continue  # a stop outside the batch's datacenters
+            for prio, vec in by_prio.items():
+                k = bisect_left(self.prios, prio)
+                held = out[k, i, NUM_RES] > 0
+                out[k, i] += vec
+                nodes_in[k] += int(out[k, i, NUM_RES] > 0) - int(held)
+        stand = [k for k, nodes in enumerate(nodes_in) if nodes > 0]
+        if len(stand) < len(nodes_in):
+            out = out[stand]
+        return [self.prios[k] for k in stand], out[:, :, :NUM_RES]
+
+
 def build_node_table(
     nodes: list[Node], allocs_by_node, usage_of=None
 ) -> NodeTable:
@@ -147,10 +230,11 @@ def build_node_table(
     usage. When given, per-node utilization comes from the store's
     incremental aggregate in O(nodes) instead of walking every live
     alloc (O(allocs) — the dominant lowering cost on a loaded cluster).
-    The fast table carries NO preemption tiers and NO core pools, so the
-    solver only takes it for batches that need neither (no preemptible
-    job types, no cores asks); everything else about the table is
-    identical.
+    The fast table is built with no preemption tiers — a batch that may
+    preempt reads them into it from the same aggregate by priority
+    (TierSlabs) — and carries NO core pools, so the solver walks the
+    allocs for a batch that asks for cores; everything else about the
+    table is identical.
     """
     n = len(nodes)
     cap = np.zeros((n, NUM_RES), dtype=np.int64)
@@ -199,7 +283,7 @@ def build_node_table(
                 if res is not None:
                     grants[id(res)] = got
             reserved += got[3]
-            prio = alloc.job.priority if alloc.job is not None else 50
+            prio = alloc_priority(alloc)
             acc = node_tiers.get(prio)
             if acc is None:
                 node_tiers[prio] = [got[0], got[1], got[2]]

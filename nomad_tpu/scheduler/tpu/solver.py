@@ -46,7 +46,9 @@ from ..util import ready_nodes_in_dcs
 from ...structs.structs import AllocDeploymentStatus
 from ...structs.placement_batch import PlacementBatch
 from ..preemption import PRIORITY_DELTA
-from .lower import LoweredGroup, build_node_table, lower_group
+from .lower import (
+    LoweredGroup, TierSlabs, alloc_priority, build_node_table, lower_group,
+)
 from .kernels import (
     pad_c,
     pad_g,
@@ -186,6 +188,9 @@ class ResidentClusterState:
         # usage rows refresh per solve
         self._host_table = None
         self._host_vers: Optional[tuple] = None
+        # the skeleton's preemption tiers as the store last gave them
+        # (lower.TierSlabs), for the batches that may preempt
+        self._host_tiers = None
         # the previous solve's returned table, held one solve gap so
         # the skeleton can harvest its lazily-built SoA columns
         self._last_table = None
@@ -269,8 +274,11 @@ class ResidentClusterState:
             (job.namespace, job.id, job.version, job.modify_index, tg_name)
         ] = (vers, tensors)
 
-    def host_table(self, nodes: list, allocs_by_node, usage_of):
-        """Cached build_node_table for the usage-aggregate path.
+    def host_table(self, nodes: list, allocs_by_node, usage_of,
+                   tiers=None):
+        """Cached build_node_table for the usage-aggregate path, with
+        (tiers given: a batch that may preempt) or without the
+        preemption tiers.
 
         Rebuilding the 100k-row host table every solve was the largest
         steady-state host cost of the sharded bench (~0.7s/solve at c2m
@@ -283,13 +291,13 @@ class ResidentClusterState:
         finish runs while batch N+1's begin re-reads usage), so handing
         consecutive solves one mutated-in-place table would race batch
         N's overflow-repair reads against batch N+1's usage refresh.
-        Per-solve state — the usage rows, the snapshot accessor, the
-        static-port masks — is this table's own; the shared attr/driver
-        caches are append-only interning keyed by node attrs the
-        fingerprint already pins."""
+        Per-solve state — the usage rows, the tier slabs, the snapshot
+        accessor, the static-port masks — is this table's own; the
+        shared attr/driver caches are append-only interning keyed by
+        node attrs the fingerprint already pins."""
         from .lower import NodeTable
 
-        def clone(src, used_arr, accessor):
+        def clone(src, used_arr, tiers, accessor):
             out = NodeTable(
                 nodes=src.nodes,
                 index_of=src.index_of,
@@ -297,8 +305,8 @@ class ResidentClusterState:
                 used=used_arr,
                 datacenters=src.datacenters,
                 dc_values=src.dc_values,
-                tier_prios=src.tier_prios,
-                tier_used=src.tier_used,
+                tier_prios=tiers[0],
+                tier_used=tiers[1],
                 cores_free=src.cores_free,
                 _attr_cache=src._attr_cache,
                 _driver_cache=src._driver_cache,
@@ -312,16 +320,23 @@ class ResidentClusterState:
                     setattr(out, col, cached)
             return out
 
+        n = len(nodes)
+        no_tiers = ([], np.zeros((0, n, 3), dtype=np.int64))
         vers = tuple((node.id, node.modify_index) for node in nodes)
         skel = self._host_table
         if skel is None or self._host_vers != vers:
             t = build_node_table(nodes, allocs_by_node, usage_of=usage_of)
+            self._host_tiers = None
+            if tiers is not None:
+                self._host_tiers = TierSlabs(t.index_of)
+                t.tier_prios, t.tier_used = self._host_tiers.read(*tiers)
             # The cached skeleton carries NO snapshot accessor: holding
             # this solve's allocs_by_node closure would pin its whole
             # state snapshot for as long as the node fingerprint stays
             # stable (hours on a quiet cluster). The live table keeps
-            # its accessor; only the cache copy is stripped.
-            self._host_table = clone(t, t.used, None)
+            # its accessor; only the cache copy is stripped — and its
+            # tiers, which are this solve's as the usage rows are.
+            self._host_table = clone(t, t.used, no_tiers, None)
             self._host_vers = vers
             self._last_table = t
             return t
@@ -338,14 +353,18 @@ class ResidentClusterState:
                     cached = getattr(last, col, None)
                     if cached is not None:
                         setattr(skel, col, cached)
-        n = len(nodes)
         used = np.empty((n, 3), dtype=np.int64)
         for i, node in enumerate(nodes):
             u = usage_of(node.id)
             used[i, 0] = u[0]
             used[i, 1] = u[1]
             used[i, 2] = u[2]
-        t2 = clone(skel, used, allocs_by_node)
+        slabs = no_tiers
+        if tiers is not None:
+            if self._host_tiers is None:
+                self._host_tiers = TierSlabs(skel.index_of)
+            slabs = self._host_tiers.read(*tiers)
+        t2 = clone(skel, used, slabs, allocs_by_node)
         self._last_table = t2
         return t2
 
@@ -1141,6 +1160,11 @@ class BatchSolver:
                     return to_host
                 table, usage_of, adj = tab
                 tspan.set_attr("nodes", table.n)
+                if usage_of is None:
+                    tspan.set_attr(
+                        "alloc_walk",
+                        "cores" if self._batch_has_cores else "no_index",
+                    )
 
             with trace.span(tctx, "lower.groups") as gspan:
                 self._lower_cache_hits = 0
@@ -1249,7 +1273,10 @@ class BatchSolver:
                      micro_wanted: bool):
         """`lower.table` over the batch's node universe: (table,
         usage_of, adj) — or None: a small batch that may preempt, the
-        host stack's."""
+        host stack's. usage_of is None where the table had to walk the
+        allocs."""
+        from ... import metrics
+
         # Capacity freed by this batch's plans (stops/destructive updates)
         # is usable: plan application re-verifies, so optimistic batching
         # treats all batch stops as vacated (reference: the host oracle's
@@ -1275,12 +1302,13 @@ class BatchSolver:
                 if a.id not in stopped_ids
             ] + placed_by_node.get(nid, [])
 
-        # Aggregate fast path: when the batch can neither preempt (no
-        # tier tensors needed) nor ask for dedicated cores (no core
-        # pools), per-node utilization comes straight from the store's
-        # incremental aggregate — O(nodes), not O(allocs) — with this
-        # batch's vacated stops and the host partition's placements
-        # applied as per-node adjustments.
+        # Aggregate fast path: a batch that asks for no dedicated cores
+        # (no core pools needed) takes per-node utilization straight
+        # from the store's incremental aggregate — O(nodes), not
+        # O(allocs) — with this batch's vacated stops and the host
+        # partition's placements applied as per-node adjustments; one
+        # that may preempt takes its tiers from the store's usage by
+        # (node, priority) the same way, adjusted per tier.
         preempt_possible = self.solve_preempt_fn is not None and any(
             self.config.preemption_enabled(a.job.type) for a in asks
         )
@@ -1298,8 +1326,7 @@ class BatchSolver:
             # same-batch host-partition placements are preemptible too
             # (they're in the dense table's live view)
             tiers.extend(
-                a.job.priority if a.job is not None else 50
-                for a in self._partition_placed
+                alloc_priority(a) for a in self._partition_placed
             )
             preempt_possible = any(
                 maxprio - p >= PRIORITY_DELTA for p in tiers
@@ -1309,34 +1336,46 @@ class BatchSolver:
             # per-request evict pass) — keep the host path for it
             return None
         usage_of = None
+        tiers = None
         adj: dict[str, list[int]] = {}
         if (
             not self._batch_has_cores
-            and not preempt_possible
-            and hasattr(self.state, "node_usage")
+            and hasattr(
+                self.state,
+                "node_tier_usage" if preempt_possible else "node_usage",
+            )
         ):
+            # node -> {priority: [cpu, mem, disk, allocs]}: the same
+            # adjustments by tier, for a batch that may preempt
+            tier_adj: dict[str, dict[int, list[int]]] = {}
 
-            def _adjust(nid: str, r, sign: int) -> None:
+            def _adjust(a, sign: int) -> None:
+                nid, r = a.node_id, a.comparable_resources()
                 d = adj.get(nid)
                 if d is None:
                     d = adj[nid] = [0, 0, 0]
                 d[0] += sign * r.cpu
                 d[1] += sign * r.memory_mb
                 d[2] += sign * r.disk_mb
+                if preempt_possible:
+                    t = tier_adj.setdefault(nid, {}).setdefault(
+                        alloc_priority(a), [0, 0, 0, 0]
+                    )
+                    for k, v in enumerate((r.cpu, r.memory_mb, r.disk_mb, 1)):
+                        t[k] += sign * v
 
             for sid in stopped_ids:
                 stored = self.state.alloc_by_id(sid)
                 if stored is not None and not stored.terminal_status():
-                    _adjust(
-                        stored.node_id, stored.comparable_resources(), -1
-                    )
+                    _adjust(stored, -1)
             for a in self._partition_placed:
-                _adjust(a.node_id, a.comparable_resources(), +1)
+                _adjust(a, +1)
             if self.extra_usage:
                 # interactive-lane ledger (worker.py): placements the
                 # priority lane committed past the chain basis — deltas,
                 # so they compose with both the set-scatter and the
-                # chained-add paths below
+                # chained-add paths below. They carry no priority and
+                # join no tier: held, not evictable.
                 for nid, vec in self.extra_usage.items():
                     d = adj.get(nid)
                     if d is None:
@@ -1356,16 +1395,29 @@ class BatchSolver:
 
             else:
                 usage_of = state_usage
+            if preempt_possible:
+                metrics.incr("nomad.tpu.lower_tiers_from_store")
+                tiers = (self.state.node_tier_usage, tier_adj)
+        else:
+            # core pools are in no aggregate (or the state keeps none):
+            # every live alloc is read
+            metrics.incr("nomad.tpu.lower_alloc_walks")
 
         if self.resident is not None and usage_of is not None:
             # cross-solve host-table cache: same fingerprint discipline
             # as the resident device tensors (ResidentClusterState)
-            table = self.resident.host_table(nodes, live_allocs, usage_of)
+            table = self.resident.host_table(
+                nodes, live_allocs, usage_of, tiers
+            )
             # lowered-skeleton cache rides the same fingerprint: valid
             # only for tables produced by this generation's skeleton
             self._lower_vers = self.resident._host_vers
         else:
             table = build_node_table(nodes, live_allocs, usage_of=usage_of)
+            if tiers is not None:
+                table.tier_prios, table.tier_used = TierSlabs(
+                    table.index_of
+                ).read(*tiers)
         return table, usage_of, adj
 
     def _solve_host_timed(self, asks: list[GroupAsk],
@@ -2420,7 +2472,7 @@ class BatchSolver:
                             row[1] += r.memory_mb
                             row[2] += r.disk_mb
                             pre.append((v, alloc.id))
-                            prio = v.job.priority if v.job is not None else 50
+                            prio = alloc_priority(v)
                             evicted_by_prio[prio] = \
                                 evicted_by_prio.get(prio, 0) + 1
                             k = tier_of.get(prio)
@@ -2499,7 +2551,7 @@ class BatchSolver:
                 and a.namespace == grp.job.namespace
             ):
                 continue
-            prio = a.job.priority if a.job is not None else 50
+            prio = alloc_priority(a)
             if grp.priority - prio < PRIORITY_DELTA:
                 continue
             cands.append((prio, a))

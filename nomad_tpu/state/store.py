@@ -22,6 +22,7 @@ from __future__ import annotations
 
 import dataclasses
 import threading
+from itertools import repeat
 
 from ..gctune import paused_gc
 from typing import Callable, Iterable, Optional
@@ -113,15 +114,23 @@ IDX_ALLOCS_EVAL = "_idx_allocs_eval"
 # per-node path. Values are immutable tuples, replaced wholesale, so the
 # table obeys the same COW discipline as every other table.
 IDX_NODE_USED = "_idx_node_used"
-# priority -> count of non-terminal allocs at that job priority. A few
-# integers that let the batch solver prove "no preemptible tier exists
-# below this batch's priorities" in O(1) and take the aggregate-usage
-# lowering path (O(nodes)) instead of walking every live alloc to build
-# tier tensors it would never use.
+# priority -> count of non-terminal allocs at that job priority: the
+# cluster's preemption tiers by name. A few integers that let the batch
+# solver prove "no preemptible tier exists below this batch's priorities"
+# in O(1); where one does exist, IDX_NODE_TIERS holds what each node
+# carries of it.
 IDX_PRIO_COUNT = "_idx_prio_count"
+# node_id -> ((priority, cpu, memory_mb, disk_mb, count), ...) ascending
+# by priority: IDX_NODE_USED split by the owning job's priority, with the
+# number of non-terminal allocs behind each sum (a tier stands on a node
+# while it has an alloc there, whatever the alloc asks). A batch that may
+# preempt lowers its tier tensors from this in O(nodes) instead of
+# walking every live alloc. One entry a node, so a node's tiers are
+# replaced together; immutable tuples replaced wholesale, as above.
+IDX_NODE_TIERS = "_idx_node_tiers"
 INDEX_TABLES = (
     IDX_ALLOCS_NODE, IDX_ALLOCS_JOB, IDX_ALLOCS_EVAL, IDX_NODE_USED,
-    IDX_PRIO_COUNT,
+    IDX_PRIO_COUNT, IDX_NODE_TIERS,
 )
 
 
@@ -182,16 +191,46 @@ def _alloc_priority(alloc) -> int:
     return alloc.job.priority if alloc.job is not None else 50
 
 
-def _prio_add(pt: dict, alloc, c) -> None:
+def _tier_add(tt: dict, node_id: str, prio: int, c, count: int,
+              sign: int = 1) -> None:
+    """Add (sign -1: take away) `count` allocs summing to c[:3] at one
+    node's tier `prio`. A tier left without an alloc goes, and the
+    node's entry with its last tier."""
+    if not node_id:
+        return
+    cur = tt.get(node_id, ())
+    k = 0
+    while k < len(cur) and cur[k][0] < prio:
+        k += 1
+    if k < len(cur) and cur[k][0] == prio:
+        e = cur[k]
+        left = e[4] + sign * count
+        mid = ((prio, e[1] + sign * c[0], e[2] + sign * c[1],
+                e[3] + sign * c[2], left),) if left > 0 else ()
+        nxt = cur[:k] + mid + cur[k + 1:]
+    elif sign > 0:
+        nxt = cur[:k] + ((prio, c[0], c[1], c[2], count),) + cur[k:]
+    else:
+        return
+    if nxt:
+        tt[node_id] = nxt
+    else:
+        del tt[node_id]
+
+
+def _prio_add(pt: dict, tt: dict, alloc, c) -> None:
     """Count a non-terminal alloc (c = its usage contribution; None
-    means terminal and uncounted — the same rule the usage table uses)."""
+    means terminal and uncounted — the same rule the usage table uses)
+    under its job's priority: once for the cluster, and with its usage
+    for its node."""
     if c is None:
         return
     p = _alloc_priority(alloc)
     pt[p] = pt.get(p, 0) + 1
+    _tier_add(tt, alloc.node_id, p, c, 1)
 
 
-def _prio_sub(pt: dict, alloc, c) -> None:
+def _prio_sub(pt: dict, tt: dict, alloc, c) -> None:
     if c is None:
         return
     p = _alloc_priority(alloc)
@@ -200,13 +239,17 @@ def _prio_sub(pt: dict, alloc, c) -> None:
         pt.pop(p, None)
     else:
         pt[p] = cur
+    _tier_add(tt, alloc.node_id, p, c, 1, sign=-1)
 
 
-def rebuild_prio_counts(allocs: dict) -> dict:
+def rebuild_priority_indexes(allocs: dict) -> tuple[dict, dict]:
+    """(priority counts, per-node tiers) recomputed from scratch: the
+    restore path, and the tests' drift invariant."""
     pt: dict[int, int] = {}
+    tt: dict[str, tuple] = {}
     for alloc in allocs.values():
-        _prio_add(pt, alloc, usage_contribution(alloc))
-    return pt
+        _prio_add(pt, tt, alloc, usage_contribution(alloc))
+    return pt, tt
 
 JOB_TRACKED_VERSIONS = 6
 
@@ -387,9 +430,23 @@ class _ReadMixin:
 
     def alloc_priority_tiers(self) -> list[int]:
         """Ascending job priorities that have at least one committed
-        non-terminal alloc — the O(1) preemption-possibility signal the
-        batch solver gates its aggregate lowering path on."""
+        non-terminal alloc: the cluster's preemption tiers by name, and
+        the batch solver's O(1) proof that a batch can (or cannot)
+        preempt anything."""
         return sorted(self._tables[IDX_PRIO_COUNT])
+
+    def node_tier_usage(self, node_ids: list[str]) -> list[tuple]:
+        """node_usage by the owning job's priority, for many nodes at
+        once: for each of `node_ids` its ((priority, cpu, memory_mb,
+        disk_mb, allocs), ...) ascending, a tier for every priority with
+        a committed non-terminal alloc on the node, () for none. What a
+        batch that may preempt lowers its tier tensors from: one table,
+        read in one pass that runs no Python for a node (a cluster's
+        worth of reads an eval, beside every other thread of the
+        server). No lock needed: dict.get of immutable tuples."""
+        return list(
+            map(self._tables[IDX_NODE_TIERS].get, node_ids, repeat(()))
+        )
 
     @_locked_on_live
     def allocs_by_node_terminal(
@@ -851,9 +908,10 @@ class StateStore(_ReadMixin):
         data["tables"][IDX_NODE_USED] = rebuild_node_usage(
             data["tables"][TABLE_ALLOCS]
         )
-        data["tables"][IDX_PRIO_COUNT] = rebuild_prio_counts(
-            data["tables"][TABLE_ALLOCS]
-        )
+        (
+            data["tables"][IDX_PRIO_COUNT],
+            data["tables"][IDX_NODE_TIERS],
+        ) = rebuild_priority_indexes(data["tables"][TABLE_ALLOCS])
         with self._cv:
             self._tables = data["tables"]
             self._indexes = data["indexes"]
@@ -933,13 +991,14 @@ class StateStore(_ReadMixin):
         self._wtable(TABLE_ALLOCS)[alloc.id] = alloc
         ut = self._wtable(IDX_NODE_USED)
         pt = self._wtable(IDX_PRIO_COUNT)
+        tt = self._wtable(IDX_NODE_TIERS)
         if existing is not None:
             ce = usage_contribution(existing)
             _usage_sub(ut, existing.node_id, ce)
-            _prio_sub(pt, existing, ce)
+            _prio_sub(pt, tt, existing, ce)
         ca = usage_contribution(alloc)
         _usage_add(ut, alloc.node_id, ca)
-        _prio_add(pt, alloc, ca)
+        _prio_add(pt, tt, alloc, ca)
         if existing is not None:
             if existing.node_id != alloc.node_id:
                 self._idx_del(IDX_ALLOCS_NODE, existing.node_id, alloc.id)
@@ -959,7 +1018,10 @@ class StateStore(_ReadMixin):
         if alloc is not None:
             c = usage_contribution(alloc)
             _usage_sub(self._wtable(IDX_NODE_USED), alloc.node_id, c)
-            _prio_sub(self._wtable(IDX_PRIO_COUNT), alloc, c)
+            _prio_sub(
+                self._wtable(IDX_PRIO_COUNT),
+                self._wtable(IDX_NODE_TIERS), alloc, c,
+            )
             self._idx_del(IDX_ALLOCS_NODE, alloc.node_id, alloc_id)
             self._idx_del(IDX_ALLOCS_JOB, (alloc.namespace, alloc.job_id), alloc_id)
             self._idx_del(IDX_ALLOCS_EVAL, alloc.eval_id, alloc_id)
@@ -1461,6 +1523,7 @@ class StateStore(_ReadMixin):
 
         ut = self._wtable(IDX_NODE_USED)
         pt = self._wtable(IDX_PRIO_COUNT)
+        tt = self._wtable(IDX_NODE_TIERS)
         if default_jobs is None:
             default_jobs = (
                 {(default_job.namespace, default_job.id): default_job}
@@ -1524,7 +1587,7 @@ class StateStore(_ReadMixin):
             if existing is not None:
                 ce = usage_contribution(existing)
                 _usage_sub(ut, existing.node_id, ce)
-                _prio_sub(pt, existing, ce)
+                _prio_sub(pt, tt, existing, ce)
             ar = alloc.resources
             if ar is not None:
                 ck2 = (id(ar), alloc.desired_status, alloc.client_status)
@@ -1534,7 +1597,7 @@ class StateStore(_ReadMixin):
             else:
                 c = usage_contribution(alloc)
             _usage_add(ut, alloc.node_id, c)
-            _prio_add(pt, alloc, c)
+            _prio_add(pt, tt, alloc, c)
             t[alloc.id] = alloc
             _inner(IDX_ALLOCS_NODE, alloc.node_id)[alloc.id] = alloc
             key = (alloc.namespace, alloc.job_id)
@@ -1634,8 +1697,9 @@ class StateStore(_ReadMixin):
     ) -> list:
         """Insert SoA placement batches: lazy AllocRow handles into the
         main/secondary tables, per-NODE (not per-row) usage-aggregate
-        updates from the columns, one priority-count bump and one
-        summary increment per batch. Per-row work is exactly the four
+        and node-tier updates from the columns at the batch's one
+        priority, one priority-count bump and one summary increment per
+        batch. Per-row work is exactly the four
         table inserts the id-keyed indexes require — everything the
         eager path did per row beyond that (defensive copy, stamps,
         contribution walk, terminal checks) happens once per batch.
@@ -1653,6 +1717,7 @@ class StateStore(_ReadMixin):
         t = self._wtable(TABLE_ALLOCS)
         ut = self._wtable(IDX_NODE_USED)
         pt = self._wtable(IDX_PRIO_COUNT)
+        tt = self._wtable(IDX_NODE_TIERS)
         st = None
         now = now_ns()
         stored: list = []
@@ -1692,9 +1757,11 @@ class StateStore(_ReadMixin):
                 )
             # aggregates: one update per touched node / one per batch
             c = b.row_contribution()
-            for nid, _ti, cnt in touched:
-                _usage_add(ut, nid, (c[0] * cnt, c[1] * cnt, c[2] * cnt, 0))
             prio = b.job.priority if b.job is not None else 50
+            for nid, _ti, cnt in touched:
+                total = (c[0] * cnt, c[1] * cnt, c[2] * cnt, 0)
+                _usage_add(ut, nid, total)
+                _tier_add(tt, nid, prio, total, cnt)
             pt[prio] = pt.get(prio, 0) + len(b)
             # summaries: every row is a fresh non-terminal insert, so the
             # O(1) starting-count increment always applies (the eager

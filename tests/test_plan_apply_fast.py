@@ -19,6 +19,8 @@ import random
 import threading
 import time
 
+import pytest
+
 from nomad_tpu import mock
 from nomad_tpu.server.plan_apply import (
     OverlaySnapshot,
@@ -82,17 +84,27 @@ def exact_evaluate_plan(snapshot, plan: Plan) -> PlanResult:
 
 def _check_aggregate(store: StateStore) -> None:
     from nomad_tpu.state.store import (
+        IDX_NODE_TIERS,
         IDX_PRIO_COUNT,
         TABLE_ALLOCS,
-        rebuild_prio_counts,
+        rebuild_priority_indexes,
     )
 
     got = store._tables[IDX_NODE_USED]
     want = rebuild_node_usage(store._tables[TABLE_ALLOCS])
     assert got == want, f"usage aggregate drifted: {got} != {want}"
     gotp = store._tables[IDX_PRIO_COUNT]
-    wantp = rebuild_prio_counts(store._tables[TABLE_ALLOCS])
+    wantp, wantt = rebuild_priority_indexes(store._tables[TABLE_ALLOCS])
     assert gotp == wantp, f"priority counts drifted: {gotp} != {wantp}"
+    gott = store._tables[IDX_NODE_TIERS]
+    assert gott == wantt, f"node tiers drifted: {gott} != {wantt}"
+    # and the tiers of a node are its usage, split: the accessor's view
+    for nid, used in got.items():
+        (tiers,) = store.node_tier_usage([nid])
+        assert [t[0] for t in tiers] == sorted({t[0] for t in tiers})
+        assert all(t[4] > 0 for t in tiers)
+        assert tuple(sum(t[k] for t in tiers) for k in (1, 2, 3)) == used[:3]
+    assert set(gott) >= set(got)
 
 
 def test_usage_aggregate_tracks_alloc_churn():
@@ -158,6 +170,175 @@ def test_usage_aggregate_survives_restore():
     restored.restore_from(raw)
     _check_aggregate(restored)
     assert restored.node_usage(node.id) == store.node_usage(node.id)
+
+
+# Every way an alloc is written, against a store that holds three tiers
+# on shared nodes; after each, every aggregate equals its rebuild.
+
+
+def _tiered_store():
+    """(store, nodes, {priority: job}, live allocs): four nodes, each
+    with allocs of priority 20, 50 and 70 jobs."""
+    store = StateStore()
+    nodes = [mock.node() for _ in range(4)]
+    for i, n in enumerate(nodes):
+        store.upsert_node(i + 1, n)
+    jobs = {p: mock.job(id=f"tier-{p}", priority=p) for p in (20, 50, 70)}
+    for k, job in enumerate(jobs.values()):
+        store.upsert_job(10 + k, job)
+    live = [mock.alloc(job, n, index=i)
+            for i, n in enumerate(nodes) for job in jobs.values()]
+    store.upsert_allocs(20, live)
+    return store, nodes, jobs, live
+
+
+def _tiers_of(store, node):
+    return {t[0]: t[1:] for t in store.node_tier_usage([node.id])[0]}
+
+
+def _write_eager_upsert(store, nodes, jobs, live):
+    store.upsert_allocs(30, [mock.alloc(jobs[20], nodes[0], index=9),
+                             mock.alloc(jobs[70], nodes[0], index=9)])
+    assert _tiers_of(store, nodes[0])[20][3] == 2
+
+
+def _write_update_in_place(store, nodes, jobs, live):
+    before = _tiers_of(store, nodes[0])[20]
+    a = live[0].copy()
+    next(iter(a.resources.tasks.values())).cpu += 100
+    store.upsert_allocs(30, [a])
+    after = _tiers_of(store, nodes[0])[20]
+    assert after == (before[0] + 100, before[1], before[2], before[3])
+
+
+def _write_node_move(store, nodes, jobs, live):
+    a = live[0].copy()
+    a.node_id = nodes[1].id
+    store.upsert_allocs(30, [a])
+    assert 20 not in _tiers_of(store, nodes[0])
+    assert _tiers_of(store, nodes[1])[20][3] == 2
+
+
+def _write_terminal_transition(store, nodes, jobs, live):
+    done = live[0].copy()
+    done.client_status = "complete"
+    store.update_allocs_from_client(30, [done])
+    stopped = live[1].copy()
+    stopped.desired_status = "stop"
+    store.upsert_allocs(31, [stopped])
+    assert set(_tiers_of(store, nodes[0])) == {70}
+    back = live[0].copy()  # and an alloc written terminal stays out
+    back.id = mock.alloc(jobs[20], nodes[0]).id
+    back.client_status = "failed"
+    store.upsert_allocs(32, [back])
+    assert set(_tiers_of(store, nodes[0])) == {70}
+
+
+def _write_delete(store, nodes, jobs, live):
+    store.delete_evals(30, [], [a.id for a in live[:3]])
+    assert store.node_tier_usage([nodes[0].id, "no such node"]) == [(), ()]
+    assert nodes[0].id not in store._tables[IDX_NODE_USED]
+
+
+def _write_priority_differs(store, nodes, jobs, live):
+    """The same alloc id written again under a job of another priority:
+    out of the tier of the row that was there, into the new row's."""
+    a = live[0].copy()
+    a.job = jobs[20].copy()
+    a.job.priority = 60
+    store.upsert_allocs(30, [a])
+    assert set(_tiers_of(store, nodes[0])) == {50, 60, 70}
+    assert store.alloc_priority_tiers() == [20, 50, 60, 70]
+
+
+def _write_snapshot_restore(store, nodes, jobs, live):
+    held = store.snapshot()
+    ids = [n.id for n in nodes]
+    was = held.node_tier_usage(ids)
+    store.delete_evals(30, [], [live[0].id])
+    assert held.node_tier_usage(ids) == was  # copy on write
+    assert store.node_tier_usage(ids) == [was[0][1:]] + was[1:]
+    restored = StateStore()
+    restored.restore_from(store.serialize())
+    _check_aggregate(restored)
+    assert restored.node_tier_usage(ids) == store.node_tier_usage(ids)
+
+
+def _solved_plan(h, job):
+    from nomad_tpu.scheduler.context import SchedulerConfig
+    from nomad_tpu.scheduler.tpu import solve_eval_batch
+
+    for t in job.task_groups[0].tasks:
+        t.resources.networks = []
+    h.state.upsert_job(h.next_index(), job)
+    ev = mock.eval_for_job(job)
+    return solve_eval_batch(
+        h.snapshot(), h, [ev],
+        SchedulerConfig(backend="tpu", small_batch_threshold=0))[ev.id]
+
+
+def _harness_over(store):
+    from nomad_tpu.testing import Harness
+
+    h = Harness()
+    h.state = store
+    for _ in range(store.latest_index()):
+        h.next_index()
+    return h
+
+
+def _write_soa_insert(store, nodes, jobs, live):
+    """The solver's SoA placement batches: one update a touched node at
+    the batch's one priority."""
+    h = _harness_over(store)
+    job = mock.job(id="soa", priority=25)  # nothing 10 under it: compact
+    job.task_groups[0].count = 6
+    job.task_groups[0].tasks[0].resources.cpu = 100
+    plan = _solved_plan(h, job)
+    assert plan.alloc_batches, "the fast-mint path must emit SoA batches"
+    h.submit_plan(plan)
+    placed = sum(_tiers_of(store, n).get(25, (0, 0, 0, 0))[3] for n in nodes)
+    assert placed == 6
+
+
+def _write_plan_with_preemptions(store, nodes, jobs, live):
+    h = _harness_over(store)
+    fill = []  # every node full of priority 20
+    for n in nodes:
+        free = n.available_resources().cpu - store.node_usage(n.id)[0]
+        a = mock.alloc(jobs[20], n, index=50)
+        next(iter(a.resources.tasks.values())).cpu = free
+        fill.append(a)
+    store.upsert_allocs(h.next_index(), fill)
+    job = mock.job(id="production", priority=90)
+    job.task_groups[0].count = 2
+    plan = _solved_plan(h, job)
+    victims = [a for v in plan.node_preemptions.values() for a in v]
+    assert victims, "the plan must evict"
+    before = store._tables[IDX_NODE_USED].copy()
+    h.submit_plan(plan)
+    assert store._tables[IDX_NODE_USED] != before
+    assert all(store.alloc_by_id(v.id).terminal_status() for v in victims)
+
+
+_ALLOC_WRITES = {
+    fn.__name__[len("_write_"):]: fn
+    for fn in (
+        _write_eager_upsert, _write_soa_insert, _write_update_in_place,
+        _write_node_move, _write_terminal_transition, _write_delete,
+        _write_plan_with_preemptions, _write_snapshot_restore,
+        _write_priority_differs,
+    )
+}
+
+
+@pytest.mark.parametrize("write", sorted(_ALLOC_WRITES))
+def test_every_alloc_write_keeps_usage_by_priority(write):
+    store, nodes, jobs, live = _tiered_store()
+    _check_aggregate(store)
+    assert set(_tiers_of(store, nodes[0])) == {20, 50, 70}
+    _ALLOC_WRITES[write](store, nodes, jobs, live)
+    _check_aggregate(store)
 
 
 # ---------------------------------------------------------------------------
