@@ -95,9 +95,62 @@ def pad_n(n: int) -> int:
     return _pad_to(n, 2048)
 
 
+# The compact solve's ladder (PR 32). XLA compiles one program for each
+# static shape it meets, 3.2-4.8 s apiece on a v5e, and a warm-up can
+# only finish a set that is closed and listed: so the group axis and the
+# readback width take their padded sizes from these two ladders and from
+# nowhere else. Both climb by 4, because a rung's waste is device time
+# and the device has it to spare (my chip runs, PR 32, at 14,336 nodes:
+# a padded scan step, count 0, takes ~27 us and a real one ~80 us; the
+# compaction ~11 ns a group x readback slot, 11 ms at 256 x 4,096; a
+# batch's host side is 400 ms and more), while every program is seconds
+# of set-up on an empty cache: 16 programs a node bucket, where rungs
+# that doubled would be 30. G_LADDER's top is what a worker's batch
+# reaches, 64 evals of a job spread over four values; C_LADDER starts at
+# 64 because a batch the kernel sees holds 49 requests or more.
+G_LADDER = (8, 32, 128, 256)
+C_LADDER = (64, 256, 1024, 4096)
+
+
 def pad_g(g: int) -> int:
-    """Group-axis bucket: multiples of 8."""
-    return max(8, _pad_to(g, 8))
+    """Group-axis bucket: the least rung of G_LADDER that holds `g`
+    (8, 32, 128, 256). Past the top the ladder goes on in multiples of
+    256, so any batch still solves and its program still follows from
+    the group count alone; such a program is outside compact_programs(),
+    compiles on first use and is counted by
+    `nomad.tpu.compact.programs_new`. (Finer rungs buy nothing: with a
+    rung every 8 groups a batch of mixed jobs met a program no warm-up
+    had listed in most windows, ~4 s each.)"""
+    for rung in G_LADDER:
+        if g <= rung:
+            return rung
+    return _pad_to(g, G_LADDER[-1])
+
+
+def pad_c(c: int) -> int:
+    """Instance-count bucket for the compact readback: the least rung of
+    C_LADDER that holds `c` (64, 256, 1,024, 4,096), then x 4 past the
+    top (outside compact_programs(), as pad_g's overflow)."""
+    for rung in C_LADDER:
+        if c <= rung:
+            return rung
+    size = C_LADDER[-1]
+    while size < c:
+        size *= 4
+    return size
+
+
+def compact_programs() -> list[tuple[int, int]]:
+    """Every (group bucket, instance bucket) the compact solve compiles
+    for the batches a worker drains, in order: the closed set a warm-up
+    has to finish, for each node bucket. Which of them a batch lands in
+    follows from its group count (pad_g) and from its largest group
+    (pad_c of the readback bound, which is at most the largest count)
+    and from nothing else — the row tables' sizes and the unit caps'
+    dtype follow from the two rungs (solver._compact_dispatch) — so a
+    batch of ONE job class at one count reaches every program a mixed
+    batch can."""
+    return [(gp, maxc) for gp in G_LADDER for maxc in C_LADDER]
 
 
 def _score_nodes(cap_f, used_f, ask_f, bias_g):
@@ -192,14 +245,6 @@ def solve_placement(cap, used, asks, counts, feas, bias, units_cap):
     return takes, used
 
 
-def pad_c(c: int) -> int:
-    """Instance-count bucket for the compact readback: power of two >= 16."""
-    size = 16
-    while size < c:
-        size *= 2
-    return size
-
-
 @functools.partial(jax.jit, static_argnames=("max_count",))
 def solve_placement_compact(
     cap,
@@ -224,13 +269,22 @@ def solve_placement_compact(
       * input dedupe — groups lowered from the same job share identical
         bias/units-cap/feasibility rows (spread sub-groups reference the
         parent's arrays; unconstrained jobs are all-equal). The host sends
-        unique rows + a per-group row index; the kernel gathers on device.
-      * feasibility rows travel bit-packed ([Uf, N/8] u8, unpacked once on
+        row tables + a per-group row index; the kernel gathers on device.
+      * feasibility rows travel bit-packed ([G, N/8] u8, unpacked once on
         device); unit caps travel as i16 (caps beyond the group count are
-        equivalent to it).
+        equivalent to it) wherever max_count fits it.
       * compact result — instead of [G, N] counts, the device emits the
         node index of each placed instance ([G, max_count] i32 via
         searchsorted over the per-group cumsum), plus [N] overflow flags.
+
+    The program is a function of (N, G, max_count) and nothing else
+    (PR 32): G and max_count are rungs of the ladder (pad_g, pad_c;
+    compact_programs() lists them), each row table has G rows whatever
+    the batch's distinct rows are — an all-equal batch fills one, a
+    batch in which every group differs fills all — and the unit caps'
+    dtype follows from max_count. Padding is inert: a padded group has
+    count 0 and places nothing, a padded row is never indexed, and the
+    host cuts the readback to the real groups.
 
     The overflow flags are a defensive invariant check, not an expected
     path: the integer waterfill can never place past free capacity (units
@@ -269,7 +323,14 @@ def solve_placement_compact(
     idx = jnp.arange(max_count, dtype=jnp.int32)
 
     def compact_one(cum_g):
-        node = jnp.searchsorted(cum_g, idx, side="right").astype(jnp.int32)
+        # compare_all: one pass of N x max_count compares, which the
+        # vector unit streams; the default binary search is max_count
+        # chains of log2(N) dependent gathers (the same integers either
+        # way; on a v5e at 14,336 nodes, 256 groups x 4,096: 11 ms of
+        # the kernel's 32 against 150 of 171, my chip runs, PR 32)
+        node = jnp.searchsorted(
+            cum_g, idx, side="right", method="compare_all"
+        ).astype(jnp.int32)
         return jnp.where(idx < cum_g[-1], node, -1)
 
     inst_node = jax.vmap(compact_one)(cum)

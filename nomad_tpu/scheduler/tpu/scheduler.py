@@ -325,6 +325,15 @@ class PendingEvalBatch:
         return self._solver.chain_out
 
     @property
+    def solved_in_begin(self) -> bool:
+        """Did the solve complete in begin() (host stack, microsolve,
+        a sticky partition, nothing to place)? Such a batch has no
+        kernel in flight and its commit is due in milliseconds: where it
+        offers no chain either, the worker waits for that commit rather
+        than solve the next batch blind to it (worker._solve_batch)."""
+        return self._pending.solved_in_begin
+
+    @property
     def used_micro(self) -> bool:
         """Did this solve run the host microsolve kernel? (zero device
         round-trip; the worker's lane telemetry reads it)."""

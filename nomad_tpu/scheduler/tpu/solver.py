@@ -608,11 +608,15 @@ class PendingSolve:
     SolveOutcome. Single-shot; the generator is dropped after finish so
     a double finish() returns the cached outcome."""
 
-    __slots__ = ("_gen", "_outcome")
+    __slots__ = ("_gen", "_outcome", "solved_in_begin")
 
     def __init__(self, gen, outcome: Optional[SolveOutcome]) -> None:
         self._gen = gen
         self._outcome = outcome
+        # the whole solve ran in phase A (host stack, microsolve, a
+        # sticky partition, nothing to place): no kernel is in flight
+        # and finish() has nothing to block on
+        self.solved_in_begin = gen is None
 
     def finish(self) -> SolveOutcome:
         if self._gen is None:
@@ -1704,35 +1708,43 @@ class BatchSolver:
     @staticmethod
     def _dedupe_rows(
         arrays: list[np.ndarray], gp: int, np_: int, dtype
-    ) -> tuple[np.ndarray, np.ndarray]:
-        """Unique row table + per-group index for host->device compression.
+    ) -> tuple[np.ndarray, np.ndarray, int]:
+        """Row table + per-group index for host->device compression,
+        and how many of the table's rows are distinct ones.
 
         Groups lowered from one job share bias/ucap array OBJECTS (spread
         splits keep the parent's references) and unconstrained jobs have
         value-identical rows, so dedupe is first by identity then by
-        content. Row count pads to a multiple of 8 for jit-shape stability.
-        """
+        content; a row cast to an integer `dtype` is clipped to what it
+        holds, from 0. The table always has `gp` rows — the group
+        bucket's, not the distinct rows': how many rows of a kind a
+        batch holds is the mix's business (one for a backlog of one job,
+        six or more for a Borg mix), and a table sized by it puts three
+        more numbers into the program's signature, so that a
+        single-class warm-up can never reach the programs a mixed batch
+        compiles. `gp` rows hold any batch (no group can bring more than
+        one row of a kind); rows past the distinct ones are zero and no
+        index names them."""
         by_id: dict[int, int] = {}
         by_content: dict[bytes, int] = {}
-        rows: list[np.ndarray] = []
+        top = np.iinfo(dtype).max if np.issubdtype(dtype, np.integer) \
+            else None
+        out = np.zeros((gp, np_), dtype=dtype)
         idx = np.zeros(gp, dtype=np.int32)
         for i, arr in enumerate(arrays):
             j = by_id.get(id(arr))
             if j is None:
-                a = np.asarray(arr, dtype=dtype)
+                a = np.asarray(arr)
                 key = a.tobytes()
                 j = by_content.get(key)
                 if j is None:
-                    j = len(rows)
-                    rows.append(a)
-                    by_content[key] = j
+                    j = by_content[key] = len(by_content)
+                    if top is not None:
+                        a = np.clip(a, 0, top)
+                    out[j, : a.shape[0]] = a
                 by_id[id(arr)] = j
             idx[i] = j
-        up = max(8, -(-len(rows) // 8) * 8)
-        out = np.zeros((up, np_), dtype=dtype)
-        for j, a in enumerate(rows):
-            out[j, : a.shape[0]] = a
-        return out, idx
+        return out, idx, len(by_content)
 
     def _readback_bound(self, cap, used, groups: list[LoweredGroup],
                         n: int) -> int:
@@ -1801,44 +1813,75 @@ class BatchSolver:
         from ... import metrics
 
         t_prep0 = now_ns()
-        with trace.span(trace.current(), "host_prep", cpu=True):
+        with trace.span(trace.current(), "host_prep", cpu=True) as span:
             pending = self._compact_dispatch(
-                table, groups, used_n, dev_state
+                table, groups, used_n, dev_state, span
             )
         metrics.time_ns("nomad.tpu.host_prep_seconds", now_ns() - t_prep0)
         return pending
 
-    def _compact_dispatch(self, table, groups, used_n, dev_state):
-        """_run_compact_async's body: pack, dedupe, upload, queue."""
+    @staticmethod
+    def _call_compact(sig: tuple, span, g: int, gp: int, maxc: int,
+                      fn, *args, **kwargs):
+        """Queue a compact program under the compile ledger, and say
+        what the dispatch is: the rung on the `host_prep` span, the real
+        and the padded groups (the roofline counts the real ones:
+        padding is the program's waste), and one `programs_new` event
+        where the ledger (solverobs.record_call) meets the signature
+        for the first time — one event a compile."""
+        from ... import metrics
+
+        span.set_attr("gp", gp)
+        span.set_attr("maxc", maxc)
+        metrics.observe("nomad.tpu.compact.groups", g)
+        metrics.observe("nomad.tpu.compact.groups_pad", gp - g)
+        metrics.observe("nomad.tpu.compact.groups_padded", gp)
+        out, compiled = solverobs.timed_call_verdict(
+            sig[0], sig, fn, *args, **kwargs
+        )
+        if compiled:
+            metrics.observe("nomad.tpu.compact.programs_new", 1)
+        return out
+
+    def _compact_dispatch(self, table, groups, used_n, dev_state, span):
+        """_run_compact_async's body: pack, dedupe, upload, queue. The
+        program it queues follows from (np_, gp, maxc) alone — the node
+        bucket and the two rungs of the ladder (kernels.pad_g / pad_c):
+        the row tables have gp rows each and the unit caps' dtype
+        follows from maxc, so nothing a mix of jobs varies is in the
+        signature."""
+        from ... import metrics
+
         n, g = table.n, len(groups)
         np_, gp, cap, used, asks_arr, counts = self._lower_small(table, groups)
         used[:n] = used_n[:n]
+        maxc = pad_c(max(1, self._readback_bound(cap, used, groups, n)))
         if self.mesh is not None:
             return self._dispatch_mesh_compact(
-                table, groups, np_, gp, cap, used, asks_arr, counts,
-                dev_state,
+                table, groups, np_, gp, maxc, cap, used, asks_arr, counts,
+                dev_state, span,
             )
-        feas_rows, feas_idx = self._dedupe_rows(
+        feas_rows, feas_idx, n_feas = self._dedupe_rows(
             [grp.feasible for grp in groups], gp, np_, np.bool_
         )
         feas_packed = np.packbits(feas_rows, axis=1)
-        bias_rows, bias_idx = self._dedupe_rows(
+        bias_rows, bias_idx, n_bias = self._dedupe_rows(
             [grp.bias for grp in groups], gp, np_, np.float32
         )
         # Dedupe on the ORIGINAL arrays (spread sub-groups share the
-        # parent's reference — the identity fast path), then shrink the few
-        # unique rows. Caps beyond a group's count are equivalent to it
-        # (the kernel clips units to count), so i16 loses nothing as long
-        # as every count fits; gigantic single-group batches keep i32.
-        ucap_rows, ucap_idx = self._dedupe_rows(
-            [grp.units_cap for grp in groups], gp, np_, np.int64
+        # parent's reference — the identity fast path); only the distinct
+        # rows are clipped and cast. A cap beyond a group's count is
+        # equivalent to the count (the kernel clips units to it), so i16
+        # loses nothing unless a count AND a node's room for it pass
+        # 32,767 — and then the readback bound does too. So the dtype
+        # follows from the rung and stays out of the signature.
+        ucap_rows, ucap_idx, n_ucap = self._dedupe_rows(
+            [grp.units_cap for grp in groups], gp, np_,
+            np.int16 if maxc < 2**15 else np.int32,
         )
-        max_count = max(int(grp.count) for grp in groups)
-        if max_count < 2**15:
-            ucap_rows = np.clip(ucap_rows, 0, 2**15 - 1).astype(np.int16)
-        else:
-            ucap_rows = np.clip(ucap_rows, 0, 2**31 - 1).astype(np.int32)
-        maxc = pad_c(max(1, self._readback_bound(cap, used, groups, n)))
+        metrics.observe(
+            "nomad.tpu.compact.distinct_rows", n_feas + n_bias + n_ucap
+        )
         # resident/chained device tensors replace the cap and/or used
         # upload when their padded shape matches this table's bucket
         cap_in, used_in = cap, used
@@ -1859,13 +1902,9 @@ class BatchSolver:
             )
             if isinstance(a, np.ndarray)
         ))
-        sig = (
-            "solve_placement_compact", np_, gp, feas_packed.shape[0],
-            bias_rows.shape[0], ucap_rows.shape[0], str(ucap_rows.dtype),
-            maxc,
-        )
-        inst, over, used_out = solverobs.timed_call(
-            "solve_placement_compact", sig, solve_placement_compact,
+        inst, over, used_out = self._call_compact(
+            ("solve_placement_compact", np_, gp, maxc), span, g, gp, maxc,
+            solve_placement_compact,
             cap_in,
             used_in,
             asks_arr,
@@ -1881,7 +1920,8 @@ class BatchSolver:
         return inst, over, used_out, g, n, time.perf_counter()
 
     def _dispatch_mesh_compact(
-        self, table, groups, np_, gp, cap, used, asks_arr, counts, dev_state
+        self, table, groups, np_, gp, maxc, cap, used, asks_arr, counts,
+        dev_state, span,
     ):
         """Node-sharded dispatch with the compact readback contract:
         the mesh's top-k compact kernel returns the same
@@ -1896,7 +1936,6 @@ class BatchSolver:
         mesh = self.mesh
         n, g = table.n, len(groups)
         feas, bias, ucap = self._dense_group_rows(n, np_, gp, groups)
-        maxc = pad_c(max(1, self._readback_bound(cap, used, groups, n)))
         fn, k = mesh.solver(maxc, compact=True)
         cap_in, used_in = cap, used
         if dev_state is not None:
@@ -1921,8 +1960,8 @@ class BatchSolver:
             "allgather", mesh.allgather_bytes(gp, np_, k)
         )
         kname = getattr(fn, "__name__", "sharded_solver_compact")
-        inst, over, used_out = solverobs.timed_call(
-            kname, (kname, np_, gp, k), fn,
+        inst, over, used_out = self._call_compact(
+            (kname, np_, gp, k), span, g, gp, k, fn,
             cap_in, used_in, asks_arr, counts, feas, bias, ucap,
         )
         return inst, over, used_out, g, n, time.perf_counter()
@@ -1944,9 +1983,11 @@ class BatchSolver:
         metrics.time_ns("nomad.tpu.device_seconds", dev_ns)
         trace.stage("device.wait", dev_ns)
         t_rb0 = now_ns()
-        # slice on-device before the host transfer: the pad region is
-        # noise and the link to the chip is the slow resource
-        result = np.asarray(inst[:g]), np.asarray(over[:n]), used_out
+        # the padded arrays cross the link whole and are cut on the
+        # host: an on-device slice is an eager program of its own for
+        # every distinct group count (as _run_kernel_finish), and the
+        # pad is a few hundred KB where the link moves GB/s
+        result = np.asarray(inst)[:g], np.asarray(over)[:n], used_out
         rb_ns = now_ns() - t_rb0
         metrics.time_ns("nomad.tpu.readback_seconds", rb_ns)
         trace.stage("readback", rb_ns)

@@ -84,6 +84,7 @@ class RemotePendingBatch:
 
     chain = None
     chain_accepted = False
+    solved_in_begin = False
     used_micro = False
 
     def __init__(self, pool: "SolverPool", dispatch: _Dispatch, snapshot,

@@ -801,6 +801,37 @@ class TPUBatchWorker:
             max(ev.modify_index for ev in evals),
             max(ev.snapshot_index for ev in evals),
         )
+        if allow_chain and self._prev is not None:
+            prev_pending, committed = self._prev[:2]
+            if (
+                not committed.is_set()
+                and prev_pending.solved_in_begin
+                and prev_pending.chain is None
+            ):
+                # The batch in flight was solved whole in its phase A
+                # and offers no chain: a small batch that the host stack
+                # or the microsolve took. Until it commits, its
+                # placements — and, commits being FIFO, those of the
+                # mega-batch before it, whose used' tensor it dropped
+                # from the chain — are in no snapshot and in no tensor
+                # this solve could be given: a kernel batch solved now
+                # re-places onto the same nodes, the applier trims it,
+                # and every batch chained behind it is nacked for the
+                # broker's 5 s (on borg2011-12k.mixed-backlog, where
+                # 60 % of the jobs are one alloc: 10-21 batches and
+                # 4-7 s of a 11 s window, one run in four; PERF.md
+                # section 6, PR 32). Such a batch has nothing to wait
+                # for on the device: its commit takes milliseconds once
+                # the FIFO reaches it. Wait for it, before the snapshot
+                # is taken. A batch with a kernel or a pool RPC in
+                # flight (dense, preempt, remote: `solved_in_begin`
+                # false) is never waited for: the overlap with it is
+                # the pipeline's point.
+                metrics.incr("nomad.worker.chain.waited")
+                with trace.span(trace.current(), "chain.wait"):
+                    while not committed.wait(0.05):
+                        if self._stop.is_set():
+                            break
         with trace.span(trace.current(), "snapshot.wait", index=wait_index):
             snapshot = self.server.state.snapshot_min_index(
                 wait_index, timeout_s=5

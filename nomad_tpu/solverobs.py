@@ -456,12 +456,19 @@ record_outputs = _global.record_outputs
 signatures = _global.signatures
 
 
-def timed_call(kernel: str, signature: tuple, fn, *args, **kwargs):
+def timed_call_verdict(kernel: str, signature: tuple, fn, *args, **kwargs):
     """Run a jit entry point under the compile ledger: times the call
     (tracing + compilation happen synchronously at dispatch; execution
-    is async and NOT awaited here) and records compile-vs-hit."""
+    is async and NOT awaited here) and records compile-vs-hit. Returns
+    (the call's result, the ledger's verdict: True for a compile)."""
     t0 = time.monotonic_ns()
     out = fn(*args, **kwargs)
-    if record_call(kernel, signature, time.monotonic_ns() - t0):
+    compiled = record_call(kernel, signature, time.monotonic_ns() - t0)
+    if compiled:
         record_outputs(kernel, out)
-    return out
+    return out, compiled
+
+
+def timed_call(kernel: str, signature: tuple, fn, *args, **kwargs):
+    """timed_call_verdict for a caller that has no use for the verdict."""
+    return timed_call_verdict(kernel, signature, fn, *args, **kwargs)[0]
