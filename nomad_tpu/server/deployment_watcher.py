@@ -6,9 +6,11 @@ auto-promoting canaries, auto-reverting on failure, and emitting follow-up
 evals so the scheduler continues (or rolls back) the rollout.
 
 TPU-native redesign: instead of one goroutine per deployment blocking on
-state watch channels, a single reconciliation pass (`run_once`) judges ALL
-active deployments against one state snapshot — the same batching philosophy
-as the TPU placement solver. A background thread polls; tests call
+state watch channels, a single reconciliation pass (`run_once`) walks the
+deployments table and judges the deployments that were written since they
+were last judged, or whose deadline is due — what a watch channel would
+have woken, found by comparing the store's touch sequence
+(`StateStore.deployments_touched`). A background thread polls; tests call
 `run_once` directly for determinism.
 """
 
@@ -83,6 +85,12 @@ class DeploymentsWatcher:
         self.poll_interval_s = poll_interval_s
         self._stop = threading.Event()
         self._thread: Optional[threading.Thread] = None
+        # What the last look at each deployment rested on: deployment id
+        # -> ((the store's touch sequence, the job's job_modify_index),
+        # the earliest now_ns at which the same reads would be judged
+        # otherwise; 0 = never). A deployment whose pair still reads the
+        # same and whose time has not come is left alone.
+        self._judged: dict[str, tuple[tuple[int, int], int]] = {}
 
     # -- lifecycle -----------------------------------------------------
 
@@ -90,6 +98,8 @@ class DeploymentsWatcher:
         # Fresh Event per incarnation (see drainer.start): a thread that
         # outlives join(timeout) polls its own event and still exits.
         self._stop = threading.Event()
+        # a leadership gained judges everything once
+        self._judged.clear()
         self._thread = threading.Thread(
             target=self._run, args=(self._stop,), daemon=True,
             name="deployment-watcher"
@@ -112,43 +122,91 @@ class DeploymentsWatcher:
     # -- the reconciliation pass ---------------------------------------
 
     def run_once(self) -> int:
-        """Judge every active deployment. Returns number acted upon.
+        """Judge the active deployments that were written since they
+        were last judged, or are due. Returns number acted upon.
+
+        A pass walks the deployments table — one sequence compare and
+        one job lookup a deployment — and reads the allocs only of a
+        deployment that the store says was touched (itself, or an alloc
+        carrying its id, written or deleted), whose job was registered
+        anew, or whose earliest deadline (an unjudged alloc's healthy
+        deadline, a group's progress deadline) has passed: it costs what
+        changed, not deployments x allocs. A watcher just started and a
+        restored store judge everything once; `_judge`'s own raft
+        applies touch the deployment, so it is judged again on the next
+        pass until it has nothing left to do.
 
         One pass is one `deploywatch.pass` trace (when tracing is on),
-        and always one observation of the deployments it judged — the
-        pass is O(active deployments) and shares the interpreter with
-        the solve and commit threads."""
+        and always one observation each of the deployments it judged
+        and of the active ones it could have judged."""
         tctx = trace.start_trace("deploywatch.pass", cpu=True)
-        acted = scanned = 0
+        acted = scanned = active = due = 0
+        judged = self._judged
         try:
             with trace.use(tctx):
+                # the sequences BEFORE the tables they guard: a write
+                # that lands after this line reads as newer next pass
+                touched = self.state.deployments_touched()
+                now = now_ns()
                 for d in self.state.deployments():
-                    if d.status == DEPLOYMENT_STATUS_SUCCESSFUL:
+                    successful = d.status == DEPLOYMENT_STATUS_SUCCESSFUL
+                    if not successful:
+                        if not d.active() or d.status == "paused":
+                            continue
+                        active += 1
+                    job = self.state.job_by_id(d.namespace, d.job_id)
+                    key = (
+                        touched.get(d.id, 0),
+                        job.job_modify_index if job is not None else -1,
+                    )
+                    last = judged.get(d.id)
+                    if last is not None and last[0] == key:
+                        if not last[1] or now <= last[1]:
+                            continue
+                        due += 1
+                    if successful:
                         # A deployment may be completed by the
                         # reconciler's plan (deployment_updates in the
                         # committed plan) rather than by this watcher —
                         # job stability still must follow.
                         self._mark_job_stable(d)
-                        continue
-                    if not d.active() or d.status == "paused":
+                        judged[d.id] = (key, 0)
                         continue
                     scanned += 1
-                    if self._judge(d):
+                    did_act, next_due = self._judge(d)
+                    if did_act:
+                        # judged again next pass, whatever the apply
+                        # wrote, until there is nothing left to do
                         acted += 1
+                        judged.pop(d.id, None)
+                    else:
+                        judged[d.id] = (key, next_due)
+                # forget the deployments that are gone
+                for did in judged.keys() - touched.keys():
+                    del judged[did]
         finally:
             if tctx is not None:
-                tctx.set_attr("scanned", scanned)
+                tctx.set_attr("judged", scanned)
+                tctx.set_attr("active", active)
+                tctx.set_attr("due", due)
                 tctx.set_attr("acted", acted)
                 tctx.finish()
             metrics.observe("nomad.deploywatch.scanned", scanned)
+            metrics.observe("nomad.deploywatch.active", active)
         return acted
 
-    def _judge(self, d: Deployment) -> bool:
-        allocs = self.state.allocs_by_deployment(d.id)
+    def _judge(self, d: Deployment) -> tuple[bool, int]:
+        """Decide one deployment from its allocs: (acted, the earliest
+        deadline found that has not passed yet, in now_ns; 0 for none).
+        Until that time, and until something is written, the same reads
+        decide the same."""
+        # SoA rows stay handles: every field read here is a column's
+        allocs = self.state.allocs_by_deployment(d.id, lazy=True)
         healthy: dict[str, int] = {g: 0 for g in d.task_groups}
         unhealthy_ids: list[str] = []
         canary_healthy: dict[str, int] = {g: 0 for g in d.task_groups}
         now = now_ns()
+        next_due = 0
 
         for a in allocs:
             if a.terminal_status():
@@ -176,21 +234,23 @@ class DeploymentsWatcher:
                     unhealthy_ids.append(a.id)
                 elif a.client_status == "failed":
                     unhealthy_ids.append(a.id)
+                elif deadline and (not next_due or deadline < next_due):
+                    next_due = deadline
 
         # 1. unhealthy allocs → fail (with optional auto-revert)
         if unhealthy_ids:
             self._fail(d, unhealthy_ids)
-            return True
+            return True, 0
 
         # 2. progress deadline exceeded → fail
         for g, dstate in d.task_groups.items():
-            if (
-                dstate.require_progress_by_ns
-                and now > dstate.require_progress_by_ns
-                and healthy[g] < dstate.desired_total
-            ):
-                self._fail(d, [], desc=DESC_PROGRESS_DEADLINE)
-                return True
+            by = dstate.require_progress_by_ns
+            if by and healthy[g] < dstate.desired_total:
+                if now > by:
+                    self._fail(d, [], desc=DESC_PROGRESS_DEADLINE)
+                    return True, 0
+                if not next_due or by < next_due:
+                    next_due = by
 
         # 3. auto-promote when all canaries are healthy
         if d.requires_promotion() and d.has_auto_promote():
@@ -201,7 +261,7 @@ class DeploymentsWatcher:
             )
             if ready:
                 self.promote(d)
-                return True
+                return True, 0
 
         # 4. counter drift: resync healthy counts so `nomad deployment
         # status` and the reconciler's computeLimit see fresh numbers.
@@ -224,7 +284,7 @@ class DeploymentsWatcher:
                     "eval": self._new_eval(d),
                 },
             )
-            return True
+            return True, 0
 
         # 5. all groups fully healthy (and promoted) → successful
         complete = all(
@@ -240,8 +300,8 @@ class DeploymentsWatcher:
                 ),
             )
             self._mark_job_stable(d)
-            return True
-        return False
+            return True, 0
+        return False, next_due
 
     # -- actions (also the Deployment RPC endpoints' backend) ----------
 

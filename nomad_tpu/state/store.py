@@ -479,11 +479,30 @@ class _ReadMixin:
             )
         ]
 
-    @_locked_on_live
-    def allocs_by_deployment(self, deployment_id: str) -> list[Allocation]:
+    def allocs_by_deployment(
+        self, deployment_id: str, lazy: bool = False
+    ) -> list[Allocation]:
+        """The deployment's allocs, read through its job's index: every
+        alloc of a deployment belongs to the deployment's job, so this
+        costs the job's allocs, not the store's. An unknown deployment
+        has none. lazy=True hands back SoA rows as their AllocRow
+        handles, for a caller that reads only what a handle answers
+        from its batch's columns (ids, statuses, task group, deployment
+        status, the create and modify times). No lock needed: the inner
+        dict's values are copied in one C call before the filter runs."""
+        d = self._tables[TABLE_DEPLOYMENTS].get(deployment_id)
+        if d is None:
+            return []
+        rows = list(
+            self._tables[IDX_ALLOCS_JOB]
+            .get((d.namespace, d.job_id), {})
+            .values()
+        )
+        if lazy:
+            return [a for a in rows if a.deployment_id == deployment_id]
         return [
             a.get() if a.__class__ is AllocRow else a
-            for a in self._tables[TABLE_ALLOCS].values()
+            for a in rows
             if a.deployment_id == deployment_id
         ]
 
@@ -699,6 +718,19 @@ class StateStore(_ReadMixin):
         # Cleared whenever a snapshot is taken. Without this, every index
         # insert copies the inner dict — O(n²) across a bulk plan apply.
         self._idx_owned: set[tuple[str, object]] = set()
+        # deployment id -> the store's write sequence when the
+        # deployment, or an alloc that carries its id, was last written
+        # or deleted: what lets the deployment watcher leave alone a
+        # deployment that nothing touched (deployments_touched). A
+        # sequence of the store's own and not the raft index: one plan
+        # apply writes a deployment and then its allocs at ONE index,
+        # and the watcher reads between them without the lock; a
+        # restore may move indexes backwards, this never does. Beside
+        # the tables, not of them: the live store's alone, not
+        # snapshotted, not persisted (a restore touches every
+        # deployment anew).
+        self._deploy_touched: dict[str, int] = {}
+        self._touch_seq = 0
         self._lock = threading.RLock()
         self._cv = threading.Condition(self._lock)
         # Event hooks: called under lock with
@@ -918,6 +950,9 @@ class StateStore(_ReadMixin):
             self._latest_index = data["latest"]
             self._shared = set()
             self._idx_owned.clear()
+            self._deploy_touched = {}
+            for did in self._tables[TABLE_DEPLOYMENTS]:
+                self._touch_deployment(did)
             self._notify_restore()
             self._cv.notify_all()
 
@@ -986,6 +1021,21 @@ class StateStore(_ReadMixin):
                 del t[key]
                 self._idx_owned.discard((table, key))
 
+    def _touch_deployment(self, deployment_id: str) -> None:
+        """Caller holds the lock and has ALREADY made the write: a
+        reader that takes deployments_touched() first and the tables
+        after it either sees the write or finds a later sequence the
+        next time it looks."""
+        if deployment_id in self._tables[TABLE_DEPLOYMENTS]:
+            self._touch_seq += 1
+            self._deploy_touched[deployment_id] = self._touch_seq
+
+    def deployments_touched(self) -> dict[str, int]:
+        """{deployment id: write sequence of its last touch}, an entry
+        for every deployment in the table, copied in one C call. Take
+        it BEFORE reading what it guards."""
+        return self._deploy_touched.copy()
+
     def _put_alloc(self, alloc: Allocation, existing: Optional[Allocation]) -> None:
         """Insert an alloc into the main table and every secondary index."""
         self._wtable(TABLE_ALLOCS)[alloc.id] = alloc
@@ -1011,6 +1061,9 @@ class StateStore(_ReadMixin):
         self._idx_put(IDX_ALLOCS_NODE, alloc.node_id, alloc)
         self._idx_put(IDX_ALLOCS_JOB, (alloc.namespace, alloc.job_id), alloc)
         self._idx_put(IDX_ALLOCS_EVAL, alloc.eval_id, alloc)
+        if existing is not None and existing.deployment_id != alloc.deployment_id:
+            self._touch_deployment(existing.deployment_id)
+        self._touch_deployment(alloc.deployment_id)
 
     def _del_alloc(self, alloc_id: str) -> None:
         t = self._wtable(TABLE_ALLOCS)
@@ -1025,6 +1078,7 @@ class StateStore(_ReadMixin):
             self._idx_del(IDX_ALLOCS_NODE, alloc.node_id, alloc_id)
             self._idx_del(IDX_ALLOCS_JOB, (alloc.namespace, alloc.job_id), alloc_id)
             self._idx_del(IDX_ALLOCS_EVAL, alloc.eval_id, alloc_id)
+            self._touch_deployment(alloc.deployment_id)
 
     # -- nodes ---------------------------------------------------------
 
@@ -1535,6 +1589,7 @@ class StateStore(_ReadMixin):
         # (solver._materialize_compact), so the contribution walk runs once
         # per distinct (resources, status) instead of once per alloc.
         contrib_cache: dict[tuple, Optional[tuple]] = {}
+        touched_deployments: set[str] = set()
         for alloc in allocs:
             existing = t.get(alloc.id)
             if not owned or existing is not None:
@@ -1588,6 +1643,8 @@ class StateStore(_ReadMixin):
                 ce = usage_contribution(existing)
                 _usage_sub(ut, existing.node_id, ce)
                 _prio_sub(pt, tt, existing, ce)
+                touched_deployments.add(existing.deployment_id)
+            touched_deployments.add(alloc.deployment_id)
             ar = alloc.resources
             if ar is not None:
                 ck2 = (id(ar), alloc.desired_status, alloc.client_status)
@@ -1642,6 +1699,8 @@ class StateStore(_ReadMixin):
                 st[key] = summary
         for ns, job_id in jobs_touched:
             self._update_job_status_txn(index, ns, job_id)
+        for did in touched_deployments:
+            self._touch_deployment(did)
         return stored
 
     @staticmethod
@@ -1786,6 +1845,7 @@ class StateStore(_ReadMixin):
             st[key] = summary
             jobs_touched.add(key)
             stored.extend(hs)
+            self._touch_deployment(b.deployment_id)
         for ns, job_id in jobs_touched:
             self._update_job_status_txn(index, ns, job_id)
         return stored
@@ -2319,6 +2379,7 @@ class StateStore(_ReadMixin):
                             ds.placed_canaries.append(a.id)
                     d.modify_index = index
                     dt[dep_id] = d
+                    self._touch_deployment(dep_id)
                     deployment_events.append(d)
             if preemption_evals:
                 self._upsert_evals_txn(index, preemption_evals)
@@ -2360,6 +2421,7 @@ class StateStore(_ReadMixin):
         deployment.modify_index = index
         deployment.modify_time = now_ns()
         t[deployment.id] = deployment
+        self._touch_deployment(deployment.id)
 
     def _update_deployment_status_txn(self, index: int, update) -> None:
         t = self._wtable(TABLE_DEPLOYMENTS)
@@ -2372,6 +2434,7 @@ class StateStore(_ReadMixin):
         d.modify_index = index
         d.modify_time = now_ns()
         t[d.id] = d
+        self._touch_deployment(d.id)
 
     def update_deployment_status(self, index: int, update) -> None:
         with self._lock:
@@ -2387,6 +2450,8 @@ class StateStore(_ReadMixin):
         with self._lock:
             t = self._wtable(TABLE_DEPLOYMENTS)
             gone = [t.pop(did) for did in deployment_ids if did in t]
+            for d in gone:
+                self._deploy_touched.pop(d.id, None)
             self._stamp(index, TABLE_DEPLOYMENTS)
             if gone:
                 self._publish(
@@ -2433,6 +2498,7 @@ class StateStore(_ReadMixin):
             d.modify_index = index
             d.modify_time = now_ns()
             t[d.id] = d
+            self._touch_deployment(d.id)
             # clear the canary flag on promoted allocs
             at = self._wtable(TABLE_ALLOCS)
             for cid in canary_ids:
@@ -2491,7 +2557,7 @@ class StateStore(_ReadMixin):
             if existing is not None:
                 d = existing.copy()
                 counts: dict[str, list[int]] = {g: [0, 0] for g in d.task_groups}
-                for a in self.allocs_by_deployment(deployment_id):
+                for a in self.allocs_by_deployment(deployment_id, lazy=True):
                     if (
                         a.deployment_status is None
                         or a.task_group not in counts
@@ -2508,6 +2574,7 @@ class StateStore(_ReadMixin):
                 d.modify_index = index
                 d.modify_time = now_ns()
                 dt[d.id] = d
+                self._touch_deployment(d.id)
             if status_update is not None:
                 self._update_deployment_status_txn(index, status_update)
             if revert_job is not None:

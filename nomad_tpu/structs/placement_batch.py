@@ -373,12 +373,24 @@ class AllocRow:
         return ALLOC_CLIENT_STATUS_PENDING
 
     @property
+    def deployment_status(self):
+        return None  # fast-mint rows carry no health and no canary mark
+
+    @property
     def create_index(self) -> int:
         return self.b.create_index
 
     @property
     def modify_index(self) -> int:
         return self.b.modify_index
+
+    @property
+    def create_time(self) -> int:
+        return self.b.create_time
+
+    @property
+    def modify_time(self) -> int:
+        return self.b.modify_time
 
     def terminal_status(self) -> bool:
         return False  # fresh run/pending by construction

@@ -900,15 +900,20 @@ def test_deploywatch_pass_is_a_trace_with_scanned_and_its_histograms():
         cap = reg.enable_timing_capture(cap=64)
         assert w.run_once() == 0  # tracing off: `scanned` all the same
         trace.set_enabled(True)
+        # one of the two running deployments is written again: the
+        # traced pass judges it alone, of two it could have judged
+        state.upsert_deployment(20, state.deployment_by_id("d-1"))
         w.run_once()
         got = reg.drain_timings(cap)
     finally:
         metrics._install_registry(old)
-    assert got["nomad.deploywatch.scanned"] == [2, 2]
+    assert got["nomad.deploywatch.scanned"] == [2, 1]
+    assert got["nomad.deploywatch.active"] == [2, 2]
     # the traced pass alone left the cpu span's two histograms: they
     # and the trace hold a pass's wall time, and nothing else does
     assert set(got) == {
         "nomad.deploywatch.scanned",
+        "nomad.deploywatch.active",
         "nomad.trace.wall_seconds.deploywatch.pass",
         "nomad.trace.offcpu_seconds.deploywatch.pass",
     }
@@ -916,8 +921,9 @@ def test_deploywatch_pass_is_a_trace_with_scanned_and_its_histograms():
     assert len(got["nomad.trace.offcpu_seconds.deploywatch.pass"]) == 1
     passes = trace.recorder().list(name="deploywatch.pass")
     assert len(passes) == 1
-    assert passes[0]["attrs"]["scanned"] == 2
-    assert passes[0]["attrs"]["acted"] == 0
+    attrs = passes[0]["attrs"]
+    assert [attrs[k] for k in ("judged", "active", "due", "acted")] == \
+        [1, 2, 0, 0]
     t = trace.recorder().get(passes[0]["id"])
     assert t["spans"][0]["name"] == "deploywatch.pass"
     assert "cpu" in t["spans"][0]
