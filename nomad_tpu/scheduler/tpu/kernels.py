@@ -153,6 +153,28 @@ def compact_programs() -> list[tuple[int, int]]:
     return [(gp, maxc) for gp in G_LADDER for maxc in C_LADDER]
 
 
+def pad_t(t: int) -> int:
+    """Tier-axis bucket of the preempt solve's prefix: room for `t`
+    priority tiers and the all-zero row before them, in multiples of 4
+    (4 holds three tiers), so that the program does not follow the
+    number of distinct priorities a cluster happens to hold."""
+    return max(4, _pad_to(t + 1, 4))
+
+
+def preempt_programs() -> list[tuple[int, int]]:
+    """Every (group bucket, tier bucket) the preempt solve compiles for
+    the batches a worker drains on a cluster of up to three priority
+    tiers, in order: the closed set a warm-up has to finish, for each
+    node bucket, as compact_programs() is the compact solve's. The
+    dense [G, N] result has no readback rung, so a batch's program
+    follows from its group count (pad_g) and the cluster's tiers
+    (pad_t) alone; the retry of a spread's leftovers runs the same
+    kernel with every tier limit 0. A cluster of more tiers lands on the
+    next tier bucket, outside this list, and is counted by
+    `nomad.tpu.preempt.programs_new`."""
+    return [(gp, pad_t(3)) for gp in G_LADDER]
+
+
 def _score_nodes(cap_f, used_f, ask_f, bias_g):
     """Vectorized ScoreFitBinPack after hypothetically adding one instance.
 

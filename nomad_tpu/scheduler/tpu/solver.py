@@ -19,6 +19,7 @@ rejection and RefreshIndex semantics are untouched.
 from __future__ import annotations
 
 import logging
+import math
 import time
 from dataclasses import dataclass, field, fields as dataclass_fields
 from typing import NamedTuple, Optional
@@ -53,6 +54,7 @@ from .kernels import (
     pad_c,
     pad_g,
     pad_n,
+    pad_t,
     solve_placement,
     solve_placement_compact,
     solve_placement_preempt,
@@ -95,6 +97,25 @@ class SolveOutcome:
     # Per-ALLOC because one eval can mix host-path asks (sticky groups)
     # with dense-kernel asks in the same batch.
     pre_appended: set = field(default_factory=set)
+
+
+def may_preempt(state, config: SchedulerConfig, jobs, extra_tiers=()) -> bool:
+    """Can a batch of these jobs — (scheduler type, priority) each —
+    take a victim at all? Only when a job whose type the operator lets
+    preempt sits PRIORITY_DELTA above some committed alloc's priority:
+    the store's priority-count aggregate proves absence in O(1), without
+    walking allocs (the common all-priority-50 cluster). `extra_tiers`:
+    priorities standing beside the store's (a batch's host partition).
+    The solver picks its kernel by it, and the worker decides by it
+    whether a batch may solve beside an uncommitted one."""
+    prios = [p for t, p in jobs if config.preemption_enabled(t)]
+    if not prios:
+        return False
+    tiers = getattr(state, "alloc_priority_tiers", None)
+    if tiers is None:
+        return True  # a state that keeps no aggregate proves nothing
+    top = max(prios)
+    return any(top - p >= PRIORITY_DELTA for p in (*tiers(), *extra_tiers))
 
 
 class _Lowered(NamedTuple):
@@ -768,6 +789,8 @@ class BatchSolver:
         return PendingSolve(gen, None)
 
     def _solve_gen(self, asks: list[GroupAsk]):
+        from ... import metrics
+
         out = SolveOutcome()
         self._outcome = out
         self._batch_has_cores = False
@@ -912,6 +935,19 @@ class BatchSolver:
                 table, groups, used, tier_limit=tier_limit,
                 use_preempt=use_preempt,
             )
+            if use_preempt and usage_of is not None:
+                # The preempt solve's used' — placements added, what the
+                # kernel counted as freed subtracted — is offered to the
+                # NEXT batch as the compact solve's is. The host picks
+                # whole victims, which free at least what the kernel
+                # counted, so the tensor never shows room that is not
+                # there: sound for a follower that places into free room
+                # alone. One that may preempt would read its tiers from
+                # a store that still holds this batch's victims and take
+                # them again; the worker has it wait for this batch's
+                # commit instead (worker._solve_batch, docs/pipeline.md).
+                metrics.incr("nomad.tpu.preempt.chain_offered")
+                self.chain_out = (tuple(node.id for node in nodes), pending[2])
         # -- phase boundary: the kernel is dispatched, nothing has read
         # it back. The pipelined worker parks here and resumes on its
         # commit stage, so the device round-trip (and everything below)
@@ -992,9 +1028,17 @@ class BatchSolver:
                     table, retry, inst2, over2, table.cap - used2,
                 )
             else:
-                assign2, _, _ = self._run_kernel(
-                    table, retry, used2, use_preempt=False
+                # the preempt solve retries on ITS kernel with every
+                # tier limit 0 — the plain waterfill, and a program of
+                # the closed set (kernels.preempt_programs) where the
+                # dense kernel would be one more family to warm
+                assign2, _, used_retry = self._run_kernel(
+                    table, retry, used2,
+                    tier_limit=np.zeros(len(retry), dtype=np.int32),
+                    use_preempt=use_preempt,
                 )
+                if self.chain_out is not None:
+                    self.chain_out = (self.chain_out[0], used_retry)
                 leftovers2, mat2_ns = self._timed_materialize(
                     self._materialize, table, retry, assign2, None
                 )
@@ -1008,8 +1052,6 @@ class BatchSolver:
         self._record_failures(final_unplaced, n)
         # solve_ns excludes any pipeline gap between the two phases
         out.solve_ns = phase_a_ns + (now_ns() - t0)
-        from ... import metrics
-
         metrics.time_ns("nomad.tpu.solve_seconds", out.solve_ns)
         # Alloc materialization joins the host_prep/device/readback stage
         # registry so the bench's breakdown covers the full commit half.
@@ -1153,12 +1195,29 @@ class BatchSolver:
                         self._fail_all(out, ask, {})
                     return "done", None
                 if micro_wanted and self._past_micro_bound(nodes, asks):
-                    # the host stack takes it whatever the lowering
-                    # would say, and uses none of it
-                    metrics.observe(
-                        "nomad.tpu.lower_skipped", total_requests
-                    )
-                    return to_host
+                    if self.solve_preempt_fn is not None and may_preempt(
+                        self.state, self.config,
+                        ((a.job.type, a.job.priority) for a in asks),
+                    ):
+                        # Past the bound a small batch that may preempt
+                        # is the tier kernel's, not the host stack's: the
+                        # stack walks every node for a placement there
+                        # and draws its node from a shuffled sample
+                        # (upstream's limit iterator), so it takes a
+                        # higher band on the node it drew while a lower
+                        # one stands elsewhere — the kernel opens a band
+                        # only once every lower one is spent wherever the
+                        # group can go (PERF.md section 6, PR 35). Within
+                        # the bound the stack keeps it, as it did. O(1):
+                        # the store's priority counts.
+                        micro_wanted = False
+                    else:
+                        # the host stack takes it whatever the lowering
+                        # would say, and uses none of it
+                        metrics.observe(
+                            "nomad.tpu.lower_skipped", total_requests
+                        )
+                        return to_host
                 tab = self._lower_table(nodes, asks, micro_wanted)
                 if tab is None:
                     return to_host
@@ -1194,7 +1253,8 @@ class BatchSolver:
                 return "done", None
             out.groups = len(groups)
 
-            self._victimized: set[str] = set()
+            # node index -> the node's allocs a placement may still evict
+            self._victim_cands: dict[int, list] = {}
             used = np.clip(table.used, 0, 2**31 - 1).astype(np.int32)
 
             tier_limit = np.zeros(len(groups), dtype=np.int32)
@@ -1313,28 +1373,13 @@ class BatchSolver:
         # partition's placements applied as per-node adjustments; one
         # that may preempt takes its tiers from the store's usage by
         # (node, priority) the same way, adjusted per tier.
-        preempt_possible = self.solve_preempt_fn is not None and any(
-            self.config.preemption_enabled(a.job.type) for a in asks
-        )
-        if preempt_possible and hasattr(self.state, "alloc_priority_tiers"):
-            # Exact O(1) refinement: preemption can only trigger when some
-            # committed alloc's priority sits PRIORITY_DELTA below a batch
-            # job's — the store's priority-count aggregate proves absence
-            # without walking allocs (the common all-priority-50 cluster).
-            maxprio = max(
-                a.job.priority
-                for a in asks
-                if self.config.preemption_enabled(a.job.type)
-            )
-            tiers = list(self.state.alloc_priority_tiers())
+        preempt_possible = self.solve_preempt_fn is not None and may_preempt(
+            self.state, self.config,
+            ((a.job.type, a.job.priority) for a in asks),
             # same-batch host-partition placements are preemptible too
             # (they're in the dense table's live view)
-            tiers.extend(
-                alloc_priority(a) for a in self._partition_placed
-            )
-            preempt_possible = any(
-                maxprio - p >= PRIORITY_DELTA for p in tiers
-            )
+            [alloc_priority(a) for a in self._partition_placed],
+        )
         if micro_wanted and preempt_possible:
             # preemption needs the tier kernel (or the host stack's
             # per-request evict pass) — keep the host path for it
@@ -1601,14 +1646,27 @@ class BatchSolver:
                             tg, penalty_nodes=penalty, metrics=metric,
                             selected_nodes=[prev_node],
                         )
-                if option is None:
+                # A task group that has failed to place once in this eval
+                # is not walked again for its other requests: they ask
+                # the same of a cluster that only got fuller, and each
+                # walk of a FULL cluster visits every node (the limit
+                # iterator never finds its candidates) — 48 requests of
+                # one small follow-up eval held the solve thread 18 s on
+                # 12,583 full machines (PERF.md section 6, PR 35). The
+                # reference coalesces at the same place
+                # (generic_sched.go computePlacements, failedTGAllocs).
+                failed_before = out.failures.get(ask.eval_obj.id, {}).get(
+                    ask.tg_name
+                ) is not None
+                if option is None and not failed_before:
                     option = stack.select(
                         tg, penalty_nodes=penalty, metrics=metric
                     )
-                if option is None and preempt_ok:
-                    option = stack.select(
-                        tg, penalty_nodes=penalty, metrics=metric, evict=True
-                    )
+                    if option is None and preempt_ok:
+                        option = stack.select(
+                            tg, penalty_nodes=penalty, metrics=metric,
+                            evict=True,
+                        )
                 metric.allocation_time_ns = now_ns() - start
                 metric.nodes_evaluated = ctx.metrics_nodes_evaluated
                 if option is None:
@@ -2054,10 +2112,9 @@ class BatchSolver:
             tl[:g] = tier_limit[:g]
             tier_limit = tl
             t = len(table.tier_prios)
-            # Pad the tier axis to a bucket (like pad_n/pad_g): the jit
-            # kernel must not recompile every time the number of
-            # distinct alloc priorities in the cluster changes.
-            tp = max(4, -(-(t + 1) // 4) * 4)
+            # the tier axis has its bucket as the node and group axes
+            # have theirs: the program is one of kernels.preempt_programs
+            tp = pad_t(t)
             tctx = trace.current()
             with trace.span(tctx, "preempt.prefix", cpu=True, tiers=t):
                 prefix = np.zeros((tp, np_, 3), dtype=np.int32)
@@ -2069,6 +2126,14 @@ class BatchSolver:
                     # padded tail repeats the full sum so any (unused)
                     # out-of-range index still reads a valid prefix
                     prefix[t + 1 :, :n] = cum[-1].astype(np.int32)
+                    # nodes on which more than one tier stands: where
+                    # the prefix has more than one step
+                    stands = (np.asarray(table.tier_used)[:, :n] > 0).any(
+                        axis=2)
+                    metrics.observe(
+                        "nomad.tpu.preempt.multi_tier_nodes",
+                        int((stands.sum(axis=0) > 1).sum()),
+                    )
                 solverobs.record_transfer(
                     "h2d", prefix.nbytes + tier_limit.nbytes
                 )
@@ -2083,11 +2148,16 @@ class BatchSolver:
                 self.solve_preempt_fn, "__name__", "solve_placement_preempt"
             )
             metrics.observe("nomad.tpu.preempt.groups", g)
-            assign, assign_evict, used_out = solverobs.timed_call(
-                kname, (kname, np_, gp, tp), self.solve_preempt_fn,
-                cap, used, prefix, asks_arr, counts, feas, bias, ucap,
-                tier_limit,
-            )
+            (assign, assign_evict, used_out), compiled = \
+                solverobs.timed_call_verdict(
+                    kname, (kname, np_, gp, tp), self.solve_preempt_fn,
+                    cap, used, prefix, asks_arr, counts, feas, bias, ucap,
+                    tier_limit,
+                )
+            if compiled:
+                # the compile ledger met the signature for the first
+                # time: one event a compile, as the compact solve's
+                metrics.observe("nomad.tpu.preempt.programs_new", 1)
             return assign, assign_evict, used_out, g, n, time.perf_counter()
         kname = getattr(self.solve_fn, "__name__", "solve_placement")
         assign, used_out = solverobs.timed_call(
@@ -2124,12 +2194,12 @@ class BatchSolver:
         rb_ns = now_ns() - t_rb0
         metrics.time_ns("nomad.tpu.readback_seconds", rb_ns)
         trace.stage("readback", rb_ns)
-        solverobs.record_transfer(
-            "d2h",
-            assign.nbytes
-            + (assign_evict.nbytes if assign_evict is not None else 0),
-            dur_ns=rb_ns, span=True,
+        nbytes = assign.nbytes + (
+            assign_evict.nbytes if assign_evict is not None else 0
         )
+        if assign_evict is not None:
+            metrics.incr("nomad.tpu.preempt.readback_bytes", nbytes)
+        solverobs.record_transfer("d2h", nbytes, dur_ns=rb_ns, span=True)
         solverobs.sample_device_memory()
         return result
 
@@ -2301,28 +2371,7 @@ class BatchSolver:
                 fr[2] -= a2
                 return True
 
-            # A PlacementRun answers the per-request checks from its
-            # shared proto: iterating the run here would mint ~10^5
-            # request rows (dataclasses.replace each) per c2m solve —
-            # the exact cost the run exists to avoid, and the single
-            # hottest host site of the r10 profile when it regressed.
-            run_proto = getattr(reqs, "proto", None)
-            slow = (
-                bool(tg.networks)
-                or any(t.resources.networks for t in tg.tasks)
-                or any(t.resources.devices for t in tg.tasks)
-                # dedicated cores need per-placement id assignment
-                or any(t.resources.cores > 0 for t in tg.tasks)
-                # canaries carry a per-alloc deployment status
-                or (
-                    (run_proto.previous_alloc is not None or run_proto.canary)
-                    if run_proto is not None
-                    else any(
-                        r.previous_alloc is not None or r.canary for r in reqs
-                    )
-                )
-            )
-            if slow:
+            if self._needs_build(grp):
                 node_idx = row_placed.tolist()
                 for i, ni in enumerate(node_idx):
                     req = reqs[i]
@@ -2336,35 +2385,7 @@ class BatchSolver:
                         continue
                     placements.append(alloc)
             else:
-                # keyed by eval too: the broker serializes evals per job,
-                # but solve_eval_batch is public API — two evals of one
-                # job in a batch must not stamp each other's eval_id
-                # (the intended reuse — spread sub-groups, the
-                # relaxation retry — is all within one eval)
-                tmpl_key = (eval_id, id(grp.job), tg.name)
-                tmpl = self._mint_cache.get(tmpl_key)
-                if tmpl is None:
-                    shared_res = AllocatedResources(
-                        tasks={
-                            t.name: AllocatedTaskResources(
-                                cpu=t.resources.cpu,
-                                memory_mb=t.resources.memory_mb,
-                            )
-                            for t in tg.tasks
-                        },
-                        shared_disk_mb=tg.ephemeral_disk.size_mb,
-                    )
-                    tmpl = self._mint_cache[tmpl_key] = _MintTemplate(
-                        Allocation(
-                            namespace=grp.job.namespace,
-                            eval_id=eval_id,
-                            job_id=grp.job.id,
-                            job=grp.job,
-                            task_group=tg.name,
-                            resources=shared_res,
-                            metrics=group_alloc_metric(grp, n),
-                        )
-                    )
+                tmpl = self._mint_template(grp, n)
                 uuids = generate_uuids(placed) if placed else []
                 group_cpu = sum(t.resources.cpu for t in tg.tasks)
                 ap = placements.append
@@ -2431,6 +2452,67 @@ class BatchSolver:
                 leftovers[gi] = unplaced
         return leftovers
 
+    @staticmethod
+    def _needs_build(grp: LoweredGroup) -> bool:
+        """Does every alloc of the group need its own assembly
+        (_build_alloc: ports, device instances, core ids, a previous
+        alloc, a canary's status), or do they differ by id, name and
+        node alone and mint off one template? A PlacementRun answers
+        from its shared proto: iterating the run here would mint ~10^5
+        request rows (dataclasses.replace each) per c2m solve — the
+        exact cost the run exists to avoid, and the single hottest host
+        site of the r10 profile when it regressed."""
+        tg, reqs = grp.tg, grp.requests
+        run_proto = getattr(reqs, "proto", None)
+        return (
+            bool(tg.networks)
+            or any(t.resources.networks for t in tg.tasks)
+            or any(t.resources.devices for t in tg.tasks)
+            # dedicated cores need per-placement id assignment
+            or any(t.resources.cores > 0 for t in tg.tasks)
+            # canaries carry a per-alloc deployment status
+            or (
+                (run_proto.previous_alloc is not None or run_proto.canary)
+                if run_proto is not None
+                else any(
+                    r.previous_alloc is not None or r.canary for r in reqs
+                )
+            )
+        )
+
+    def _mint_template(self, grp: LoweredGroup, n: int) -> _MintTemplate:
+        """The group's interned alloc prototype. Keyed by eval too: the
+        broker serializes evals per job, but solve_eval_batch is public
+        API — two evals of one job in a batch must not stamp each
+        other's eval_id (the intended reuse — spread sub-groups, the
+        relaxation retry — is all within one eval)."""
+        eval_id, tg = grp.key[0], grp.tg
+        tmpl_key = (eval_id, id(grp.job), tg.name)
+        tmpl = self._mint_cache.get(tmpl_key)
+        if tmpl is None:
+            shared_res = AllocatedResources(
+                tasks={
+                    t.name: AllocatedTaskResources(
+                        cpu=t.resources.cpu,
+                        memory_mb=t.resources.memory_mb,
+                    )
+                    for t in tg.tasks
+                },
+                shared_disk_mb=tg.ephemeral_disk.size_mb,
+            )
+            tmpl = self._mint_cache[tmpl_key] = _MintTemplate(
+                Allocation(
+                    namespace=grp.job.namespace,
+                    eval_id=eval_id,
+                    job_id=grp.job.id,
+                    job=grp.job,
+                    task_group=tg.name,
+                    resources=shared_res,
+                    metrics=group_alloc_metric(grp, n),
+                )
+            )
+        return tmpl
+
     def _materialize(
         self,
         table,
@@ -2469,10 +2551,22 @@ class BatchSolver:
         tier_left = (np.array(table.tier_used, dtype=np.int64)
                      if assign_evict is not None else None)
         tier_of = {p: k for k, p in enumerate(table.tier_prios)}
+        n_placed = 0
         for gi, grp in enumerate(groups):
             eval_id = grp.key[0]
             placements = out.placements.setdefault(eval_id, [])
-            req_iter = iter(grp.requests)
+            # requests are handed out by index: iterating a PlacementRun
+            # would mint a row for every one of them
+            reqs = grp.requests
+            n_reqs = len(reqs)
+            names = grp.names if len(grp.names) == n_reqs else None
+            # allocs that differ by id, name and node alone mint off one
+            # template, as the compact path's do: _build_alloc's port
+            # index and metric for each were 0.07 ms a placement
+            tmpl = None
+            if not self._batch_has_cores and not self._needs_build(grp):
+                tmpl = self._mint_template(grp, n)
+            ri = 0
             unplaced: list = []
             a0, a1, a2 = (int(grp.ask[0]), int(grp.ask[1]), int(grp.ask[2]))
             node_indices = np.nonzero(assign[gi, :n])[0]
@@ -2485,9 +2579,9 @@ class BatchSolver:
                 )
                 row = free[ni]
                 for _ in range(take):
-                    req = next(req_iter, None)
-                    if req is None:
+                    if ri >= n_reqs:
                         break
+                    i, ri = ri, ri + 1
                     victims: list = []
                     if row[0] < a0 or row[1] < a1 or row[2] < a2:
                         if evict_budget > 0:
@@ -2495,19 +2589,38 @@ class BatchSolver:
                             victims = self._pick_victims(table, ni, grp) or []
                             pick_ns += now_ns() - t_pick
                         if not victims:
-                            unplaced.append(req)  # out of exact capacity
+                            unplaced.append(reqs[i])  # out of exact capacity
                             continue
-                    alloc = self._build_alloc(table, grp, node, req)
-                    if alloc is None:
-                        unplaced.append(req)  # port assignment failed
-                        continue
+                    if tmpl is not None:
+                        alloc = tmpl.mint(
+                            generate_uuid(),
+                            names[i] if names is not None else reqs[i].name,
+                            node,
+                        )
+                    else:
+                        alloc = self._build_alloc(table, grp, node, reqs[i])
+                        if alloc is None:
+                            unplaced.append(reqs[i])  # port assignment failed
+                            continue
+                    n_placed += 1
                     if victims:
                         evict_budget -= 1
                         n_preempting += 1
                         alloc.preempted_allocations = [v.id for v in victims]
                         pre = out.preemptions.setdefault(eval_id, [])
+                        # Whole victims free more than the shortage, and
+                        # what they leave over is room on the node for
+                        # whatever is placed there next — a placement of
+                        # ANOTHER eval too, whose plan then stands on
+                        # this plan's eviction. The eviction stays in the
+                        # preemptor's plan alone: groups are solved in
+                        # the order their plans are submitted in, and the
+                        # applier judges a batch's plans in that order,
+                        # each on what the ones before it left
+                        # (plan_apply._commit_merged), so the one that
+                        # draws is refused wherever the one that evicts
+                        # was.
                         for v in victims:
-                            self._victimized.add(v.id)
                             r = v.comparable_resources()
                             row[0] += r.cpu
                             row[1] += r.memory_mb
@@ -2525,7 +2638,8 @@ class BatchSolver:
                     row[1] -= a1
                     row[2] -= a2
                     placements.append(alloc)
-            unplaced.extend(req_iter)  # instances the kernel never placed
+            if ri < n_reqs:  # instances the kernel never placed
+                unplaced.extend(reqs[ri:])
             if unplaced:
                 leftovers[gi] = unplaced
             if max(grp_by_tier, default=0) > 0:
@@ -2533,8 +2647,15 @@ class BatchSolver:
                     grp, tier_left, grp_by_tier)
         if assign_evict is not None:
             n_evicted = sum(evicted_by_prio.values())
+            metrics.incr("nomad.tpu.preempt.placements", n_placed)
             metrics.incr("nomad.tpu.preempt.placed", n_preempting)
             metrics.incr("nomad.tpu.preempt.evicted", n_evicted)
+            # victims of any tier but the lowest that stands in the cell
+            metrics.incr(
+                "nomad.tpu.preempt.evicted_higher_tiers",
+                sum(c for p, c in evicted_by_prio.items()
+                    if tier_of.get(p, 0) > 0),
+            )
             metrics.incr(
                 "nomad.tpu.preempt.evicted_above_lowest", above_lowest)
             trace.stage_attrs(
@@ -2575,63 +2696,64 @@ class BatchSolver:
         enough for grp.ask from preemptible allocs, lowest priority tier
         first, closest resource distance within a tier (the Preemptor's
         scoring, reference preemption.go:198)."""
-        from ...structs import Resources
-        from ..preemption import PRIORITY_DELTA, basic_resource_distance
-
+        # The node's live allocs as (priority, cpu, mem, disk, alloc),
+        # lowest priority first, read from the store once a solve and
+        # kept: a victim leaves the list when it is picked, so no two
+        # placements take the same one. (Read anew for every placement
+        # it was a third of a 1,000-placement solve's materialize.)
+        cands = self._victim_cands.get(ni)
+        if cands is None:
+            cands = []
+            for a in table._allocs_by_node(table.nodes[ni].id):
+                r = a.comparable_resources()
+                cands.append(
+                    (alloc_priority(a), r.cpu, r.memory_mb, r.disk_mb, a))
+            cands.sort(key=lambda c: c[0])
+            self._victim_cands[ni] = cands
         row = self._free[ni]
-        shortage = [max(int(grp.ask[i]) - row[i], 0) for i in range(3)]
-        need = Resources(
-            cpu=shortage[0], memory_mb=shortage[1], disk_mb=shortage[2]
-        )
-        cands = []
-        for a in table._allocs_by_node(table.nodes[ni].id):
-            if a.id in self._victimized:
-                continue
-            if (
-                a.job_id == grp.job.id
-                and a.namespace == grp.job.namespace
-            ):
-                continue
-            prio = alloc_priority(a)
-            if grp.priority - prio < PRIORITY_DELTA:
-                continue
-            cands.append((prio, a))
-        if not cands:
-            return None
-        cands.sort(
-            key=lambda pa: (
-                pa[0],
-                basic_resource_distance(need, pa[1].comparable_resources()),
-            )
-        )
-        freed = [0, 0, 0]
+        s0, s1, s2 = (max(int(grp.ask[i]) - row[i], 0) for i in range(3))
+
+        def distance(c) -> float:
+            # basic_resource_distance(shortage, the alloc's resources)
+            x = (s0 - c[1]) / s0 if s0 > 0 else 0.0
+            y = (s1 - c[2]) / s1 if s1 > 0 else 0.0
+            z = (s2 - c[3]) / s2 if s2 > 0 else 0.0
+            return math.sqrt(x * x + y * y + z * z)
+
+        top = grp.priority - PRIORITY_DELTA
+        job_id, namespace = grp.job.id, grp.job.namespace
+        f0 = f1 = f2 = 0
         picks = []
-        for _, a in cands:
-            r = a.comparable_resources()
-            freed[0] += r.cpu
-            freed[1] += r.memory_mb
-            freed[2] += r.disk_mb
-            picks.append(a)
-            if (
-                freed[0] >= shortage[0]
-                and freed[1] >= shortage[1]
-                and freed[2] >= shortage[2]
-            ):
-                break
-        else:
+        covered = False
+        j, end = 0, len(cands)
+        while j < end and cands[j][0] <= top and not covered:
+            k = j
+            while k < end and cands[k][0] == cands[j][0]:
+                k += 1
+            tier = [c for c in cands[j:k]
+                    if c[4].job_id != job_id or c[4].namespace != namespace]
+            tier.sort(key=distance)
+            for c in tier:
+                f0, f1, f2 = f0 + c[1], f1 + c[2], f2 + c[3]
+                picks.append(c)
+                if f0 >= s0 and f1 >= s1 and f2 >= s2:
+                    covered = True
+                    break
+            j = k
+        if not covered:
             return None
         # no more victims than the shortage needs: drop, highest
         # priority first, every pick the others cover without (the
         # reference's filterSuperset; with equal asks the greedy walk
         # above already stops at one)
-        for a in reversed(picks[:-1]):
-            r = a.comparable_resources()
-            rest = (freed[0] - r.cpu, freed[1] - r.memory_mb,
-                    freed[2] - r.disk_mb)
-            if all(rest[i] >= shortage[i] for i in range(3)):
-                picks.remove(a)
-                freed = list(rest)
-        return picks
+        for c in reversed(picks[:-1]):
+            r0, r1, r2 = f0 - c[1], f1 - c[2], f2 - c[3]
+            if r0 >= s0 and r1 >= s1 and r2 >= s2:
+                picks.remove(c)
+                f0, f1, f2 = r0, r1, r2
+        for c in picks:
+            cands.remove(c)
+        return [c[4] for c in picks]
 
     def _live_allocs(self, node_id: str):
         """Non-terminal allocs minus this batch's plan-stops — the same

@@ -385,84 +385,151 @@ def _contribution_with_job(alloc, default_job):
 
 
 class OverlaySnapshot:
-    """The latest committed snapshot with one in-flight PlanResult
-    optimistically applied: what state WILL look like once the pending
-    plan's raft commit lands. Plan N+1 verifies against this while plan
-    N replicates — the pipelining of reference plan_apply.go:54-63,
-    without blocking on snapshotMinIndex.
+    """The latest committed snapshot with verified, not yet committed
+    PlanResults laid over it in order: what the store WILL hold once
+    they are applied. Two users. The pipelined applier verifies plan N+1
+    on plan N's result while N replicates (reference
+    plan_apply.go:54-63), without blocking on snapshotMinIndex. And the
+    plans of one batch are verified one after another, each on the
+    results of those before it (PlanApplier._commit_merged), and commit
+    as one raft entry.
 
-    Only the surface evaluate_plan reads is overlaid (allocs by id/node,
-    per-node usage); everything else delegates to the base snapshot.
-    Volume-touching plans never verify on an overlay (the applier drains
-    the pipeline first), so volume claims always read committed state."""
+    Lazy: add() files a result under the nodes it writes and notes what
+    it stops and places; a node's usage delta is summed when a later
+    plan asks for that node, and kept — so a batch pays for the nodes
+    its plans share, not for every row it places. Only the surface
+    evaluate_plan reads is overlaid (allocs by id/node, per-node usage);
+    everything else delegates to the base snapshot. Volume-touching
+    plans never verify on an overlay (the applier drains the pipeline
+    first, and keeps them out of a merged pass), so volume claims always
+    read committed state."""
 
-    def __init__(self, base, result: PlanResult, job) -> None:
+    def __init__(self, base, result: Optional[PlanResult] = None,
+                 job=None) -> None:
         self.base = base
         self.index = base.index
-        self._placed: dict[str, object] = {}
-        self._placed_by_node: dict[str, list] = {}
+        self.node_by_id = base.node_by_id  # evaluate_plan's hot read
+        # node id -> [(result, its plan's job)] that write it, in order
+        self._by_node: dict[str, list] = {}
         self._stopped: set[str] = set()
-        # node_id -> [cpu, mem, disk, complex] delta vs the base aggregate,
-        # mirroring exactly what the FSM's alloc writes will do to it.
-        delta: dict[str, list] = {}
+        self._placed: dict[str, object] = {}  # eager rows by id
+        self._batches: list = []  # SoA batches: rows resolve on demand
+        self._rows: Optional[dict] = None  # their id -> lazy row handle
+        self._counts: dict[int, dict] = {}  # id(batch) -> {node: rows}
+        # node id -> (results summed, [cpu, mem, disk, complex] delta vs
+        # the base aggregate), mirroring exactly what the FSM's alloc
+        # writes will do to it
+        self._delta: dict[str, tuple[int, list]] = {}
+        # alloc id -> what a summed result left it holding (None: nothing)
+        self._held: dict[str, Optional[tuple]] = {}
+        if result is not None:
+            self.add(result, job)
 
-        def _sub_stored(alloc_id: str, node_id: str) -> None:
-            stored = base.alloc_by_id(alloc_id)
-            if stored is None or stored.node_id != node_id:
-                return
-            c = usage_contribution(stored)
-            if c is not None:
-                d = delta.setdefault(node_id, [0, 0, 0, 0])
-                for i in range(4):
-                    d[i] -= c[i]
-
-        for node_id, allocs in result.node_update.items():
-            for a in allocs:
-                self._stopped.add(a.id)
-                _sub_stored(a.id, node_id)
-        for node_id, allocs in result.node_preemptions.items():
-            for a in allocs:
-                self._stopped.add(a.id)
-                _sub_stored(a.id, node_id)
-        for node_id, allocs in result.node_allocation.items():
-            bucket = self._placed_by_node.setdefault(node_id, [])
+    def add(self, result: PlanResult, job, nodes=None) -> None:
+        """Lay one more verified result on top. `nodes`: the node ids it
+        may write (its plan's partition key), when the caller has them."""
+        if nodes is None:
+            nodes = (
+                set(result.node_update)
+                | set(result.node_preemptions)
+                | set(result.node_allocation)
+            )
+            for b in result.alloc_batches:
+                nodes.update(nid for nid, _ti, _cnt in b.touched_nodes())
+        entry = (result, job)
+        by_node = self._by_node
+        for node_id in nodes:
+            writers = by_node.get(node_id)
+            if writers is None:
+                by_node[node_id] = [entry]
+            else:
+                writers.append(entry)
+        for allocs in result.node_update.values():
+            self._stopped.update(a.id for a in allocs)
+        for allocs in result.node_preemptions.values():
+            self._stopped.update(a.id for a in allocs)
+        for allocs in result.node_allocation.values():
             for a in allocs:
                 self._placed[a.id] = a
-                bucket.append(a)
-                _sub_stored(a.id, node_id)
-                c = _contribution_with_job(a, job)
-                if c is not None:
-                    d = delta.setdefault(node_id, [0, 0, 0, 0])
-                    for i in range(4):
-                        d[i] += c[i]
-        for b in result.alloc_batches:
-            # SoA rows overlay as lazy handles: the usage delta comes
-            # from the columns (count x shared contribution); a later
-            # plan's verification materializes a row only if it actually
-            # dereferences it (alloc_by_id / the exact per-node path)
-            c = b.row_contribution()
-            touched = b.touched_nodes()
-            for nid, _ti, cnt in touched:
-                d = delta.setdefault(nid, [0, 0, 0, 0])
-                d[0] += c[0] * cnt
-                d[1] += c[1] * cnt
-                d[2] += c[2] * cnt
-            ti_to_nid = {ti: nid for nid, ti, _cnt in touched}
-            idx = b.node_idx
-            for i, uid in enumerate(b.ids):
-                h = _row_handle(b, i)
-                self._placed[uid] = h
-                self._placed_by_node.setdefault(
-                    ti_to_nid[int(idx[i])], []
-                ).append(h)
-        self._usage_delta = delta
+        if result.alloc_batches:
+            self._batches.extend(result.alloc_batches)
+            self._rows = None
 
     def __getattr__(self, name):
         return getattr(self.base, name)
 
+    def stops_a_placement(self, plan: Plan) -> bool:
+        """Does the plan stop or evict an alloc that a result laid over
+        places or re-places — or one the base does not hold at all,
+        which an earlier result's SoA rows may be minting? The store
+        applies an entry's stops before its placements, so such a stop
+        has to land in a LATER entry than the placement."""
+        for table in (plan.node_update, plan.node_preemptions):
+            for allocs in table.values():
+                for a in allocs:
+                    if (
+                        a.id in self._placed
+                        or self.base.alloc_by_id(a.id) is None
+                    ):
+                        return True
+        return False
+
+    def _release(self, d: list, alloc_id: str, node_id: str) -> None:
+        """Take out of the node's delta what the alloc holds there now:
+        what an earlier result of the overlay left it, else what the
+        base stores. Nothing twice: two results may stop one alloc."""
+        if alloc_id in self._held:
+            c = self._held[alloc_id]
+        else:
+            stored = self.base.alloc_by_id(alloc_id)
+            c = (
+                usage_contribution(stored)
+                if stored is not None and stored.node_id == node_id
+                else None
+            )
+        self._held[alloc_id] = None
+        if c is not None:
+            for i in range(4):
+                d[i] -= c[i]
+
+    def _node_delta(self, node_id: str) -> Optional[list]:
+        writers = self._by_node.get(node_id)
+        if not writers:
+            return None
+        seen, d = self._delta.get(node_id, (0, None))
+        if seen == len(writers):
+            return d
+        d = [0, 0, 0, 0] if d is None else list(d)
+        for result, job in writers[seen:]:
+            for a in result.node_update.get(node_id, ()):
+                self._release(d, a.id, node_id)
+            for a in result.node_preemptions.get(node_id, ()):
+                self._release(d, a.id, node_id)
+            for a in result.node_allocation.get(node_id, ()):
+                self._release(d, a.id, node_id)
+                c = self._held[a.id] = _contribution_with_job(a, job)
+                if c is not None:
+                    for i in range(4):
+                        d[i] += c[i]
+            for b in result.alloc_batches:
+                # SoA rows: count x the batch's shared contribution
+                counts = self._counts.get(id(b))
+                if counts is None:
+                    counts = self._counts[id(b)] = {
+                        nid: cnt for nid, _ti, cnt in b.touched_nodes()
+                    }
+                cnt = counts.get(node_id)
+                if cnt:
+                    c = b.row_contribution()
+                    d[0] += c[0] * cnt
+                    d[1] += c[1] * cnt
+                    d[2] += c[2] * cnt
+        self._delta[node_id] = (len(writers), d)
+        return d
+
     def node_usage(self, node_id: str):
         base = self.base.node_usage(node_id)
-        d = self._usage_delta.get(node_id)
+        d = self._node_delta(node_id)
         if d is None:
             return base
         return (base[0] + d[0], base[1] + d[1], base[2] + d[2], base[3] + d[3])
@@ -472,7 +539,19 @@ class OverlaySnapshot:
         if a is not None:
             return a
         a = self.base.alloc_by_id(alloc_id)
-        if a is not None and alloc_id in self._stopped:
+        if a is None:
+            if not self._batches:
+                return None
+            if self._rows is None:
+                # a later plan names a row an earlier one minted (rare):
+                # handles for every batch row, once
+                self._rows = {
+                    uid: _row_handle(b, i)
+                    for b in self._batches
+                    for i, uid in enumerate(b.ids)
+                }
+            return self._rows.get(alloc_id)
+        if alloc_id in self._stopped:
             from ..structs.structs import ALLOC_DESIRED_STATUS_STOP
 
             a = a.copy()
@@ -480,16 +559,21 @@ class OverlaySnapshot:
         return a
 
     def allocs_by_node_terminal(self, node_id: str, terminal: bool = False):
+        placed: dict[str, object] = {}
+        for result, _job in self._by_node.get(node_id, ()):
+            for a in result.node_allocation.get(node_id, ()):
+                placed[a.id] = a
+            if result.alloc_batches:
+                for a in _batch_rows_for_node(result, node_id):
+                    placed[a.id] = a
         out = []
         for a in self.base.allocs_by_node_terminal(node_id, terminal):
-            if a.id in self._placed:
+            if a.id in placed:
                 continue
             if not terminal and a.id in self._stopped:
                 continue
             out.append(a)
-        for a in self._placed_by_node.get(node_id, []):
-            if a.terminal_status() == terminal:
-                out.append(a)
+        out.extend(a for a in placed.values() if a.terminal_status() == terminal)
         return out
 
 
@@ -515,25 +599,27 @@ def partition_plan_batch(
     plans: list[Plan],
     keys: Optional[list[tuple[set, bool, Optional[tuple]]]] = None,
 ) -> tuple[list[int], list[int]]:
-    """Per-node conflict partition of a same-snapshot plan batch.
+    """Which plans of a same-snapshot batch verify and commit together.
 
-    Returns (merged, serial) index lists. A plan joins the merged set
-    when its touched node set is disjoint from every earlier merged
-    plan's — disjoint node sets mean one plan's placements/stops cannot
-    change another's fit, so all of them verify correctly against ONE
-    snapshot and commit as one raft entry. Plans that conflict on a
-    node, or touch volumes (two node-disjoint plans can still race one
-    volume's write claim), fall back to the existing serial path, in
-    submission order, AFTER the merged commit — so their verification
-    sees the merged plans' effects and rejects/refreshes exactly as if
-    everything had been serial.
+    Returns (merged, serial) index lists. The merged plans are verified
+    one after another in submission order, each on the committed
+    snapshot with the results of those before it laid over
+    (OverlaySnapshot), and commit as ONE raft entry — so a plan that
+    shares a node with an earlier one is judged exactly as if everything
+    had been serial: it stands on what the earlier plan placed, stopped
+    and evicted there, and is refused where that plan was refused room
+    it counted on.
 
-    Two plans for the SAME job never merge either: the bulk commit
-    collapses each round's jobs by (namespace, id), so same-job plans at
-    different job versions would re-attach one plan's allocs to the
-    other's version. The eval broker's one-in-flight-eval-per-job lock
-    already makes this unreachable from the TPU worker, but enqueue_batch
-    is public API — enforce it here rather than rely on the convention.
+    Two kinds of plan stay out. One that touches volumes (two plans can
+    race one volume's write claim, and claims are read from committed
+    state) falls back to the serial path, in submission order, AFTER the
+    merged commit. And two plans for the SAME job never merge: the bulk
+    commit collapses an entry's jobs by (namespace, id), so same-job
+    plans at different job versions would re-attach one plan's allocs to
+    the other's version — the later one waits for the next pass. The
+    eval broker's one-in-flight-eval-per-job lock already makes this
+    unreachable from the TPU worker, but enqueue_batch is public API —
+    enforce it here rather than rely on the convention.
 
     keys — optional precomputed _plan_partition_key list parallel to
     plans."""
@@ -541,17 +627,13 @@ def partition_plan_batch(
         keys = [_plan_partition_key(p) for p in plans]
     merged: list[int] = []
     serial: list[int] = []
-    claimed: set[str] = set()
     claimed_jobs: set[tuple] = set()
-    for i, (nodes, touches_volumes, job_key) in enumerate(keys):
-        if (
-            touches_volumes
-            or (nodes & claimed)
-            or (job_key is not None and job_key in claimed_jobs)
+    for i, (_nodes, touches_volumes, job_key) in enumerate(keys):
+        if touches_volumes or (
+            job_key is not None and job_key in claimed_jobs
         ):
             serial.append(i)
             continue
-        claimed |= nodes
         if job_key is not None:
             claimed_jobs.add(job_key)
         merged.append(i)
@@ -830,25 +912,40 @@ class PlanApplier:
 
     def _commit_merged(
         self, plans: list[Plan], merged_idx: list[int], snapshot,
-        tref=None, round_no: int = 0, seen_mints: Optional[set] = None,
-    ) -> dict[int, PlanResult]:
-        """Verify the merged (node-disjoint) subset against one snapshot
-        and commit every non-no-op result as ONE raft entry backed by one
-        bulk store transaction."""
+        keys: list, tref=None, round_no: int = 0,
+        seen_mints: Optional[set] = None,
+    ) -> tuple[dict[int, PlanResult], list[int]]:
+        """Verify the merged subset in submission order, each plan on
+        the snapshot with the results of those before it laid over, and
+        commit every non-no-op result as ONE raft entry backed by one
+        bulk store transaction. Returns the results and the indices put
+        off to the next pass (OverlaySnapshot.stops_a_placement)."""
         tctx, tparent = tref if tref is not None else (None, None)
         results: dict[int, PlanResult] = {}
         verified: list[tuple[int, PlanResult]] = []
         to_commit: list[tuple[int, PlanResult]] = []
+        put_off: list[int] = []
+        overlay = OverlaySnapshot(snapshot)
+        claimed: set[str] = set()  # nodes a verified plan of this pass writes
         with paused_gc():
             with trace.span(
                 tctx, "plan.verify", parent=tparent, cpu=True,
                 round=round_no, plans=len(merged_idx),
             ):
                 for i in merged_idx:
-                    result = evaluate_plan(snapshot, plans[i])
+                    plan = plans[i]
+                    nodes = keys[i][0]
+                    if claimed and overlay.stops_a_placement(plan):
+                        put_off.append(i)
+                        continue
+                    # a plan alone on its nodes reads the snapshot itself
+                    view = snapshot if claimed.isdisjoint(nodes) else overlay
+                    result = evaluate_plan(view, plan)
+                    claimed |= nodes
                     if result.is_no_op():
                         results[i] = result
                         continue
+                    overlay.add(result, plan.job, nodes)
                     verified.append((i, result))
             # identity guard BEFORE preemption evals / normalization: a
             # trimmed row must not leave its preemption or job wiring
@@ -876,19 +973,16 @@ class PlanApplier:
             for i, r in to_commit:
                 r.alloc_index = index
                 results[i] = r
-        return results
+        return results, put_off
 
     def _commit_merged_rounds(
         self, plans: list[Plan], snapshot, tref=None
     ) -> tuple[dict[int, PlanResult], list[int]]:
-        """Round-partitioned merged commit: each round commits the
-        mutually node-disjoint prefix of the REMAINING plans as one raft
-        entry, then re-snapshots so the next round's verification sees
-        it. A node-conflicting plan thus still rides a bulk commit one
-        round later (same optimistic-concurrency outcome as the serial
-        path: it verifies against committed state that includes the
-        plans that beat it, and rejects/refreshes if it lost the race)
-        instead of paying an individual raft apply + store transaction.
+        """Merged commit in passes: a pass verifies every remaining plan
+        that may merge (partition_plan_batch) in submission order and
+        commits them as one raft entry; what it put off — a second plan
+        of one job, a stop of an alloc the pass itself places — rides
+        the next pass, on a fresh snapshot. One pass is the rule.
         Volume-touching plans never merge; their indices are returned
         for the caller's true serial path."""
         from .. import metrics
@@ -900,7 +994,7 @@ class PlanApplier:
         merged_total = 0
         rounds = 0
         # (eval_id, alloc name) minted anywhere in this batch — the
-        # duplicate-mint guard's memory across rounds
+        # duplicate-mint guard's memory across passes
         seen_mints: set = set()
         while remaining:
             rel_merged, rel_rest = partition_plan_batch(
@@ -912,15 +1006,14 @@ class PlanApplier:
             if rounds > 0:
                 snapshot = self.state.snapshot()
             round_idx = [remaining[r] for r in rel_merged]
-            results.update(
-                self._commit_merged(
-                    plans, round_idx, snapshot, tref=tref,
-                    round_no=rounds, seen_mints=seen_mints,
-                )
+            done, put_off = self._commit_merged(
+                plans, round_idx, snapshot, keys, tref=tref,
+                round_no=rounds, seen_mints=seen_mints,
             )
-            merged_total += len(round_idx)
+            results.update(done)
+            merged_total += len(done)
             rounds += 1
-            remaining = [remaining[r] for r in rel_rest]
+            remaining = sorted(put_off + [remaining[r] for r in rel_rest])
         metrics.observe("nomad.plan_apply.batch_merged", merged_total)
         metrics.observe("nomad.plan_apply.batch_rounds", rounds)
         metrics.observe("nomad.plan_apply.batch_serial", len(remaining))

@@ -230,8 +230,9 @@ class FSM:
     def _apply_plan_results_batch(
         self, index: int, results: list[PlanResult]
     ) -> None:
-        """N node-disjoint plan results committed as one log entry (the
-        batched plan applier's merged commit — one store transaction)."""
+        """N plan results, each verified on those before it, committed
+        as one log entry (the batched plan applier's merged commit — one
+        store transaction)."""
         self.state.upsert_plan_results_batch(index, results)
         evs = [e for r in results for e in r.preemption_evals]
         if evs and self.on_eval_update:

@@ -145,7 +145,8 @@ class WorkerPlanner:
 
     def submit_plan_batch(self, plans: list[Plan]) -> list[PlanResult]:
         """Submit a whole batch of same-snapshot plans as one queue item;
-        the applier merges the node-disjoint subset into a single raft
+        the applier verifies them in submission order, each on the
+        results of those before it, and commits them as a single raft
         apply (plan_apply.py). One snapshot wait covers every partial
         commit in the batch, so retry evals never race their own
         refresh index."""
@@ -796,6 +797,7 @@ class TPUBatchWorker:
         in-flight parent's used' tensor — lane solves commit ahead of
         the parent, outside the FIFO the chain verdict relies on."""
         from ..scheduler.tpu import solve_eval_batch_begin
+        from ..scheduler.tpu.solver import may_preempt
 
         wait_index = max(
             max(ev.modify_index for ev in evals),
@@ -803,12 +805,15 @@ class TPUBatchWorker:
         )
         if allow_chain and self._prev is not None:
             prev_pending, committed = self._prev[:2]
-            if (
-                not committed.is_set()
-                and prev_pending.solved_in_begin
-                and prev_pending.chain is None
+            if not committed.is_set() and (
+                (prev_pending.solved_in_begin and prev_pending.chain is None)
+                or may_preempt(
+                    self.server.state, self.config,
+                    ((ev.type, ev.priority) for ev in evals),
+                )
             ):
-                # The batch in flight was solved whole in its phase A
+                # Two batches that may not solve beside the one in
+                # flight. (1) That one was solved whole in its phase A
                 # and offers no chain: a small batch that the host stack
                 # or the microsolve took. Until it commits, its
                 # placements — and, commits being FIFO, those of the
@@ -822,11 +827,24 @@ class TPUBatchWorker:
                 # 4-7 s of a 11 s window, one run in four; PERF.md
                 # section 6, PR 32). Such a batch has nothing to wait
                 # for on the device: its commit takes milliseconds once
-                # the FIFO reaches it. Wait for it, before the snapshot
-                # is taken. A batch with a kernel or a pool RPC in
-                # flight (dense, preempt, remote: `solved_in_begin`
-                # false) is never waited for: the overlap with it is
-                # the pipeline's point.
+                # the FIFO reaches it. (2) THIS batch may preempt: an
+                # eval of a type the operator lets preempt,
+                # PRIORITY_DELTA over some alloc's priority
+                # (solver.may_preempt, the test the solver picks its
+                # kernel by). A chained used' carries the parent's
+                # placements and what its victims freed, but this
+                # solve's tiers are read from a store that still holds
+                # those victims: it would count them free again, and
+                # choose its exact victims and its exact room from a
+                # snapshot without the parent's plan — the applier trims
+                # it and the cascade is (1)'s (PERF.md section 6,
+                # PR 35). Either way: wait for that commit, before the
+                # snapshot is taken; the batch then chains on nothing.
+                # Every other batch beside a kernel or a pool RPC in
+                # flight (compact, preempt, remote) chains on the
+                # parent's used' — a preempt parent offers its own — and
+                # is never made to wait: the overlap is the pipeline's
+                # point.
                 metrics.incr("nomad.worker.chain.waited")
                 with trace.span(trace.current(), "chain.wait"):
                     while not committed.wait(0.05):
@@ -1042,6 +1060,10 @@ class TPUBatchWorker:
         metrics.observe(
             "nomad.tpu.commit_seconds", (trace.now_ns() - t0) / 1e9
         )
+        if not all_full and lane != "interactive":
+            # the applier cut a plan of a batch-lane batch: the chained
+            # follower is nacked (above) and the evals are retried
+            metrics.observe("nomad.worker.batch.trimmed", 1)
         if lane == "interactive":
             if not all_full:
                 # the applier cut the lane's plan against a commit that
@@ -1084,16 +1106,25 @@ class TPUBatchWorker:
         blocked_basis: Optional[int] = None,
     ) -> bool:
         # One merged submission for the whole batch (the applier commits
-        # the node-disjoint subset as a single raft apply + bulk store
-        # transaction, serial-fallback for conflicting plans). Returns
+        # it as a single raft apply + bulk store transaction, each plan
+        # verified on the results of those before it). Returns
         # whether EVERY plan committed in full — a trimmed plan means the
         # chained used' tensor carries placements that never landed.
         # blocked_basis — for a CHAINED solve, the parent's snapshot
         # index: blocked evals must not mark capacity events between the
         # chain basis and this snapshot as already seen.
-        submit = [
-            (ev, plans[ev.id]) for ev in evals if not plans[ev.id].is_no_op()
-        ]
+        # In the solver's own order, highest priority first (a stable
+        # sort, as BatchSolver's): the applier verifies a batch's plans
+        # in submission order, and a plan may stand on room an EARLIER
+        # group's whole victim left over — a follow-up eval's sand
+        # beside the production boulder that evicted more than it
+        # needed. The eviction is in the preemptor's plan alone, so the
+        # plan that draws on it has to be judged after it.
+        submit = sorted(
+            ((ev, plans[ev.id]) for ev in evals
+             if not plans[ev.id].is_no_op()),
+            key=lambda ep: -ep[0].priority,
+        )
         results: dict[str, PlanResult] = {}
         if submit:
             got = self.planner.submit_plan_batch([p for _, p in submit])

@@ -2241,8 +2241,9 @@ class StateStore(_ReadMixin):
         """Apply N verified plan results as ONE store transaction.
 
         The batched plan applier commits a whole TPU batch's worth of
-        same-snapshot, node-disjoint plans in a single raft entry; here
-        they land under one lock acquisition with one bulk alloc upsert
+        same-snapshot plans, verified one on the other's result, in a
+        single raft entry; here they land in that order under one lock
+        acquisition with one bulk alloc upsert
         (one COW table fork, one summaries/status pass, one publish)
         instead of N serial upsert_plan_results calls. Semantics per
         result are identical to the single-plan form — the differential
@@ -2251,8 +2252,7 @@ class StateStore(_ReadMixin):
         with self._lock, paused_gc():
             allocs_to_upsert: list[Allocation] = []
             batches: list[PlacementBatch] = []
-            stopped: list[Allocation] = []
-            preempted: list[Allocation] = []
+            freed: list[Allocation] = []  # stopped and preempted
             deployment_events: list = []
             default_jobs: dict[tuple[str, str], Job] = {}
             preemption_evals: list[Evaluation] = []
@@ -2260,10 +2260,14 @@ class StateStore(_ReadMixin):
                 for allocs in result.node_allocation.values():
                     allocs_to_upsert.extend(allocs)
                 batches.extend(result.alloc_batches)
+                # a result's stops, then its preemptions, result after
+                # result: where two results of one entry free the same
+                # alloc, the later one's word stands, as it would had
+                # they been applied one after another
                 for allocs in result.node_update.values():
-                    stopped.extend(allocs)
+                    freed.extend(allocs)
                 for allocs in result.node_preemptions.values():
-                    preempted.extend(allocs)
+                    freed.extend(allocs)
                 if result.job is not None:
                     default_jobs[
                         (result.job.namespace, result.job.id)
@@ -2288,7 +2292,7 @@ class StateStore(_ReadMixin):
             # Stops and preemptions merge desired-status changes onto the
             # existing alloc rather than replacing client state.
             committed: list[Allocation] = []
-            for alloc in stopped + preempted:
+            for alloc in freed:
                 existing = t.get(alloc.id)
                 merged = alloc.copy()
                 if existing is not None:
@@ -2388,9 +2392,7 @@ class StateStore(_ReadMixin):
             if any_deployment or canary_by_deploy:
                 tables.append(TABLE_DEPLOYMENTS)
             self._stamp(index, *tables)
-            jobs_touched = {
-                (a.namespace, a.job_id) for a in stopped + preempted
-            }
+            jobs_touched = {(a.namespace, a.job_id) for a in freed}
             self._reconcile_summaries_txn(index, jobs_touched)
             for ns, job_id in jobs_touched:
                 self._update_job_status_txn(index, ns, job_id)

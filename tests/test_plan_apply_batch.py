@@ -325,9 +325,10 @@ def test_merged_round_never_trims_existing_alloc_updates():
 
 
 def test_forced_node_conflict_partitions_and_matches_serial():
-    """Two plans fighting over one node: the partition must route the
-    second to the serial path, and the final state (including the
-    loser's rejection) must match all-serial application."""
+    """Two plans fighting over one node: both ride the merged pass (the
+    second is verified on the first's result, PR 35), and the final
+    state (including the loser's rejection) must match all-serial
+    application."""
     h, jobs = build_state(n_nodes=2, n_jobs=2, count=1)
     nodes = h.state.nodes()
     target = nodes[0]
@@ -336,7 +337,7 @@ def test_forced_node_conflict_partitions_and_matches_serial():
     plan_b = _manual_plan(jobs[1], [(target, 3000, 512)])
 
     merged, serial = partition_plan_batch([plan_a, plan_b])
-    assert merged == [0] and serial == [1]
+    assert merged == [0, 1] and serial == []
 
     serial_state = clone_store(h.state)
     applier_s, _ = make_applier(serial_state)
@@ -418,6 +419,228 @@ def test_merged_batch_with_stops_and_disjoint_updates():
     applier_b.apply_batch(copy_plans([stop_plan, place_plan]))
 
     assert state_fingerprint(serial_state) == state_fingerprint(batch_state)
+
+
+# ---------------------------------------------------------------------------
+# Plans that share a node ride ONE pass, in submission order (PR 35): a
+# plan is judged on what the plans before it placed, stopped and evicted,
+# exactly as if everything had been serial. An eviction stands in its
+# preemptor's plan alone; a plan that stands on the room another plan's
+# victim left over is refused wherever that plan was.
+# ---------------------------------------------------------------------------
+
+
+def _standing(h, job, node, cpu, index):
+    a = mock.alloc(job_=job, node_=node)
+    a.name = f"{job.id}.web[{index}]"
+    a.resources.tasks["web"].cpu = cpu
+    a.resources.tasks["web"].memory_mb = 64
+    a.resources.tasks["web"].networks = []
+    a.client_status = "running"
+    h.state.upsert_allocs(h.next_index(), [a])
+    return h.state.alloc_by_id(a.id)
+
+
+def _eviction_cell():
+    """One node of 4,000 MHz: a victim of 3,000, a bystander of 600, 400
+    free. Plan A evicts the victim for a placement of 1,000 and leaves
+    2,000 over; plan B places 2,000 there, which only that leftover
+    holds. A second, empty node for whatever needs one."""
+    h, jobs = build_state(n_nodes=2, n_jobs=4, count=1)
+    n0, n1 = h.state.nodes()
+    victim = _standing(h, jobs[2], n0, 3000, 0)
+    _standing(h, jobs[3], n0, 600, 0)
+    plan_a = _manual_plan(jobs[0], [(n0, 1000, 64)])
+    by = plan_a.node_allocation[n0.id][0].id
+    plan_a.append_preempted_alloc(victim, by)
+    plan_b = _manual_plan(jobs[1], [(n0, 2000, 64)])
+    return h, jobs, (n0, n1), victim, plan_a, plan_b
+
+
+def _same_store(a, b) -> bool:
+    """state_fingerprint, but for the evals, which are counted: a
+    preempted job's follow-up eval is minted with a fresh id each time."""
+    fa, fb = state_fingerprint(a), state_fingerprint(b)
+    return fa[:4] == fb[:4] and sorted(fa[4].values()) == sorted(
+        fb[4].values())
+
+
+def _rounds_of(fn):
+    from nomad_tpu import metrics
+    from nomad_tpu.metrics import Registry
+
+    reg = Registry()
+    old = metrics._install_registry(reg)
+    try:
+        out = fn()
+        return out, reg.histogram_raw("nomad.plan_apply.batch_rounds")
+    finally:
+        metrics._install_registry(old)
+
+
+def test_a_plan_stands_on_an_earlier_plans_eviction_in_one_pass():
+    h, jobs, (n0, _), victim, plan_a, plan_b = _eviction_cell()
+    serial_state = clone_store(h.state)
+    applier_s, _ = make_applier(serial_state)
+    for p in copy_plans([plan_a, plan_b]):
+        applier_s.apply_one(p)
+
+    batch_state = clone_store(h.state)
+    applier_b, _ = make_applier(batch_state)
+    (ra, rb), rounds = _rounds_of(
+        lambda: applier_b.apply_batch(copy_plans([plan_a, plan_b])))
+    assert ra.full_commit(plan_a)[0] and rb.full_commit(plan_b)[0]
+    assert ra.alloc_index == rb.alloc_index  # one raft entry
+    assert rounds["count"] == 1 and rounds["max"] == 1
+    assert batch_state.alloc_by_id(victim.id).desired_status == "evict"
+    assert batch_state.node_usage(n0.id)[0] == 600 + 1000 + 2000
+    assert _same_store(serial_state, batch_state)
+
+
+def test_the_plan_that_draws_is_refused_where_it_comes_first():
+    h, jobs, (n0, _), victim, plan_a, plan_b = _eviction_cell()
+    applier, _ = make_applier(h.state)
+    rb, ra = applier.apply_batch([plan_b, plan_a])
+    assert not rb.full_commit(plan_b)[0] and rb.refresh_index > 0
+    assert ra.full_commit(plan_a)[0]
+    assert h.state.node_usage(n0.id)[0] == 600 + 1000
+
+
+def test_the_plan_that_draws_is_refused_where_the_preemptors_plan_was():
+    """The preemptor's plan is refused (all-at-once, and its other node
+    has no room): its eviction does not happen, and the plan behind it,
+    which lists no victim of its own, finds no room and is refused on
+    the node too. Nothing is evicted for a preemptor that never lived."""
+    h, jobs, (n0, n1), victim, plan_a, plan_b = _eviction_cell()
+    _standing(h, jobs[3], n1, 3900, 1)
+    extra = _manual_plan(jobs[0], [(n1, 1000, 64)])
+    for allocs in extra.node_allocation.values():
+        for a in allocs:
+            a.eval_id = plan_a.eval_id
+            a.name = f"{jobs[0].id}.web[1]"
+            plan_a.append_fresh_alloc(a, jobs[0])
+    plan_a.all_at_once = True
+    serial_state = clone_store(h.state)
+    applier_s, _ = make_applier(serial_state)
+    for p in copy_plans([plan_a, plan_b]):
+        applier_s.apply_one(p)
+
+    batch_state = clone_store(h.state)
+    applier_b, _ = make_applier(batch_state)
+    ra, rb = applier_b.apply_batch(copy_plans([plan_a, plan_b]))
+    assert ra.is_no_op() and ra.refresh_index > 0
+    assert not rb.full_commit(plan_b)[0] and rb.refresh_index > 0
+    assert batch_state.alloc_by_id(victim.id).desired_status == "run"
+    assert batch_state.node_usage(n0.id)[0] == 3000 + 600
+    assert _same_store(serial_state, batch_state)
+
+
+def test_a_refused_preemptor_does_not_stop_a_plan_that_fits_by_itself():
+    h, jobs, (n0, _), victim, _, _ = _eviction_cell()
+    plan_a = _manual_plan(jobs[0], [(n0, 3900, 64)])  # 600 + 3900 > 4,000
+    plan_a.append_preempted_alloc(
+        victim, plan_a.node_allocation[n0.id][0].id)
+    plan_b = _manual_plan(jobs[1], [(n0, 300, 64)])   # the free 400 holds it
+    applier, _ = make_applier(h.state)
+    ra, rb = applier.apply_batch([plan_a, plan_b])
+    assert not ra.full_commit(plan_a)[0] and not ra.node_preemptions
+    assert rb.full_commit(plan_b)[0]
+    assert h.state.alloc_by_id(victim.id).desired_status == "run"
+    assert h.state.node_usage(n0.id)[0] == 3000 + 600 + 300
+
+
+def test_an_alloc_two_plans_stop_frees_its_room_once():
+    """The victim is evicted by plan A and stopped by its own job's plan
+    S; plan B behind them may count its 3,000 MHz once: 2,000 + 1,500
+    over the 400 free and the 2,000 left over does not fit."""
+    h, jobs, (n0, _), victim, plan_a, _ = _eviction_cell()
+    plan_s = Plan(eval_id="stop-ev", priority=50, job=jobs[2])
+    plan_s.append_stopped_alloc(victim, "job stopped", "")
+    plan_b = _manual_plan(jobs[1], [(n0, 2000, 64)])
+    plan_c = _manual_plan(jobs[3], [(n0, 1500, 64)])
+    serial_state = clone_store(h.state)
+    applier_s, _ = make_applier(serial_state)
+    for p in copy_plans([plan_a, plan_s, plan_b, plan_c]):
+        applier_s.apply_one(p)
+    batch_state = clone_store(h.state)
+    applier_b, _ = make_applier(batch_state)
+    ra, rs, rb, rc = applier_b.apply_batch(
+        copy_plans([plan_a, plan_s, plan_b, plan_c]))
+    assert ra.full_commit(plan_a)[0] and rb.full_commit(plan_b)[0]
+    assert not rc.full_commit(plan_c)[0] and rc.refresh_index > 0
+    assert batch_state.node_usage(n0.id)[0] == 600 + 1000 + 2000
+    assert _same_store(serial_state, batch_state)
+
+
+def test_a_stop_of_what_the_pass_places_waits_for_the_next_pass():
+    """The store applies an entry's stops before its placements: a plan
+    that evicts an alloc an earlier plan of the batch places must land in
+    a later entry, or the victim would outlive its eviction."""
+    h, jobs = build_state(n_nodes=1, n_jobs=2, count=1)
+    (n0,) = h.state.nodes()
+    plan_a = _manual_plan(jobs[0], [(n0, 3000, 64)])
+    fresh = plan_a.node_allocation[n0.id][0]
+    plan_b = _manual_plan(jobs[1], [(n0, 3500, 64)])
+    plan_b.append_preempted_alloc(fresh, plan_b.node_allocation[n0.id][0].id)
+    serial_state = clone_store(h.state)
+    applier_s, _ = make_applier(serial_state)
+    for p in copy_plans([plan_a, plan_b]):
+        applier_s.apply_one(p)
+    batch_state = clone_store(h.state)
+    applier_b, _ = make_applier(batch_state)
+    (ra, rb), rounds = _rounds_of(
+        lambda: applier_b.apply_batch(copy_plans([plan_a, plan_b])))
+    assert ra.full_commit(plan_a)[0] and rb.full_commit(plan_b)[0]
+    assert rounds["max"] == 2 and rb.alloc_index > ra.alloc_index
+    assert batch_state.alloc_by_id(fresh.id).desired_status == "evict"
+    assert batch_state.node_usage(n0.id)[0] == 3500
+    assert _same_store(serial_state, batch_state)
+
+
+@pytest.mark.parametrize("seed", [1, 2, 3, 5, 8, 3_000_000_019])
+def test_plans_that_fight_over_nodes_commit_as_a_sequence_does(seed):
+    """Seeded plans over three crowded nodes — placements of every
+    size, stops, evictions of standing allocs, some for room that only
+    an earlier plan's stop makes — through the merged pass and one after
+    another: the same verdict for every plan, the same store."""
+    import random
+
+    rng = random.Random(seed)
+    h, jobs = build_state(n_nodes=3, n_jobs=12, count=1)
+    nodes = h.state.nodes()
+    standing = [
+        _standing(h, jobs[9 + i % 3], nodes[i % 3], rng.choice(
+            [500, 800, 1200]), i)
+        for i in range(9)
+    ]
+    plans = []
+    for j in range(9):
+        spec = [(rng.choice(nodes), rng.choice([300, 900, 1500, 2600]), 64)
+                for _ in range(rng.randint(1, 3))]
+        plan = _manual_plan(jobs[j], spec)
+        for k, allocs in enumerate(plan.node_allocation.values()):
+            for i, a in enumerate(allocs):
+                a.name = f"{jobs[j].id}.web[{k}.{i}]"
+        for v in rng.sample(standing, rng.randint(0, 2)):
+            here = plan.node_allocation.get(v.node_id)
+            if here and rng.random() < 0.7:
+                plan.append_preempted_alloc(v, here[0].id)
+            else:
+                plan.append_stopped_alloc(v, "seeded stop", "")
+        plans.append(plan)
+    serial_state = clone_store(h.state)
+    applier_s, _ = make_applier(serial_state)
+    serial = [applier_s.apply_one(p) for p in copy_plans(plans)]
+    batch_state = clone_store(h.state)
+    applier_b, _ = make_applier(batch_state)
+    batch = applier_b.apply_batch(copy_plans(plans))
+    for p, rs, rb in zip(plans, serial, batch):
+        assert rs.full_commit(p)[1:] == rb.full_commit(p)[1:]
+        assert sorted(rs.node_preemptions) == sorted(rb.node_preemptions)
+    assert any(not r.full_commit(p)[0] for p, r in zip(plans, batch))
+    assert _same_store(serial_state, batch_state)
+    for n in nodes:
+        assert batch_state.node_usage(n.id)[0] <= 4000
 
 
 # ---------------------------------------------------------------------------

@@ -11,9 +11,15 @@ the same nodes, was trimmed, and every batch chained behind it was
 nacked for the broker's 5 s.
 
 Only such a batch is waited for: one with a kernel or a pool RPC still in
-flight (the dense and preempt kernels, a `RemotePendingBatch`) offers no
-chain either, but its commit is far off and the overlap with it is the
+flight (the dense kernel, a `RemotePendingBatch`) offers no chain
+either, but its commit is far off and the overlap with it is the
 pipeline's point (docs/pipeline.md, docs/solver-pool.md).
+
+And a solve that MAY PREEMPT waits for whatever is in flight (PR 35), at
+the same place, before its snapshot: it reads its tiers, its exact room
+and its victims from the store, which holds the parent's plan only once
+it is committed. The preempt solve itself offers its used' tensor, so a
+batch behind it that cannot preempt chains on it and does not wait.
 """
 
 import threading
@@ -36,8 +42,13 @@ class _Snapshot:
 
 
 class _State:
+    tiers = [50]  # the priorities with a live alloc: one band
+
     def __init__(self, order):
         self.order = order
+
+    def alloc_priority_tiers(self):
+        return self.tiers
 
     def snapshot_min_index(self, index, timeout_s=None):
         self.order.append("snapshot")
@@ -123,6 +134,28 @@ def test_a_solve_behind_a_batch_that_offers_its_tensor_does_not_wait(worker):
     t.join()
     assert given == [chain]
     assert waited() == 0
+
+
+@pytest.mark.parametrize("type_, priority, tiers, waits", [
+    ("service", 50, [10, 30, 50], True),   # 40 over the lowest band
+    ("service", 50, [45, 50], False),      # nothing 10 under it
+    ("batch", 50, [10, 30, 50], False),    # batch jobs may not preempt
+    ("batch", 10, [10, 30, 50], False),    # an evicted job's follow-up
+])
+def test_a_solve_that_may_preempt_waits_for_whatever_is_in_flight(
+        worker, type_, priority, tiers, waits):
+    w, order, given = worker
+    w.server.state.tiers = tiers
+    chain = (("n1",), object())
+    t = in_flight(w, order, chain=chain)
+    ev = mock.evaluation()
+    ev.type, ev.priority = type_, priority
+    w._solve_batch([ev, mock.evaluation(type="batch")])
+    # it waits before its snapshot is taken, and chains on nothing
+    assert order == (["committed", "snapshot"] if waits else ["snapshot"])
+    t.join()
+    assert given == [None if waits else chain]
+    assert waited() == (1 if waits else 0)
 
 
 def test_the_interactive_lane_never_waits(worker):
