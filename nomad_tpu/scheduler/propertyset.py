@@ -53,9 +53,13 @@ class PropertySet:
         return resolve_target(node, self.target_attribute)
 
     def _compute_existing(self) -> dict[str, int]:
+        """The job's live allocs per value, read through the job's index
+        as the reference does (propertyset.go populateExisting:
+        AllocsByJob) — a walk of every alloc in the store cost a spread
+        eval 10 ms at 26,000 allocs (PERF.md section 6, PR 36)."""
         counts: dict[str, int] = {}
         node_cache: dict[str, Optional[Node]] = {}
-        for alloc in self.ctx.state.allocs():
+        for alloc in self.ctx.state.allocs_by_job(self.namespace, self.job.id):
             if alloc.terminal_status() or not self._relevant(alloc):
                 continue
             node = node_cache.get(alloc.node_id, ...)

@@ -97,6 +97,10 @@ class SolveOutcome:
     # Per-ALLOC because one eval can mix host-path asks (sticky groups)
     # with dense-kernel asks in the same batch.
     pre_appended: set = field(default_factory=set)
+    # the host stack's walk: nodes its stacks were given, and how many
+    # positions of their permutations were ever drawn (stack.ShuffledNodes)
+    stack_nodes: int = 0
+    stack_nodes_drawn: int = 0
 
 
 def may_preempt(state, config: SchedulerConfig, jobs, extra_tiers=()) -> bool:
@@ -1475,8 +1479,10 @@ class BatchSolver:
         from ... import metrics
 
         t0 = now_ns()
-        with trace.span(trace.current(), "host_solve", cpu=True):
+        with trace.span(trace.current(), "host_solve", cpu=True) as span:
             out = self._solve_host(asks)
+            span.set_attr("nodes", out.stack_nodes)
+            span.set_attr("nodes_drawn", out.stack_nodes_drawn)
         out.solve_ns = now_ns() - t0
         metrics.time_ns("nomad.tpu.solve_seconds", out.solve_ns)
         metrics.observe("nomad.tpu.small_batch_requests", total_requests)
@@ -1579,6 +1585,7 @@ class BatchSolver:
         each ask's plan as they land, so distinct/property/capacity
         checks see earlier placements exactly as generic.py's loop does
         (computePlacements, generic_sched.go:472)."""
+        from ... import metrics
         from ..stack import GenericStack
         from ..util import annotate_previous_alloc
 
@@ -1708,6 +1715,13 @@ class BatchSolver:
                 ask.plan.append_fresh_alloc(alloc, ask.job)
                 out.pre_appended.add(alloc.id)
                 placements.append(alloc)
+        for stack in stacks.values():
+            drawn = stack.nodes.drawn
+            metrics.observe("nomad.sched.stack.nodes_drawn", drawn)
+            if stack.nodes.eager:
+                metrics.incr("nomad.sched.stack.eager_finishes")
+            out.stack_nodes += len(stack.nodes)
+            out.stack_nodes_drawn += drawn
         return out
 
     def _tier_limit(self, table, grp: LoweredGroup) -> int:
