@@ -26,7 +26,7 @@ from http.server import BaseHTTPRequestHandler, ThreadingHTTPServer
 from typing import Callable, Optional
 from urllib.parse import parse_qs, urlparse
 
-from .. import codec, metrics
+from .. import codec, hostobs, metrics
 from .. import trace as _trace
 from ..server.server import ConflictError
 from ..state.store import (
@@ -44,6 +44,17 @@ logger = logging.getLogger("nomad_tpu.http")
 # and every RPC carries it for cross-region forwarding)
 _REQ_REGION = contextvars.ContextVar("nomad_http_region", default="")
 _REQ_TOKEN = contextvars.ContextVar("nomad_http_token", default="")
+
+
+class _Server(ThreadingHTTPServer):
+    """A thread a connection: most live under one pass of the host
+    profiler, so each hands its CPU clock in as its last act."""
+
+    def process_request_thread(self, request, client_address):
+        try:
+            super().process_request_thread(request, client_address)
+        finally:
+            hostobs.note_thread_exit()
 
 
 class RawResponse:
@@ -131,7 +142,7 @@ class HTTPAgentServer:
         self._routes: list[tuple[str, re.Pattern, Callable]] = []
         self._register_routes()
         handler = self._make_handler()
-        self._httpd = ThreadingHTTPServer((host, port), handler)
+        self._httpd = _Server((host, port), handler)
         self._httpd.daemon_threads = True
         self.tls = bool(tls_cert and tls_key)
         self._tls_ctx = None
@@ -1386,8 +1397,6 @@ class HTTPAgentServer:
             # gauges. Same agent:read gate as /v1/metrics; available
             # even when enable_debug 404s the raw pprof capture
             # (observability is not a debug mode).
-            from .. import hostobs
-
             try:
                 top = int(q.get("top", ["50"])[0])
             except ValueError:
@@ -1398,8 +1407,6 @@ class HTTPAgentServer:
             # /v1/profile/collapsed: collapsed-stack flamegraph text
             # ("role;span;frame;...;leaf count" per line) — pipe into
             # flamegraph.pl / speedscope verbatim (docs/profiling.md).
-            from .. import hostobs
-
             try:
                 limit = int(q.get("limit", ["0"])[0])
             except ValueError:

@@ -2307,12 +2307,22 @@ def _render_top(
             top_span = next(
                 (s for s in spans if s != "-"), None
             ) or (next(iter(spans), None))
+            waits = {
+                k: v["wait_seconds"] for k, v in spans.items() if k != "-"
+            }
+            top_wait = max(waits, key=waits.get, default=None)
             gc_tot = (profile.get("gc") or {}).get(
                 "pause_seconds_total", 0.0
             )
             lines.append(
-                f"Host        busy {p_busy / window * 100:.1f}%"
+                f"Host        busy (cpu) {p_busy / window * 100:.1f}%"
                 + (f"   top span {top_span}" if top_span else "")
+                + (
+                    f"   most wait {top_wait}"
+                    f" ({_fmt_dur(waits[top_wait])})"
+                    if top_wait and waits[top_wait]
+                    else ""
+                )
                 + f"   gc {gc_n} pauses"
                 + (f" ({_fmt_dur(gc_tot)})" if gc_tot else "")
                 + (
@@ -3039,7 +3049,7 @@ def _render_profile_status(snap: dict) -> str:
         f"Sampler     {samples} samples over {window:.0f}s"
         f"  ({snap.get('interval_ms', 0):.0f}ms interval"
         f"{'' if snap.get('running') else ', STOPPED'})"
-        f"   busy {busy:.1f}s ({busy / window * 100:.1f}% of window)"
+        f"   cpu {busy:.1f}s ({busy / window * 100:.1f}% of window)"
         f"   overhead {overhead.get('duty_cycle', 0) * 100:.2f}%"
     )
     gc_s = snap.get("gc") or {}
@@ -3081,15 +3091,33 @@ def _render_profile_status(snap: dict) -> str:
         }
         if busy_roles:
             lines.append(
-                "Busy by role: "
+                "CPU by role (cpu_seconds, +wait_seconds inside spans): "
                 + "  ".join(
                     f"{r} {s['busy_seconds']:.2f}s"
+                    + (
+                        f" +{s['wait_seconds']:.2f}s"
+                        if s.get("wait_seconds")
+                        else ""
+                    )
                     for r, s in sorted(
                         busy_roles.items(),
                         key=lambda kv: -kv[1]["busy_seconds"],
                     )
                 )
             )
+    waits = sorted(
+        (
+            (v["wait_seconds"], v["cpu_seconds"], k)
+            for k, v in (snap.get("spans") or {}).items()
+            if k != "-" and v["wait_seconds"]
+        ),
+        reverse=True,
+    )[:10]
+    if waits:
+        lines.append(
+            "Wait by span (wait_seconds / cpu_seconds): "
+            + "  ".join(f"{k} {w:.2f}s/{c:.2f}s" for w, c, k in waits)
+        )
     sites = snap.get("top_sites") or []
     rows = [
         [
@@ -3104,15 +3132,15 @@ def _render_profile_status(snap: dict) -> str:
     ]
     if rows:
         lines.append("")
-        lines.append("Top self-time sites (role x span x function):")
+        lines.append("Top CPU sites (role x span x function):")
         lines.append(_fmt_table(
             rows,
-            ["ROLE", "SPAN", "SITE", "SELF", "OF-BUSY", "SAMPLES"],
+            ["ROLE", "SPAN", "SITE", "CPU", "OF-CPU", "SAMPLES"],
         ))
     else:
         lines.append("")
         lines.append(
-            "No busy samples yet (an idle agent profiles as idle; "
+            "No CPU charged yet (an idle agent profiles as idle; "
             "span names appear once tracing is enabled)."
         )
     dropped = snap.get("sites_evicted", 0) + snap.get("stacks_dropped", 0)
@@ -3164,7 +3192,7 @@ def cmd_operator_profile_top(args) -> int:
                     "pause_seconds_total", 0.0
                 )
                 lines.append(
-                    f"\nRates       busy {busy_rate * 100:.1f}% of wall"
+                    f"\nRates       cpu {busy_rate * 100:.1f}% of wall"
                     f"   gc {_fmt_dur(max(0.0, gc_now - prev_gc))} paused"
                     f" in {dt:.1f}s"
                 )

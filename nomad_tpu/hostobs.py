@@ -8,17 +8,21 @@ and the only CPU profiler was the on-demand, enable_debug-gated capture
 in agent/debug.py. This module is the always-on layer, in the spirit of
 fleet continuous profilers (Google-Wide Profiling; Pyroscope/Parca):
 
-  * sampling profiler — a background thread samples
-    ``sys._current_frames()`` on an interval adaptive to load and
-    attributes each busy sample to **(thread role x active trace span x
-    leaf function)**, using the per-thread active-span registry
-    maintained by nomad_tpu/trace.py (``trace.thread_spans()``). The
-    pipelined worker's solve and commit threads profile as distinct
-    roles. Ledgers are bounded (site/stack overflow aggregates into an
-    explicit ``(other)`` bucket — coverage loss is COUNTED, never
-    silent), and the idle fast path allocates nothing: a thread whose
-    leaf frame is a known blocking wait is skipped before any tuple or
-    string is built.
+  * thread-CPU ledger + sampler — the kernel keeps a cumulative CPU
+    clock for every thread; a background thread reads every live Python
+    thread's on an interval adaptive to load and charges what each USED
+    since the previous pass (CPU, never wall: under one interpreter
+    lock a span's wall is mostly the wait for the other threads) to its
+    role — exact however rarely the sampler ran — and, walking only
+    the threads that ran, to **(thread role x active trace span x leaf
+    function)** (``trace.thread_spans()``). A thread inside a span is
+    also charged the pass's wall less its CPU as **wait**. Threads
+    shorter than a pass hand their clock in as they end
+    (``note_thread_exit``); the account closes against the process's
+    CPU clock in the role ``(unaccounted)``. Ledgers are bounded
+    (site/stack overflow aggregates into an explicit ``(other)`` bucket
+    — coverage loss is COUNTED, never silent), and a thread whose clock
+    stood still costs one clock read: no frame, tuple or string.
   * runtime telemetry — GC pause/collection accounting via
     ``gc.callbacks`` (pauses are buffered in the callback and flushed to
     the metrics registry by the sampler thread: the callback itself can
@@ -67,32 +71,8 @@ MAX_SITES = 2048
 MAX_STACKS = 8192
 MAX_DEPTH = 48
 OTHER_SITE = "(other)"
-
-# Leaf frames that mean "parked, not working": skipped before any
-# allocation (the zero-allocation idle fast path). The basename match
-# is anchored to the STDLIB directory (threading.__file__'s home) —
-# a bare suffix match would classify this repo's own
-# server/plan_queue.py as "queue.py" and silently drop one of the very
-# hot paths this layer exists to attribute. The name set covers this
-# repo's known blocking read loops, whose leaf is repo code parked in
-# a C recv/accept.
-_STDLIB_DIR = os.path.dirname(threading.__file__) + os.sep
-_IDLE_STDLIB_BASENAMES = frozenset({
-    "threading.py",
-    "selectors.py",
-    "queue.py",
-    "socketserver.py",
-    "socket.py",
-    "ssl.py",
-    "subprocess.py",
-    "_base.py",  # concurrent/futures/_base.py (Future.result waits)
-})
-_IDLE_NAMES = frozenset({
-    "recv_exact",
-    "recv_frame",
-    "_read_loop",
-    "_accept_loop",
-})
+UNACCOUNTED = "(unaccounted)"
+WALK_NS = 1_000_000  # CPU a thread adds up between two walks of its stack
 
 _enabled = True
 
@@ -103,7 +83,7 @@ def enabled() -> bool:
 
 def set_enabled(on: bool) -> None:
     """Recording gate (GIL-atomic flag): the sampler thread keeps
-    running but skips the frame walk entirely when off. The bench uses
+    running but reads no clock and walks no frame when off. The bench uses
     this to exclude cluster-build time from attribution windows and as
     the unprofiled side of the overhead gate; production leaves it on."""
     global _enabled
@@ -270,6 +250,18 @@ def _role_of(name: str) -> str:
     return name.rstrip("0123456789-") or "other"
 
 
+def _cpu_clock_of(native_id: int) -> int:
+    """The clock id of one thread's cumulative CPU clock, for
+    ``time.clock_gettime_ns`` from any thread. Built from the kernel's
+    tid (CPUCLOCK_SCHED | per-thread: the very number
+    ``time.pthread_getcpuclockid(ident)`` returns) and NOT through that
+    call: it dereferences the pthread_t, a stale pointer once the thread
+    is gone, where a dead tid can only make the read raise OSError (or,
+    reused, read another thread of THIS process: the kernel admits no
+    other — and a pass reads only threads it has just found alive)."""
+    return ((~native_id) << 3) | 6
+
+
 # -- the profiler --------------------------------------------------------
 
 
@@ -277,11 +269,12 @@ class HostProfiler:
     """One process-wide instance (module functions delegate); tests and
     the bench may install a fresh one via :func:`_install`.
 
-    Writer discipline: the sampler thread is the only ledger writer (GC
-    callbacks buffer into a bounded pending list the sampler flushes);
-    readers (snapshot/collapsed, any thread) copy under ``_lock``. The
-    lock is therefore uncontended at steady state — held by the sampler
-    for the microseconds of one sample pass."""
+    Writer discipline: ledgers are written by a pass alone, under
+    ``_lock`` (the sampler's, and the one snapshot() takes first; GC
+    callbacks and exiting threads buffer into bounded lists a pass
+    drains); readers (snapshot/collapsed, any thread) copy under
+    ``_lock``, which is uncontended at steady state — held for the
+    microseconds of one pass."""
 
     def __init__(
         self,
@@ -311,13 +304,31 @@ class HostProfiler:
         self._sites: dict[tuple, list] = {}
         # collapsed "role;span;f0;f1;...;leaf" -> samples
         self._stacks: dict[str, int] = {}
-        self._span_ns: dict[str, int] = {}
         # source -> busy ns: the clusterobs thread->source registry's
         # dimension ("handler CPU x source node") — bounded, overflow
         # folds into "(other)" like the site ledger
         self._source_ns: dict[str, int] = {}
         self.max_sources = 512
-        self._role_stats: dict[str, list] = {}  # role -> [samples, ns]
+        # role, span -> [samples, cpu ns, wait ns]. Wait is wall less
+        # CPU inside a span, summed SIGNED and shown never below 0: a
+        # clock that steps (10 ms on the chip's host) shows no step in
+        # one pass and two in the next
+        self._role_stats: dict[str, list] = {}
+        self._span_stats: dict[str, list] = {}
+        # the thread-CPU ledger, thread -> [role, clock id, last ns,
+        # CPU not yet walked], keyed by the Thread OBJECT (held, so
+        # neither a reused ident nor a reused tid can inherit a role);
+        # what note_thread_exit handed in, and who did (never read
+        # again while they linger)
+        self._threads: dict = {}
+        self._exits: list = []  # (thread, thread_time_ns)
+        self._gone: set = set()
+        self._proc_ns = 0  # the process's CPU over the same passes
+        self._proc_last = 0
+        self._last_ns = now_ns()
+        # False: the next pass only takes readings (start, or recording
+        # was off): CPU used before it belongs to no window
+        self._primed = False
         self.samples = 0
         self.idle_samples = 0
         self.busy_ns = 0
@@ -325,9 +336,8 @@ class HostProfiler:
         self.stacks_dropped = 0
         self._sampler_ns = 0  # time spent inside sample passes
         self._started_ns = 0
-        # code object -> (qualified frame label, leaf-site label, idle?)
+        # code object -> (qualified frame label, leaf-site label)
         self._code_cache: dict = {}
-        self._roles: dict[int, str] = {}
         # GC accounting (callback-side buffers; sampler flushes)
         self._gc_t0 = 0
         self._gc_pending: list[tuple[int, int]] = []  # (gen, pause_ns)
@@ -360,6 +370,7 @@ class HostProfiler:
                 return
             self._stop = threading.Event()
             self._started_ns = now_ns()
+            self._primed = False
             self._thread = threading.Thread(
                 target=self._run, args=(self._stop,), daemon=True,
                 name="host-profiler",
@@ -428,12 +439,14 @@ class HostProfiler:
     def reset_stats(self) -> None:
         """Forget attribution (bench per-config isolation; the sampler
         thread and lifecycle state are untouched)."""
+        self._sample()  # CPU used up to here belongs to what is forgotten
         with self._flush_lock, self._lock:
             self._sites.clear()
             self._stacks.clear()
-            self._span_ns.clear()
+            self._span_stats.clear()
             self._source_ns.clear()
             self._role_stats.clear()
+            self._proc_ns = 0
             self.samples = 0
             self.idle_samples = 0
             self.busy_ns = 0
@@ -495,33 +508,37 @@ class HostProfiler:
         if len(self._section_pending) < 1024:
             self._section_pending.append(int(dur_ns))
 
+    def note_thread_exit(self) -> None:
+        """The last act of a thread that may live under a pass (an HTTP
+        or RPC connection): hand its own CPU clock to the ledger, which
+        charges its role with what no pass had read. One append, no
+        lock; bounded like the GC buffers (the loss shows as
+        ``(unaccounted)``)."""
+        if _enabled and len(self._exits) < 4096:
+            self._exits.append(
+                (threading.current_thread(), time.thread_time_ns())
+            )
+
     # -- sampler ---------------------------------------------------------
 
     def _run(self, stop: threading.Event) -> None:
-        last = now_ns()
         interval = self.interval_s
         idle_streak = 0
         next_flush = 0.0
         while not stop.wait(interval):
             self.cur_interval_s = interval
             t0 = now_ns()
-            # wall time since the previous sample is what this sample's
-            # busy threads are charged with (capped: a sampler starved
-            # for seconds must not attribute the whole gap to whatever
-            # runs at wakeup)
-            dt = min(t0 - last, 2_000_000_000)
-            last = t0
-            if _enabled:
-                busy = self._sample(dt)
-                if busy:
-                    idle_streak = 0
-                    interval = self.interval_s
-                else:
-                    # adaptive idle backoff: a quiet agent converges to
-                    # idle_interval_s, ~10x fewer wakeups
-                    idle_streak += 1
-                    if idle_streak >= 50:
-                        interval = min(interval * 2, self.idle_interval_s)
+            if not _enabled:
+                self._primed = False
+            elif self._sample():
+                idle_streak = 0
+                interval = self.interval_s
+            else:
+                # adaptive idle backoff: a quiet agent converges to
+                # idle_interval_s, ~10x fewer wakeups
+                idle_streak += 1
+                if idle_streak >= 50:
+                    interval = min(interval * 2, self.idle_interval_s)
             now = time.monotonic()
             if now >= next_flush:
                 next_flush = now + self.flush_interval_s
@@ -529,51 +546,107 @@ class HostProfiler:
                     self._flush()
                 except Exception:  # flush must never kill the sampler
                     pass
-            self._sampler_ns += now_ns() - t0
+            self._sampler_ns += (took := now_ns() - t0)
+            # a pass holds the interpreter for a syscall a thread (6 us
+            # each on the chip's host): at most a hundredth of the time
+            interval = max(interval, min(took * 1e-7, self.idle_interval_s))
 
-    def _sample(self, dt_ns: int) -> bool:
-        """One pass over every live thread's current frame. Returns
-        whether any thread was busy (drives the adaptive interval)."""
+    def _sample(self) -> bool:
+        """One pass over every live thread's CPU clock (the sampler's,
+        or snapshot()'s on the reader's thread). What a thread used
+        since the previous pass goes to its role — exact, however long
+        ago that was — and, at the frame it is met in, to (role, span,
+        site) and the collapsed stack; inside a span, the pass's wall
+        less that CPU is wait. Returns whether any other thread's clock
+        moved (drives the adaptive interval)."""
         from . import clusterobs as _clusterobs, trace as _trace
 
         me = threading.get_ident()
         spans = _trace.thread_spans()
         sources = _clusterobs.thread_sources()
-        frames = sys._current_frames()
-        busy_any = False
+        read = time.clock_gettime_ns
         code_cache = self._code_cache
+        ledger = self._threads
+        busy_any, frames = False, None
         with self._lock:
-            self.samples += 1
-            for tid, frame in frames.items():
-                if tid == me:
-                    continue
-                code = frame.f_code
-                cached = code_cache.get(code)
-                if cached is None:
-                    cached = self._describe(code)
-                    if len(code_cache) < 8192:
-                        code_cache[code] = cached
-                label, site, is_idle = cached
-                if is_idle:
-                    continue
-                busy_any = True
-                role = self._roles.get(tid)
-                if role is None:
-                    role = self._refresh_role(tid)
-                span = spans.get(tid) or "-"
-                key = (role, span, site)
-                ent = self._sites.get(key)
+            t = now_ns()
+            # the cap is for wait alone (a sampler starved for seconds
+            # must not call the whole gap a wait); CPU needs none
+            dt_ns = min(t - self._last_ns, 2_000_000_000)
+            self._last_ns = t
+            credit, self._primed = self._primed, True
+            proc = time.process_time_ns()
+            if credit:
+                self.samples += 1
+                self._proc_ns += proc - self._proc_last
+            self._proc_last = proc
+            n = len(self._exits)  # the exiting threads only append
+            for th, cpu in self._exits[:n]:
+                ent = ledger.pop(th, None)
+                self._gone.add(th)
+                cpu -= ent[2] if ent else 0
+                if credit and cpu > 0:
+                    self.busy_ns += cpu
+                    self._charge(self._role_stats, _role_of(th.name), cpu)
+            del self._exits[:n]
+            threads = threading.enumerate()
+            for th in threads:
+                ent = ledger.get(th)
                 if ent is None:
+                    if th.native_id is None or th in self._gone:
+                        continue
+                    # born since the last pass: all it used counts
+                    ent = ledger[th] = [
+                        _role_of(th.name), _cpu_clock_of(th.native_id), 0, 0
+                    ]
+                try:
+                    cpu = read(ent[1])
+                except OSError:
+                    continue  # died under the pass: keeps what was read
+                d_ns = cpu - ent[2]
+                ent[2] = cpu
+                tid = th.ident
+                span = spans.get(tid)
+                if not credit or (d_ns <= 0 and span is None):
+                    continue
+                role = ent[0]
+                wait_ns = 0 if span is None else dt_ns - d_ns
+                span = span or "-"
+                self._charge(self._role_stats, role, d_ns, wait_ns)
+                self._charge(self._span_stats, span, d_ns, wait_ns)
+                if d_ns <= 0:
+                    continue
+                busy_any = busy_any or tid != me
+                self.busy_ns += d_ns
+                ent[3] += d_ns
+                if ent[3] < WALK_NS:
+                    continue
+                d_ns, ent[3] = ent[3], 0
+                frames = frames or sys._current_frames()
+                f = frames.get(tid)
+                if f is None:
+                    continue  # ended since its clock was read
+                descs = []  # leaf first
+                while f is not None and len(descs) < self.max_depth:
+                    c = f.f_code
+                    cc = code_cache.get(c)
+                    if cc is None:
+                        cc = self._describe(c)
+                        if len(code_cache) < 8192:
+                            code_cache[c] = cc
+                    descs.append(cc)
+                    f = f.f_back
+                key = (role, span, descs[0][1])
+                site = self._sites.get(key)
+                if site is None:
                     if len(self._sites) >= self.max_sites:
                         key = (role, span, OTHER_SITE)
                         self.sites_evicted += 1
-                        ent = self._sites.get(key)
-                    if ent is None:
-                        ent = self._sites[key] = [0, 0]
-                ent[0] += 1
-                ent[1] += dt_ns
-                self.busy_ns += dt_ns
-                self._span_ns[span] = self._span_ns.get(span, 0) + dt_ns
+                        site = self._sites.get(key)
+                    if site is None:
+                        site = self._sites[key] = [0, 0]
+                site[0] += 1
+                site[1] += d_ns
                 # source dimension (clusterobs thread registry): only
                 # threads currently serving an attributed request carry
                 # one — handler CPU lands on its source node/namespace
@@ -585,45 +658,48 @@ class HostProfiler:
                     ):
                         src = OTHER_SITE
                     self._source_ns[src] = (
-                        self._source_ns.get(src, 0) + dt_ns
+                        self._source_ns.get(src, 0) + d_ns
                     )
-                rs = self._role_stats.get(role)
-                if rs is None:
-                    rs = self._role_stats[role] = [0, 0]
-                rs[0] += 1
-                rs[1] += dt_ns
-                # collapsed stack (flamegraph surface): root-first
-                parts = []
-                f = frame
-                depth = 0
-                while f is not None and depth < self.max_depth:
-                    c = f.f_code
-                    cc = code_cache.get(c)
-                    if cc is None:
-                        cc = self._describe(c)
-                        if len(code_cache) < 8192:
-                            code_cache[c] = cc
-                    parts.append(cc[0])
-                    f = f.f_back
-                    depth += 1
-                parts.append(f"{role};{span}")
-                parts.reverse()
-                stack_key = ";".join(parts)
+                # collapsed stack (flamegraph surface): root-first,
+                # weighted by CPU microseconds
+                descs.reverse()
+                stack_key = f"{role};{span};" + ";".join(
+                    [d[0] for d in descs]
+                )
                 cnt = self._stacks.get(stack_key)
                 if cnt is None:
                     if len(self._stacks) >= self.max_stacks:
                         self.stacks_dropped += 1
                         continue
-                    self._stacks[stack_key] = 1
-                else:
-                    self._stacks[stack_key] = cnt + 1
-            if not busy_any:
-                self.idle_samples += 1
+                    cnt = 0
+                self._stacks[stack_key] = cnt + max(1, d_ns // 1000)
+            if len(ledger) > len(threads) or self._gone:
+                live = set(threads)
+                for th in [k for k in ledger if k not in live]:
+                    del ledger[th]
+                self._gone &= live
+            if credit:
+                self.idle_samples += not busy_any
+                # the account closes: the process's CPU less every
+                # role's is native threads (XLA/PJRT) and what was missed
+                self._role_stats[UNACCOUNTED] = [
+                    0, max(0, self._proc_ns - self.busy_ns), 0
+                ]
         return busy_any
 
     @staticmethod
-    def _describe(code) -> tuple[str, str, bool]:
-        """(frame label, leaf-site label, idle?) for one code object —
+    def _charge(table: dict, key: str, cpu_ns: int, wait_ns: int = 0) -> None:
+        ent = table.get(key)
+        if ent is None:
+            ent = table[key] = [0, 0, 0]
+        ent[2] += wait_ns
+        if cpu_ns > 0:
+            ent[0] += 1
+            ent[1] += cpu_ns
+
+    @staticmethod
+    def _describe(code) -> tuple[str, str]:
+        """(frame label, leaf-site label) for one code object —
         computed once and cached; the per-sample path is dict hits."""
         fn = code.co_filename
         name = code.co_name
@@ -632,25 +708,10 @@ class HostProfiler:
             # sampler's only chance to run "inside" one is while the
             # Python gc callback executes, so the entire collection gap
             # lands on this frame — name it what it is
-            return "(gc-collect)", "(gc-collect)", False
+            return "(gc-collect)", "(gc-collect)"
         base = os.path.basename(fn)
         mod = base[:-3] if base.endswith(".py") else base
-        label = f"{mod}.{name}"
-        site = f"{name} ({base}:{code.co_firstlineno})"
-        idle = name in _IDLE_NAMES or (
-            fn.startswith(_STDLIB_DIR) and base in _IDLE_STDLIB_BASENAMES
-        )
-        return label, site, idle
-
-    def _refresh_role(self, tid: int) -> str:
-        names = {t.ident: t.name for t in threading.enumerate()}
-        for ident, name in names.items():
-            if ident not in self._roles:
-                self._roles[ident] = _role_of(name)
-        role = self._roles.get(tid)
-        if role is None:
-            role = self._roles[tid] = "other"
-        return role
+        return f"{mod}.{name}", f"{name} ({base}:{code.co_firstlineno})"
 
     # -- flush: buffered GC events + runtime gauges ----------------------
 
@@ -703,11 +764,19 @@ class HostProfiler:
         fds = _count_fds()
         if fds is not None:
             metrics.set_gauge("nomad.runtime.fds", float(fds))
-        # prune role cache + the trace-side span registry + the
-        # clusterobs source registry for dead tids
+        # the cumulative sums, for an operator's scrape
+        with self._lock:
+            cpu = {r: s[1] for r, s in self._role_stats.items()}
+            waits = {k: s[2] for k, s in self._span_stats.items() if k != "-"}
+        for role, ns in cpu.items():
+            metrics.set_gauge(f"nomad.host.cpu_seconds.{role}", ns / 1e9)
+        for span, ns in waits.items():
+            metrics.set_gauge(
+                f"nomad.host.wait_seconds.{span}", max(0, ns) / 1e9
+            )
+        # prune the trace-side span registry + the clusterobs source
+        # registry for dead tids
         live = {t.ident for t in threading.enumerate()}
-        for tid in [t for t in self._roles if t not in live]:
-            self._roles.pop(tid, None)
         _trace.prune_thread_spans(live)
         from . import clusterobs as _clusterobs
 
@@ -731,6 +800,8 @@ class HostProfiler:
 
     def snapshot(self, top: int = 50) -> dict:
         """The /v1/profile/status payload."""
+        if _enabled:
+            self._sample()
         try:
             self._flush()
         except Exception:
@@ -740,9 +811,12 @@ class HostProfiler:
                 self._sites.items(), key=lambda kv: -kv[1][1]
             )[: max(1, top)]
             spans = {
-                k: round(v / 1e9, 4)
+                k: {
+                    "cpu_seconds": round(v[1] / 1e9, 4),
+                    "wait_seconds": round(max(0, v[2]) / 1e9, 4),
+                }
                 for k, v in sorted(
-                    self._span_ns.items(), key=lambda kv: -kv[1]
+                    self._span_stats.items(), key=lambda kv: -kv[1][1]
                 )
             }
             sources = {
@@ -752,7 +826,11 @@ class HostProfiler:
                 )[: max(1, top)]
             }
             roles = {
-                r: {"samples": s[0], "busy_seconds": round(s[1] / 1e9, 4)}
+                r: {
+                    "samples": s[0],
+                    "busy_seconds": round(s[1] / 1e9, 4),
+                    "wait_seconds": round(max(0, s[2]) / 1e9, 4),
+                }
                 for r, s in sorted(self._role_stats.items())
             }
             wall_ns = max(1, now_ns() - self._started_ns)
@@ -779,8 +857,8 @@ class HostProfiler:
                     for (role, span, site), ent in sites
                 ],
                 "spans": spans,
-                # handler CPU x source (clusterobs dimension): seconds
-                # of busy samples taken while the thread was serving an
+                # handler CPU x source (clusterobs dimension): CPU
+                # seconds charged while the thread was serving an
                 # attributed request for that source
                 "sources": sources,
                 "threads": roles,
@@ -856,7 +934,7 @@ def _install(prof: HostProfiler) -> HostProfiler:
     the test isolation hook, mirroring solverobs._install. The caller
     owns stopping the old instance's thread if it started one."""
     global _global, start, stop, running, configure, reset_stats
-    global snapshot, collapsed, note_gc_section
+    global snapshot, collapsed, note_gc_section, note_thread_exit
     old = _global
     _global = prof
     start = prof.start
@@ -867,6 +945,7 @@ def _install(prof: HostProfiler) -> HostProfiler:
     snapshot = prof.snapshot
     collapsed = prof.collapsed
     note_gc_section = prof.note_gc_section
+    note_thread_exit = prof.note_thread_exit
     return old
 
 
@@ -878,3 +957,4 @@ reset_stats = _global.reset_stats
 snapshot = _global.snapshot
 collapsed = _global.collapsed
 note_gc_section = _global.note_gc_section
+note_thread_exit = _global.note_thread_exit

@@ -31,7 +31,7 @@ import time
 from concurrent.futures import ThreadPoolExecutor
 from typing import Callable, Optional
 
-from .. import clusterobs, codec, metrics, trace
+from .. import clusterobs, codec, hostobs, metrics, trace
 from .. import faultplane
 from .keyring import ensure_keyring
 from .wire import (
@@ -298,6 +298,7 @@ class RPCServer:
             logger.exception("rpc connection handler failed")
         finally:
             self._drop_conn(conn)
+            hostobs.note_thread_exit()  # a thread a connection
 
     def _handle_rpc_conn(self, conn: socket.socket) -> None:
         wlock = threading.Lock()

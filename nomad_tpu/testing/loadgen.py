@@ -560,8 +560,9 @@ def run_soak(
     from .. import hostobs
 
     # NOMAD_TPU_SOAK_PROFILE=0 turns the measurement apparatus off
-    # (role attribution degrades to empty; the gated CPU stat is
-    # process_time and survives) — also the A/B knob for isolating
+    # (no sampler thread: sites and stacks stay empty; CPU by role is
+    # still read from the threads' clocks at the window's two ends, and
+    # the gated CPU stat is process_time) — also the A/B knob for isolating
     # profiler-load effects on race-timing-sensitive soaks.
     profile_on = os.environ.get("NOMAD_TPU_SOAK_PROFILE", "1") != "0"
     prof = hostobs.HostProfiler(interval_s=0.01, idle_interval_s=0.02)
@@ -664,11 +665,10 @@ def run_soak(
         # thread's actual CPU): one process hosts the whole control
         # plane here, so this is the fleet's server cost — an UPPER
         # bound, since the in-process generator's own threads count too.
-        # The profiler role table rides along as the attribution view;
-        # its numbers are busy WALL (a thread parked in a C call —
-        # time.sleep, a device wait — samples busy at the calling
-        # frame, the documented hostobs conflation), so they apportion
-        # cost by role but must never be summed as CPU.
+        # The profiler role table rides along as the attribution view:
+        # CPU seconds by thread role from the same kernel clocks (the
+        # keys keep their older names), `(unaccounted)` — native
+        # threads — among the server's.
         roles = prof_snap.get("threads") or {}
         client_roles = {"loadgen", "main"}
         server_busy_s = sum(

@@ -80,6 +80,8 @@ def pytest_collection_modifyitems(items):
 _PINNED_TO_ITS_OWN_END = {
     "test_bench_mixed_backlog.py::test_the_cell_and_its_metrics_are_"
     "appended_and_nothing_else_moved": "borg2011-12k.mixed-backlog",
+    "test_bench_bands.py::test_the_cell_and_its_metrics_are_appended":
+        "borg2011-12k-bands.prod-backlog",
 }
 
 

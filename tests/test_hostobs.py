@@ -1,5 +1,8 @@
-"""Continuous host-profiling tests (nomad_tpu/hostobs.py): sampler
-attribution units (role x span x function, bounded ledgers), TimedLock
+"""Continuous host-profiling tests (nomad_tpu/hostobs.py): the
+thread-CPU ledger (CPU by role from the kernel's per-thread clocks, wait
+inside spans, threads shorter than a pass, the account closing against
+the process's clock), sampler attribution units (role x span x
+function, bounded ledgers), TimedLock
 wait accounting + Condition compatibility, GC/runtime telemetry, the
 /v1/profile/* surface + ACL battery + debug-bundle capture, the
 single-flight guard on /v1/agent/pprof/profile, profiler/trace teardown
@@ -156,8 +159,8 @@ def test_sampler_attributes_role_span_function():
         s for s in snap["top_sites"]
         if s["role"] == "solve" and s["span"] == "unit.span"
     )
-    assert "_spin_until" in site["site"]
-    assert snap["spans"]["unit.span"] > 0
+    assert "_spin_until" in site["site"] and site["seconds"] > 0
+    assert snap["spans"]["unit.span"]["cpu_seconds"] > 0
     assert snap["threads"]["solve"]["busy_seconds"] > 0
     # collapsed stacks carry the role;span prefix and end in a count
     lines = prof.collapsed().splitlines()
@@ -168,23 +171,219 @@ def test_sampler_attributes_role_span_function():
         assert stack and int(count) >= 1
 
 
-def test_sampler_idle_thread_not_attributed():
-    """A thread parked in Event.wait samples as idle (the
-    zero-allocation fast path), not busy."""
-    prof = HostProfiler(interval_s=0.002)
-    parked = threading.Event()
-    t = threading.Thread(
-        target=parked.wait, args=(20,), name="unit-parked", daemon=True
-    )
-    t.start()
+# What a thread does for the window, as code of this repo would: the
+# leaf under each wait is a C call made from a plain function of a
+# plain file — exactly what the deleted frame-name list called "busy".
+
+
+def _burns(stop, held):
+    while not stop.is_set():
+        sum(range(50))
+
+
+def _sleeps(stop, held):
+    while not stop.is_set():
+        time.sleep(0.01)
+
+
+def _blocks_on_a_lock(stop, held):
+    held.acquire()
+
+
+def _parks(stop, held):
+    stop.wait(20)
+
+
+@pytest.mark.parametrize(
+    "what, span, gets_cpu",
+    [
+        (_burns, "", True),
+        (_sleeps, "unit.sleep", False),
+        (_blocks_on_a_lock, "unit.lock", False),
+        (_parks, "", False),
+    ],
+    ids=["burns_cpu", "asleep_in_a_span", "blocked_in_a_span", "parked"],
+)
+def test_cpu_goes_to_the_thread_that_ran_and_wait_to_the_span(
+        what, span, gets_cpu):
+    """The rule that replaced the frame-name heuristic: a thread is
+    charged what its own CPU clock moved — so a thread asleep in
+    time.sleep, blocked in Lock.acquire or parked in Event.wait gets
+    none, whatever its leaf frame is called — and one that waits INSIDE
+    a span is charged the wall it spent there, less its CPU, as wait."""
+    was = trace.enabled()
+    trace.set_enabled(True)
+    prof = HostProfiler()
+    stop, held = threading.Event(), threading.Lock()
+    held.acquire()
+    ctx = trace.start_trace("unit.trace") if span else None
+
+    def body():
+        if ctx is None:
+            return what(stop, held)
+        with trace.use(ctx), trace.span(ctx, span):
+            what(stop, held)
+
+    t = threading.Thread(target=body, name="unit-subject", daemon=True)
     try:
-        prof.start()
-        assert wait_until(lambda: prof.samples >= 20, 10)
+        prof._sample()  # takes the readings; charges nothing
+        t.start()
+        time.sleep(0.4)
+        prof._sample()
     finally:
-        prof.stop()
-        parked.set()
+        stop.set()
+        held.release()
         t.join(timeout=5)
-    assert not any(role == "unit-parked" for role, _, _ in prof._sites)
+        trace.set_enabled(was)
+    snap = prof.snapshot()
+    mine = snap["threads"].get("unit-subject", {})
+    sites = [s for s in snap["top_sites"] if s["role"] == "unit-subject"]
+    if gets_cpu:
+        assert mine["busy_seconds"] > 0.1
+        assert any(what.__name__ in s["site"] for s in sites), sites
+    else:
+        assert mine.get("busy_seconds", 0.0) < 0.05, mine
+    if span:
+        assert mine["wait_seconds"] > 0.3, mine
+        assert snap["spans"][span]["wait_seconds"] > 0.3
+        assert snap["spans"][span]["cpu_seconds"] < 0.05
+    else:
+        assert mine.get("wait_seconds", 0.0) == 0.0, mine
+
+
+def test_a_reused_ident_does_not_inherit_a_role():
+    """The ledger is keyed by the Thread object: a thread that gets the
+    ident (and possibly the tid) of one that died takes its own role,
+    and the dead one keeps what was last read of it."""
+    prof = HostProfiler()
+    prof._sample()
+
+    def run_and_meet(name):
+        stop = threading.Event()
+        t = threading.Thread(target=_burns, args=(stop, None), name=name)
+        t.start()
+        role = hostobs._role_of(name)
+        assert wait_until(
+            lambda: prof._sample() and role in prof._role_stats, 10, 0.005
+        )  # met alive, with CPU used
+        stop.set()
+        t.join(timeout=5)
+        return t.ident
+
+    rounds = reused = 0
+    while rounds < 20 and not reused:
+        rounds += 1
+        ident = run_and_meet("raft-a")
+        after_a = prof.snapshot()["threads"]["raft"]["busy_seconds"]
+        reused = run_and_meet("tpu-batch-solve-b") == ident
+    threads = prof.snapshot()["threads"]
+    assert threads["solve"]["busy_seconds"] > 0
+    # raft has what its own threads used: it kept it, and got no more
+    assert threads["raft"]["busy_seconds"] == after_a > 0
+    assert not any(th.name == "raft-a" for th in prof._threads)
+
+
+def test_a_thread_shorter_than_a_pass_is_counted_through_the_exit_call():
+    """Connection threads live under a pass: what they used arrives
+    with note_thread_exit(), under the role their name gives."""
+    old = hostobs._install(HostProfiler())
+    prof = hostobs.profiler()
+    try:
+        prof._sample()
+
+        def conn(note):
+            t0 = time.thread_time()
+            while time.thread_time() - t0 < 0.01:
+                sum(range(50))
+            if note:
+                hostobs.note_thread_exit()
+
+        for note, name in [(True, "rpc-conn"), (False, "unit-silent")] * 10:
+            t = threading.Thread(target=conn, args=(note,), name=name)
+            t.start()
+            t.join(timeout=5)
+        assert len(prof._exits) == 10
+        snap = prof.snapshot()
+    finally:
+        hostobs._install(old)
+    assert prof._exits == []
+    assert 0.09 <= snap["threads"]["rpc"]["busy_seconds"] < 0.2
+    # no pass met the silent ones: their CPU is the account's residual
+    assert "unit-silent" not in snap["threads"]
+    assert snap["threads"][hostobs.UNACCOUNTED]["busy_seconds"] >= 0.08
+
+
+def test_the_account_closes_against_the_process_clock():
+    """Sum of the roles' CPU + (unaccounted) is what the process's own
+    CPU clock moved between two snapshots, to within the clocks' step,
+    and no role's sum ever decreases."""
+    prof = HostProfiler(interval_s=0.002)
+    stop = threading.Event()
+    prof.start()
+    burners = [
+        threading.Thread(target=_burns, args=(stop, None), name=n)
+        for n in ("tpu-batch-solve", "plan-applier", "unit-other")
+    ]
+    try:
+        s0 = prof.snapshot()["threads"]
+        p0 = time.process_time_ns()
+        for t in burners:
+            t.start()
+        time.sleep(0.2)
+        mid = prof.snapshot()["threads"]
+        time.sleep(0.2)
+        stop.set()
+        for t in burners:
+            t.join(timeout=5)
+        p1 = time.process_time_ns()
+        s1 = prof.snapshot()["threads"]
+    finally:
+        stop.set()
+        prof.stop()
+    total = lambda s: sum(v["busy_seconds"] for v in s.values())  # noqa: E731
+    assert total(s1) - total(s0) == pytest.approx((p1 - p0) / 1e9, abs=0.06)
+    assert s1[hostobs.UNACCOUNTED]["busy_seconds"] >= 0.0
+    for role, v in mid.items():
+        if role != hostobs.UNACCOUNTED:  # the residual is no sum
+            assert s1[role]["busy_seconds"] >= v["busy_seconds"], role
+    assert s1["solve"]["busy_seconds"] > 0.05
+    assert s1["host-profiler"]["busy_seconds"] > 0.0  # its own, counted
+
+
+def test_a_failing_clock_read_is_skipped(monkeypatch):
+    """A thread that died between the enumeration and the read gives
+    OSError: the pass skips it and reads the others."""
+    # a tid over the kernel's pid_max: what a thread that has gone
+    # reads as, without this sandbox's quick reuse of tids
+    dead_clock = hostobs._cpu_clock_of(1 << 27)
+    with pytest.raises(OSError):
+        time.clock_gettime_ns(dead_clock)
+    stop = threading.Event()
+    victim = threading.Thread(
+        target=_burns, args=(stop, None), name="unit-victim", daemon=True
+    )
+    other = threading.Thread(
+        target=_burns, args=(stop, None), name="unit-read", daemon=True
+    )
+    victim.start()
+    other.start()
+    real = hostobs._cpu_clock_of
+    monkeypatch.setattr(
+        hostobs, "_cpu_clock_of",
+        lambda tid: dead_clock if tid == victim.native_id else real(tid),
+    )
+    prof = HostProfiler()
+    try:
+        prof._sample()
+        time.sleep(0.1)
+        prof._sample()  # must not raise
+    finally:
+        stop.set()
+        victim.join(timeout=5)
+        other.join(timeout=5)
+    threads = prof.snapshot()["threads"]
+    assert "unit-victim" not in threads
+    assert threads["unit-read"]["busy_seconds"] > 0.01
 
 
 def test_sampler_site_ledger_bounded():
@@ -236,17 +435,31 @@ from nomad_tpu.hostobs import HostProfiler
 prof = HostProfiler(interval_s=0.001, idle_interval_s=0.05)
 prof.start()
 try:
-    # Park in Event.wait — leaf in threading.py, classified idle. After
-    # 50 consecutive idle samples the effective interval climbs past
-    # the busy cadence; assert on the published cur_interval_s.
+    # Park in Event.wait: no thread's CPU clock moves. After 50
+    # consecutive passes in which none moved the effective interval
+    # climbs to the idle ceiling; assert on the published
+    # cur_interval_s.
     parked = threading.Event()
     deadline = time.monotonic() + 15
     engaged = False
     while time.monotonic() < deadline and not engaged:
         parked.wait(0.3)
-        engaged = prof.cur_interval_s > prof.interval_s
+        engaged = prof.cur_interval_s >= prof.idle_interval_s
     assert engaged, prof.cur_interval_s
     assert prof.idle_samples > 0
+    # ... and the first pass that sees a clock move snaps it back
+    stop = threading.Event()
+    def burn():
+        while not stop.is_set():
+            sum(range(50))
+    t = threading.Thread(target=burn, daemon=True)
+    t.start()
+    deadline = time.monotonic() + 15
+    while time.monotonic() < deadline and engaged:
+        time.sleep(0.05)
+        engaged = prof.cur_interval_s >= prof.idle_interval_s
+    stop.set()
+    assert not engaged, prof.cur_interval_s
 finally:
     prof.stop()
 print("BACKOFF OK")
@@ -254,11 +467,12 @@ print("BACKOFF OK")
 
 
 def test_sampler_adaptive_idle_backoff():
-    """Clean subprocess: inside the full suite, daemon threads leaked
-    by earlier modules (raft tickers etc.) sample as busy — the
-    documented C-call conflation — so the PROCESS never accumulates 50
-    consecutive idle passes and the backoff legitimately never engages.
-    The property under test is the sampler's, not the suite's."""
+    """An idle pass is one in which no other thread's CPU clock moved.
+    Clean subprocess: inside the full suite, daemon threads leaked by
+    earlier modules (raft tickers etc.) do run a little in every
+    window, so the PROCESS never accumulates 50 consecutive idle passes
+    and the backoff legitimately never engages. The property under test
+    is the sampler's, not the suite's."""
     import subprocess
     import sys
 
@@ -271,6 +485,33 @@ def test_sampler_adaptive_idle_backoff():
     )
     assert proc.returncode == 0, proc.stderr[-2000:]
     assert "BACKOFF OK" in proc.stdout
+
+
+def test_a_slow_pass_stretches_the_period():
+    """A pass may take a hundredth of the time: where it is slow (a
+    clock read is a 6 us syscall on the chip's host, a thread each) the
+    period stretches under load too, up to the idle ceiling, and the
+    roles' CPU stays exact — the clocks are cumulative."""
+    prof = HostProfiler(interval_s=0.001, idle_interval_s=0.08)
+    real = prof._sample
+
+    def slow_pass():
+        time.sleep(0.004)  # 100 x 4 ms is over the ceiling
+        real()
+        return True  # "busy": the idle backoff is not what stretches it
+
+    prof._sample = slow_pass
+    prof.start()
+    try:
+        assert wait_until(lambda: prof.cur_interval_s >= 0.08, 10), (
+            prof.cur_interval_s
+        )
+        prof._sample = real  # fast again: back toward the configured one
+        assert wait_until(lambda: prof.cur_interval_s < 0.04, 10), (
+            prof.cur_interval_s
+        )
+    finally:
+        prof.stop()
 
 
 def test_start_stop_refcounted_no_thread_leak():
@@ -713,7 +954,7 @@ def test_e2e_host_attribution_acceptance(tmp_path, capsys):
         capsys.readouterr()
         assert cmd_operator_profile_status(args) == 0
         out = capsys.readouterr().out
-        assert "Top self-time sites" in out
+        assert "Top CPU sites" in out and "CPU by role" in out
         assert "GC" in out and "Runtime" in out
         # ... and `operator top` gained the Host row
         targs = SimpleNamespace(
