@@ -219,6 +219,41 @@ class TierSlabs:
         return [self.prios[k] for k in stand], out[:, :, :NUM_RES]
 
 
+class UsageRows:
+    """Committed usage by node as the [N, NUM_RES] rows a NodeTable
+    carries, kept current from the store's aggregate (`node_usage_many`)
+    the way TierSlabs keeps the tiers: a node whose entry is the SAME
+    tuple as last time has not changed, so only the nodes a plan touched
+    are rewritten, in one numpy call. A fresh instance has read nothing:
+    its first read writes every row."""
+
+    def __init__(self, index_of: dict[str, int]) -> None:
+        self.index_of = index_of
+        self.ids = list(index_of)  # node order
+        self.rows: list = [None] * len(self.ids)  # as last read
+        self.base = np.zeros((len(self.ids), NUM_RES), dtype=np.int64)
+        self.rewritten = 0  # rows the last read wrote
+
+    def read(self, usage_many, adj: dict) -> np.ndarray:
+        """The batch's `used` [N, NUM_RES], the caller's own array.
+        `usage_many`: the snapshot's bulk reader, node ids -> for each
+        its (cpu, mem, disk, complex); `adj`: the batch's own view of a
+        few nodes, node id -> [cpu, mem, disk] to add (its stops
+        negative); a node outside the table is skipped."""
+        rows = usage_many(self.ids)
+        old, self.rows = self.rows, rows
+        changed = list(compress(range(len(rows)), map(is_not, rows, old)))
+        if changed:
+            self.base[changed] = [rows[i][:NUM_RES] for i in changed]
+        self.rewritten = len(changed)
+        out = self.base.copy()
+        for nid, vec in adj.items():
+            i = self.index_of.get(nid)
+            if i is not None:
+                out[i] += vec
+        return out
+
+
 def build_node_table(
     nodes: list[Node], allocs_by_node, usage_of=None
 ) -> NodeTable:

@@ -48,7 +48,8 @@ from ...structs.structs import AllocDeploymentStatus
 from ...structs.placement_batch import PlacementBatch
 from ..preemption import PRIORITY_DELTA
 from .lower import (
-    LoweredGroup, TierSlabs, alloc_priority, build_node_table, lower_group,
+    LoweredGroup, TierSlabs, UsageRows, alloc_priority, build_node_table,
+    lower_group,
 )
 from .kernels import (
     pad_c,
@@ -216,6 +217,11 @@ class ResidentClusterState:
         # the skeleton's preemption tiers as the store last gave them
         # (lower.TierSlabs), for the batches that may preempt
         self._host_tiers = None
+        # ... and its usage rows (lower.UsageRows), for every batch
+        self._host_usage = None
+        # the node list last proven to be the skeleton's universe: the
+        # same list object needs no fingerprint walk
+        self._host_nodes: Optional[list] = None
         # the previous solve's returned table, held one solve gap so
         # the skeleton can harvest its lazily-built SoA columns
         self._last_table = None
@@ -299,17 +305,24 @@ class ResidentClusterState:
             (job.namespace, job.id, job.version, job.modify_index, tg_name)
         ] = (vers, tensors)
 
-    def host_table(self, nodes: list, allocs_by_node, usage_of,
+    def host_table(self, nodes: list, allocs_by_node, usage_of, usage,
                    tiers=None):
         """Cached build_node_table for the usage-aggregate path, with
         (tiers given: a batch that may preempt) or without the
-        preemption tiers.
+        preemption tiers. `usage`: (the snapshot's bulk reader
+        `node_usage_many`, the batch's per-node adjustments) — the rows
+        come from lower.UsageRows, rewriting only the nodes whose entry
+        changed; `usage_of` builds the rows of a rebuilt skeleton.
 
         Rebuilding the 100k-row host table every solve was the largest
         steady-state host cost of the sharded bench (~0.7s/solve at c2m
         scale, plus re-interning every constraint attribute). The
         skeleton (cap, index_of, dc codes, attr/driver interning) is
-        valid as long as every node's (id, modify_index) is unchanged.
+        valid as long as every node's (id, modify_index) is unchanged —
+        proven without a walk when `nodes` is the very list last proven:
+        ready_nodes hands out one cached list per datacenter set and
+        nodes-table index, every node write moves the index, and the
+        reference held here keeps the list's id from being reused.
 
         Every call returns a FRESH NodeTable object that shares only
         the immutable skeleton: pipelined batches overlap (batch N's
@@ -345,16 +358,25 @@ class ResidentClusterState:
                     setattr(out, col, cached)
             return out
 
+        from ... import metrics
+
         n = len(nodes)
         no_tiers = ([], np.zeros((0, n, 3), dtype=np.int64))
-        vers = tuple((node.id, node.modify_index) for node in nodes)
         skel = self._host_table
-        if skel is None or self._host_vers != vers:
+        rebuild = False
+        if skel is None or nodes is not self._host_nodes:
+            metrics.incr("nomad.tpu.lower_fingerprint_walks")
+            vers = tuple((node.id, node.modify_index) for node in nodes)
+            rebuild = skel is None or self._host_vers != vers
+        self._host_nodes = nodes
+        if rebuild:
             t = build_node_table(nodes, allocs_by_node, usage_of=usage_of)
             self._host_tiers = None
             if tiers is not None:
                 self._host_tiers = TierSlabs(t.index_of)
                 t.tier_prios, t.tier_used = self._host_tiers.read(*tiers)
+            # the usage rows start over too: the next read writes them all
+            self._host_usage = None
             # The cached skeleton carries NO snapshot accessor: holding
             # this solve's allocs_by_node closure would pin its whole
             # state snapshot for as long as the node fingerprint stays
@@ -378,12 +400,12 @@ class ResidentClusterState:
                     cached = getattr(last, col, None)
                     if cached is not None:
                         setattr(skel, col, cached)
-        used = np.empty((n, 3), dtype=np.int64)
-        for i, node in enumerate(nodes):
-            u = usage_of(node.id)
-            used[i, 0] = u[0]
-            used[i, 1] = u[1]
-            used[i, 2] = u[2]
+        if self._host_usage is None:
+            self._host_usage = UsageRows(skel.index_of)
+        used = self._host_usage.read(*usage)
+        metrics.observe(
+            "nomad.tpu.lower_usage_rewritten", self._host_usage.rewritten
+        )
         slabs = no_tiers
         if tiers is not None:
             if self._host_tiers is None:
@@ -1460,7 +1482,8 @@ class BatchSolver:
             # cross-solve host-table cache: same fingerprint discipline
             # as the resident device tensors (ResidentClusterState)
             table = self.resident.host_table(
-                nodes, live_allocs, usage_of, tiers
+                nodes, live_allocs, usage_of,
+                (self.state.node_usage_many, adj), tiers,
             )
             # lowered-skeleton cache rides the same fingerprint: valid
             # only for tables produced by this generation's skeleton

@@ -534,6 +534,13 @@ class OverlaySnapshot:
             return base
         return (base[0] + d[0], base[1] + d[1], base[2] + d[2], base[3] + d[3])
 
+    def node_usage_many(self, node_ids: list[str]) -> list[tuple]:
+        """node_usage for many nodes, overlaid as node_usage is (the
+        base's bulk reader would skip the pending deltas). A node without
+        a delta reads the base's very entry, so an identity reader
+        (lower.UsageRows) rewrites only the overlaid nodes."""
+        return list(map(self.node_usage, node_ids))
+
     def alloc_by_id(self, alloc_id: str):
         a = self._placed.get(alloc_id)
         if a is not None:

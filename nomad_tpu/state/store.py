@@ -114,6 +114,7 @@ IDX_ALLOCS_EVAL = "_idx_allocs_eval"
 # per-node path. Values are immutable tuples, replaced wholesale, so the
 # table obeys the same COW discipline as every other table.
 IDX_NODE_USED = "_idx_node_used"
+_NO_USAGE = (0, 0, 0, 0)  # a node without an entry: one object for all
 # priority -> count of non-terminal allocs at that job priority: the
 # cluster's preemption tiers by name. A few integers that let the batch
 # solver prove "no preemptible tier exists below this batch's priorities"
@@ -426,7 +427,19 @@ class _ReadMixin:
         write; the plan applier's vectorized verifier reads this instead of
         re-summing the node's allocs. (No lock needed: a single dict.get
         of an immutable tuple.)"""
-        return self._tables[IDX_NODE_USED].get(node_id, (0, 0, 0, 0))
+        return self._tables[IDX_NODE_USED].get(node_id, _NO_USAGE)
+
+    def node_usage_many(self, node_ids: list[str]) -> list[tuple]:
+        """node_usage for many nodes at once, in one pass that runs no
+        Python for a node. A node with nothing is the one shared
+        `_NO_USAGE`, and every other entry is replaced wholesale, so a
+        reader that keeps the last list (lower.UsageRows) finds what
+        changed by identity. No lock needed: dict.get of immutable
+        tuples."""
+        return list(
+            map(self._tables[IDX_NODE_USED].get, node_ids,
+                repeat(_NO_USAGE))
+        )
 
     def alloc_priority_tiers(self) -> list[int]:
         """Ascending job priorities that have at least one committed
