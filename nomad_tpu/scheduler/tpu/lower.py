@@ -52,6 +52,9 @@ class NodeTable:
     # and each tier's usage — feeds the preemption kernel's prefix sums
     tier_prios: list[int] = field(default_factory=list)
     tier_used: Optional[np.ndarray] = None  # [T, N, NUM_RES] int64
+    # standing priorities above the batch's tier ceiling, left out of
+    # the tiers: no group of the batch may evict them
+    tiers_above: int = 0
     # dedicated-core availability: total ids and ids held by live allocs
     # (cores ride OUTSIDE the dense NUM_RES columns — a static screen
     # here, exact id assignment at materialization, allocs_fit backstop)

@@ -21,6 +21,7 @@ from __future__ import annotations
 import logging
 import math
 import time
+from bisect import bisect_right
 from dataclasses import dataclass, field, fields as dataclass_fields
 from typing import NamedTuple, Optional
 
@@ -463,6 +464,16 @@ class ResidentClusterState:
             else:
                 self._cap_dev = jax.device_put(cap)
                 self._used_dev = jax.device_put(used)
+                # A chained solve adds its adjustments — a lane commit
+                # the parent's used' never saw — onto that jit output
+                # with the scatter-add (_chain_adj_add): its smallest
+                # bucket is compiled here, once, with the resident
+                # tensors, and not inside the first chained solve that
+                # has any. Every index is out of range: nothing lands.
+                idx = np.full(1, 1 << 30, dtype=np.int32)
+                rows = np.zeros((1, 3), dtype=np.int32)
+                _scatter_add_rows(_scatter_rows(
+                    self._used_dev, idx, rows, donate=False), idx, rows)
             # block before timestamping: device_put only ENQUEUES on
             # async backends, and an un-awaited span would read ~0 on
             # exactly the slow-link deployments the span exists to
@@ -1494,6 +1505,26 @@ class BatchSolver:
                 table.tier_prios, table.tier_used = TierSlabs(
                     table.index_of
                 ).read(*tiers)
+        if preempt_possible:
+            # The tier ceiling: the highest priority any group of the
+            # batch may evict. A tier above it is no group's victim — its
+            # usage is in `used` like any held alloc's — so the solve
+            # carries the tiers at or under it: a production batch beside
+            # a standing monitoring band keeps its three-tier program
+            # instead of compiling the next tier bucket, and makes no
+            # waterfill pass over a tier it may not take. The tiers are
+            # in ascending priority, so what is kept is a prefix, and
+            # every group's tier_limit counts within it (_tier_limit).
+            ceiling = max(
+                (a.job.priority for a in asks
+                 if self.config.preemption_enabled(a.job.type)),
+                default=0,
+            ) - PRIORITY_DELTA
+            k = bisect_right(table.tier_prios, ceiling)
+            if k < len(table.tier_prios):
+                table.tiers_above = len(table.tier_prios) - k
+                table.tier_prios = table.tier_prios[:k]
+                table.tier_used = table.tier_used[:k]
         return table, usage_of, adj
 
     def _solve_host_timed(self, asks: list[GroupAsk],
@@ -2153,7 +2184,8 @@ class BatchSolver:
             # have theirs: the program is one of kernels.preempt_programs
             tp = pad_t(t)
             tctx = trace.current()
-            with trace.span(tctx, "preempt.prefix", cpu=True, tiers=t):
+            with trace.span(tctx, "preempt.prefix", cpu=True, tiers=t,
+                            tiers_above=table.tiers_above):
                 prefix = np.zeros((tp, np_, 3), dtype=np.int32)
                 if t:
                     cum = np.cumsum(

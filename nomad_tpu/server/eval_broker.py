@@ -231,6 +231,9 @@ class EvalBroker:
         # redelivered eval's queue wait must not include the prior
         # attempt's processing time or the nack delay.
         self._wait_starts: dict[str, float] = {}
+        # (min_priority, event): set when an eval at or above that
+        # priority becomes ready (watch_ready)
+        self._ready_watch: list[tuple[int, threading.Event]] = []
         self._timer: Optional[threading.Thread] = None
         self._stop = threading.Event()
         self.stats = {
@@ -284,6 +287,19 @@ class EvalBroker:
     @property
     def enabled(self) -> bool:
         return self._enabled
+
+    def watch_ready(self, min_priority: int, event: threading.Event) -> None:
+        """Set `event` whenever an eval at or above `min_priority`
+        becomes ready: a worker blocked on something else (a commit)
+        wakes to serve it (TPUBatchWorker's interactive lane)."""
+        with self._lock:
+            self._ready_watch.append((min_priority, event))
+
+    def unwatch_ready(self, event: threading.Event) -> None:
+        with self._lock:
+            self._ready_watch = [
+                w for w in self._ready_watch if w[1] is not event
+            ]
 
     def _flush_locked(self) -> None:
         self._ready.clear()
@@ -533,6 +549,9 @@ class EvalBroker:
         )
         if ev.job_id:
             self._in_flight[(ev.namespace, ev.job_id)] = ev.id
+        for min_priority, event in self._ready_watch:
+            if ev.priority >= min_priority:
+                event.set()
         if bulk is not None:
             # enqueue_all collects per-type lists; the caller bulk-pushes
             # each heap once and broadcasts once after the loop
@@ -548,12 +567,23 @@ class EvalBroker:
     ) -> tuple[Optional[Evaluation], str]:
         """Blocking dequeue of the highest-priority ready eval among the
         given scheduler types. Returns (eval, token) or (None, "")."""
+        ev, token, _ready = self.dequeue_ready(schedulers, timeout_s)
+        return ev, token
+
+    def dequeue_ready(
+        self, schedulers: list[str], timeout_s: Optional[float] = None,
+        min_priority: int = 0,
+    ) -> tuple[Optional[Evaluation], str, int]:
+        """`dequeue` of an eval at or above `min_priority`, with the
+        instant it became ready on the trace clock (`trace.now_ns`; 0
+        when unknown). Returns (eval, token, ready_ns) or (None, "", 0)."""
         deadline = time.monotonic() + timeout_s if timeout_s is not None else None
         while True:
             wait_s = None
+            ready_ns = 0
             with self._cv:
                 if self._enabled:
-                    ev = self._pop_best_locked(schedulers)
+                    ev = self._pop_best_locked(schedulers, min_priority)
                     if ev is not None:
                         # pending -> in-flight: the admission bound
                         # covers the backlog, not work being processed
@@ -574,6 +604,8 @@ class EvalBroker:
                         ready_at = self._wait_starts.pop(ev.id, None)
                         if ready_at is not None:
                             wait_s = time.monotonic() - ready_at
+                            # time.monotonic is the trace clock's base
+                            ready_ns = int(ready_at * 1e9)
                         entry = self._traces.get(ev.id)
                         if entry is not None:
                             ctx, open_span = entry
@@ -594,7 +626,7 @@ class EvalBroker:
                 if deadline is not None:
                     remaining = deadline - time.monotonic()
                     if remaining <= 0:
-                        return None, ""
+                        return None, "", 0
                     self._cv.wait(remaining)
                 else:
                     self._cv.wait(1.0)
@@ -603,9 +635,11 @@ class EvalBroker:
         # edge the racecheck battery would have to carry forever
         if wait_s is not None:
             metrics.observe("nomad.broker.wait_seconds", wait_s)
-        return ev, token
+        return ev, token, ready_ns
 
-    def _pop_best_locked(self, schedulers: list[str]) -> Optional[Evaluation]:
+    def _pop_best_locked(
+        self, schedulers: list[str], min_priority: int = 0
+    ) -> Optional[Evaluation]:
         best_type = None
         best = None
         for stype in schedulers:
@@ -617,7 +651,7 @@ class EvalBroker:
                 continue
             if best is None or ev.priority > best.priority:
                 best, best_type = ev, stype
-        if best is None:
+        if best is None or best.priority < min_priority:
             return None
         return self._ready[best_type].pop()
 

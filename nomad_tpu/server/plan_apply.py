@@ -176,6 +176,11 @@ def _volume_overcommitted_nodes(snapshot, plan: Plan) -> set[str]:
     return bad
 
 
+def _not_live(snapshot, alloc_id: str) -> bool:
+    a = snapshot.alloc_by_id(alloc_id)
+    return a is None or a.terminal_status()
+
+
 def _fast_path_usage(snapshot, plan: Plan, node_id: str, node,
                      contrib: Optional[dict] = None):
     """Try to express one node's re-verification as a 3-vector compare.
@@ -286,9 +291,22 @@ def evaluate_plan(snapshot, plan: Plan) -> PlanResult:
     slow_ids: list[str] = []
     contrib: dict = {}  # per-plan shared-resources contribution memo
 
+    # A victim the store no longer holds live — evicted or stopped by a
+    # commit that landed first: a lane plan solved beside a batch in
+    # flight that chose it too — is not evicted twice. The node's
+    # placements counted on its room and are refused with it: the plan
+    # is trimmed there, and its eval retried.
+    victims_gone = {
+        node_id for node_id, victims in plan.node_preemptions.items()
+        if any(_not_live(snapshot, v.id) for v in victims)
+    }
+
     def verify_node(node_id: str, proposed) -> None:
         if node_id in vol_rejected:
             reject(node_id, "volume write-claim conflict")
+            return
+        if node_id in victims_gone:
+            reject(node_id, "a victim is no longer live")
             return
         add = batch_add.get(node_id)
         if not proposed and add is None:
@@ -318,6 +336,8 @@ def evaluate_plan(snapshot, plan: Plan) -> PlanResult:
     for node_id in batch_add:
         if node_id not in plan.node_allocation:
             verify_node(node_id, [])
+    for node_id in victims_gone - rejected_nodes:
+        reject(node_id, "a victim is no longer live")
     if fast_rows:
         rows = np.asarray(fast_rows, dtype=np.int64)
         fits = (rows[:, :3] <= rows[:, 3:]).all(axis=1)

@@ -20,6 +20,7 @@ import queue as queue_mod
 import sys
 import threading
 import time
+from collections import deque
 from concurrent.futures import CancelledError
 from typing import Optional
 
@@ -282,6 +283,19 @@ class Worker:
             sched.process(ev)
 
 
+class _Committed(threading.Event):
+    """A batch's commit-done flag that also wakes the solve thread,
+    which may be waiting on it beside the interactive lane."""
+
+    def __init__(self, wake: threading.Event) -> None:
+        super().__init__()
+        self._wake = wake
+
+    def set(self) -> None:
+        super().set()
+        self._wake.set()
+
+
 class TPUBatchWorker:
     """Drains up to `batch_size` ready evals per cycle and solves them in
     one batched tensor program.
@@ -326,9 +340,21 @@ class TPUBatchWorker:
                 os.environ.get("NOMAD_TPU_LANE_PRIORITY", "60") or 0
             )
         self.lane_priority = lane_priority
-        # an interactive eval pulled mid-drain, solved FIRST next
-        # cycle: (eval, token, hold time — its running lane clock)
-        self._held: Optional[tuple[Evaluation, str, float]] = None
+        # an interactive eval pulled mid-drain, solved at the first
+        # wait of the batch or FIRST next cycle: (eval, token, hold time
+        # — its running lane clock, (ready_ns, behind) of `lane.queue`)
+        self._held: Optional[tuple] = None
+        # what the solve thread is doing, and since when (trace clock):
+        # idle | lower | dispatch | chain.wait | stall | handoff — the
+        # `behind` of a lane eval's `lane.queue` span is the phase at the
+        # instant it became ready
+        self._phase = "idle"
+        self._phases: deque = deque(maxlen=256)
+        # wakes the solve thread where it blocks on a commit: set by the
+        # broker when an interactive eval becomes ready (watch_ready), by
+        # a batch's commit verdict (_Committed) and by the commit stage's
+        # take from the hand-off queue
+        self._wake = threading.Event()
         # Interactive-placement ledger: (raft index, {node_id: (cpu,
         # mem, disk)}) per lane commit that landed while a mega-batch
         # chain was in flight. A chained solve supersedes the committed
@@ -403,6 +429,10 @@ class TPUBatchWorker:
         self._prev = None
         self._held = None
         self._lane_ledger = []
+        self._phase = "idle"
+        self._phases.clear()
+        if self.lane_priority > 0:
+            self.server.eval_broker.watch_ready(self.lane_priority, self._wake)
         self._thread = threading.Thread(
             target=self._run, args=(self._stop,), daemon=True,
             name="tpu-batch-solve"
@@ -419,6 +449,8 @@ class TPUBatchWorker:
 
     def stop(self) -> None:
         self._stop.set()
+        self._wake.set()
+        self.server.eval_broker.unwatch_ready(self._wake)
         if self._thread:
             self._thread.join(timeout=5)
             self._thread = None
@@ -517,17 +549,23 @@ class TPUBatchWorker:
                 if not stalled:
                     stalled = True
                     metrics.incr("nomad.worker.backpressure_throttled")
-                stop.wait(0.05)
+                    self._enter("stall")
+                # the stalled batch lane does not hold up the lane: an
+                # interactive eval that becomes ready wakes this wait
+                self._wake.clear()
+                if not self._serve_lane("stall"):
+                    self._wake.wait(0.05)
             if stop.is_set():
                 break
             batch: list[tuple[Evaluation, str]] = []
             t_deq = None
+            queue = None
             if self._held is not None:
                 # the interactive eval that preempted the last drain —
                 # its lane clock started when it was HELD, so the time
                 # it waited through the preempting batch's phase A
                 # counts (lane starvation must read off the histogram)
-                ev, token, t_deq = self._held
+                ev, token, t_deq, queue = self._held
                 self._held = None
             else:
                 # one idle stretch = one `worker.idle` span: the stamp
@@ -535,9 +573,12 @@ class TPUBatchWorker:
                 # only when an eval arrives
                 if idle_since is None:
                     idle_since = trace.now_ns()
-                ev, token = broker.dequeue(
+                self._enter("idle")
+                ev, token, ready_ns = broker.dequeue_ready(
                     self.schedulers, timeout_s=DEQUEUE_TIMEOUT_S
                 )
+                if ev is not None:
+                    queue = (ready_ns, self._behind(ready_ns))
             if ev is None:
                 continue
             idle = None
@@ -545,8 +586,9 @@ class TPUBatchWorker:
                 t_deq = trace.now_ns()
                 idle, idle_since = (idle_since, t_deq), None
             if self._interactive(ev):
-                self._run_interactive(ev, token, t_deq, idle)
+                self._run_interactive(ev, token, t_deq, idle, queue)
                 continue
+            self._enter("lower")
             batch.append((ev, token))
             # Effective batch size under backpressure: plan-queue depth
             # and submit-latency EWMA shrink the drain so the solver
@@ -573,7 +615,7 @@ class TPUBatchWorker:
                 # chain (the applier trims the loser; PERF.md § 6, PR 25).
                 wait_s = STRAGGLER_WAIT_S if coalescing else 0
                 while len(batch) < limit:
-                    ev2, token2 = broker.dequeue(
+                    ev2, token2, ready2 = broker.dequeue_ready(
                         self.schedulers, timeout_s=wait_s
                     )
                     if ev2 is None:
@@ -581,9 +623,11 @@ class TPUBatchWorker:
                     if self._interactive(ev2):
                         # lane preempts the drain: the interactive eval
                         # is never baked into this mega-batch — it jumps
-                        # the line as its own solve next cycle (held
-                        # with its lane clock already running)
-                        self._held = (ev2, token2, trace.now_ns())
+                        # the line as its own solve at the batch's first
+                        # wait or next cycle (held with its lane clock
+                        # already running)
+                        self._held = (ev2, token2, trace.now_ns(),
+                                      (ready2, self._behind(ready2)))
                         metrics.incr("nomad.worker.lane.drain_preempted")
                         break
                     batch.append((ev2, token2))
@@ -620,23 +664,31 @@ class TPUBatchWorker:
                     outcome, chained_on, bctx, t_deq=t_deq,
                 )
                 continue
-            committed = threading.Event()
+            committed = _Committed(self._wake)
             handed_off = False
             hspan = trace.span(bctx, "commit.handoff")
             hspan.__enter__()
+            blocked = False
             while not stop.is_set():
+                self._wake.clear()
                 try:
-                    self._commit_q.put(
+                    self._commit_q.put_nowait(
                         (batch, pending, snapshot, committed,
                          outcome, chained_on, bctx, t_deq,
                          # `commit.queue` starts here (_commit_loop)
                          trace.now_ns() if bctx is not None else 0),
-                        timeout=0.2,
                     )
                     handed_off = True
                     break
                 except queue_mod.Full:
-                    continue
+                    # the commit stage is still on the batch before: the
+                    # lane is served while this one waits its turn, and
+                    # the commit stage's take wakes the wait
+                    if not blocked:
+                        blocked = True
+                        self._enter("handoff")
+                    self._serve_lane("handoff")
+                    self._wake.wait(0.2)
             hspan.__exit__(None, None, None)
             if not handed_off:
                 # stopping with a solved batch that never reached the
@@ -655,8 +707,51 @@ class TPUBatchWorker:
                 basis = chained_on[1] if chained_on else snapshot.index
                 self._prev = (pending, committed, outcome, basis)
 
+    def _enter(self, phase: str) -> None:
+        """The solve thread moves to `phase` (what a lane eval that
+        becomes ready from now on is behind)."""
+        if phase != self._phase:
+            self._phase = phase
+            self._phases.append((trace.now_ns(), phase))
+
+    def _behind(self, ready_ns: int) -> str:
+        """The solve thread's phase at `ready_ns` (trace clock)."""
+        if not ready_ns:
+            return self._phase
+        for t, phase in reversed(self._phases):
+            if t <= ready_ns:
+                return phase
+        return self._phases[0][1] if self._phases else self._phase
+
+    def _serve_lane(self, wait: str) -> int:
+        """Where the batch lane's solve thread blocks on a commit
+        (`wait`: chain.wait, stall, handoff), run the interactive evals
+        it holds or the broker has ready, one after another; returns how
+        many."""
+        if self.lane_priority <= 0:
+            return 0
+        served = 0
+        while not self._stop.is_set():
+            if self._held is not None:
+                (ev, token, t_deq, queue), self._held = self._held, None
+            else:
+                ev, token, ready_ns = self.server.eval_broker.dequeue_ready(
+                    self.schedulers, timeout_s=0,
+                    min_priority=self.lane_priority,
+                )
+                if ev is None:
+                    break
+                t_deq = trace.now_ns()
+                queue = (ready_ns, self._behind(ready_ns))
+            metrics.incr("nomad.worker.lane.served_in_wait")
+            self._run_interactive(ev, token, t_deq, queue=queue)
+            self._enter(wait)
+            served += 1
+        return served
+
     def _run_interactive(self, ev: Evaluation, token: str, t_deq: int,
-                         idle: Optional[tuple] = None) -> None:
+                         idle: Optional[tuple] = None,
+                         queue: Optional[tuple] = None) -> None:
         """The interactive lane: solve one eval alone — no drain, no
         mega-batch — and commit INLINE on the solve thread, jumping
         ahead of the in-flight batch sitting in the commit queue. Small
@@ -664,8 +759,16 @@ class TPUBatchWorker:
         big high-priority evals still skip the drain wait. The used'
         chain composes through the lane ledger: a committed lane
         placement that the live chain tensor never saw is fed back to
-        the next chained solve as usage deltas (_solve_batch)."""
+        the next chained solve as usage deltas (_solve_batch). `queue`:
+        (ready_ns, behind) of the eval's `lane.queue` span, which ends
+        at `t_deq`."""
+        self._enter("dispatch")
         metrics.incr("nomad.worker.lane.interactive")
+        if queue is not None and queue[0]:
+            metrics.observe(
+                "nomad.worker.lane.queue_seconds",
+                max(t_deq - queue[0], 0) / 1e9,
+            )
         if ev.create_time:
             # the lane's OTHER clock: from the eval's creation (the
             # job's register, wall clock) to here — the broker, the
@@ -683,6 +786,9 @@ class TPUBatchWorker:
                 bctx.add_span("worker.idle", *idle)
             bctx.set_attr("eval_id", ev.id)
             bctx.set_attr("job_id", ev.job_id)
+            if queue is not None and queue[0]:
+                bctx.add_span("lane.queue", queue[0], t_deq,
+                              attrs={"behind": queue[1]})
             self.server.eval_broker.annotate_trace(
                 ev.id, batch=bctx.trace_id
             )
@@ -847,9 +953,20 @@ class TPUBatchWorker:
                 # point.
                 metrics.incr("nomad.worker.chain.waited")
                 with trace.span(trace.current(), "chain.wait"):
-                    while not committed.wait(0.05):
-                        if self._stop.is_set():
+                    self._enter("chain.wait")
+                    while True:
+                        self._wake.clear()
+                        if committed.is_set() or self._stop.is_set():
                             break
+                        # The lane is served while this batch waits: its
+                        # commit lands before this batch's snapshot, so
+                        # the batch sees the lane's placements and
+                        # evictions in the store. Where the lane and the
+                        # batch in flight chose the same room or victim,
+                        # the applier trims whichever commits second.
+                        self._serve_lane("chain.wait")
+                        self._wake.wait(0.05)
+                    self._enter("lower")
         with trace.span(trace.current(), "snapshot.wait", index=wait_index):
             snapshot = self.server.state.snapshot_min_index(
                 wait_index, timeout_s=5
@@ -942,6 +1059,9 @@ class TPUBatchWorker:
         # un-acked.
         while True:
             item = cq.get()
+            # room in the hand-off queue: a solve thread blocked there
+            # hands off its batch
+            self._wake.set()
             if item is None:
                 return
             (batch, pending, snapshot, committed, outcome,
@@ -1027,6 +1147,7 @@ class TPUBatchWorker:
                 all_full = self._commit_batch(
                     [e for e, _ in batch], plans, snapshot,
                     blocked_basis=chained_on[1] if chained_on else None,
+                    lane=lane,
                 )
         except (Exception, CancelledError) as e:
             # CancelledError included: plan futures cancelled by a queue
@@ -1103,7 +1224,7 @@ class TPUBatchWorker:
 
     def _commit_batch(
         self, evals: list[Evaluation], plans, snapshot,
-        blocked_basis: Optional[int] = None,
+        blocked_basis: Optional[int] = None, lane: str = "batch",
     ) -> bool:
         # One merged submission for the whole batch (the applier commits
         # it as a single raft apply + bulk store transaction, each plan
@@ -1127,7 +1248,11 @@ class TPUBatchWorker:
         )
         results: dict[str, PlanResult] = {}
         if submit:
+            t0 = trace.now_ns()
             got = self.planner.submit_plan_batch([p for _, p in submit])
+            if lane == "interactive":
+                metrics.observe("nomad.worker.lane.commit_seconds",
+                                (trace.now_ns() - t0) / 1e9)
             results = {ev.id: r for (ev, _), r in zip(submit, got)}
         all_full = True
         updates: list[Evaluation] = []

@@ -82,6 +82,16 @@ _PINNED_TO_ITS_OWN_END = {
     "appended_and_nothing_else_moved": "borg2011-12k.mixed-backlog",
     "test_bench_bands.py::test_the_cell_and_its_metrics_are_appended":
         "borg2011-12k-bands.prod-backlog",
+    # a configuration and a cell appended after the bands cell: two more
+    # of its tests read their own at the end of `configs` and `workloads`
+    "test_bench_bands.py::test_the_fleet_is_borg2011_12ks_letter_for_letter":
+        "borg2011-12k-bands.prod-backlog",
+    "test_bench_bands.py::test_the_window_is_mixed_backlogs_period_in_the_"
+    "production_band": "borg2011-12k-bands.prod-backlog",
+    # ... and metrics after the sixteen CPU-by-role ones, whose last is
+    # the last entry that lists `c1m-5k.deploys`
+    "test_bench_host_role.py::test_the_sixteen_are_appended_in_the_tables_"
+    "order_after_pr_35s": "c1m-5k.deploys",
 }
 
 

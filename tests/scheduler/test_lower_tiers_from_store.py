@@ -128,6 +128,7 @@ class Batch:
 def same_table(got, want) -> None:
     assert [n.id for n in got.nodes] == [n.id for n in want.nodes]
     assert got.tier_prios == want.tier_prios
+    assert got.tiers_above == want.tiers_above
     for name in ("cap", "used", "tier_used", "datacenters"):
         a, b = getattr(got, name), getattr(want, name)
         assert a.dtype == b.dtype and a.shape == b.shape, name
@@ -290,4 +291,8 @@ def test_the_resident_tiers_follow_the_store_from_solve_to_solve(seed):
         done = done.copy()
         done.client_status = "complete"
         h.state.update_allocs_from_client(h.next_index(), [done])
-    assert 70 in got.tier_prios
+    # the placements' own priority stands now, and is above what a
+    # production batch may evict: kept by the slabs, left out of the
+    # batch's tiers by the ceiling (solver._lower_table)
+    assert 70 in resident._host_tiers.prios
+    assert 70 not in got.tier_prios and got.tiers_above == 1

@@ -365,7 +365,8 @@ def test_tpu_commit_partial_commit_fails_chain_verdict():
             return {}
 
     w._commit_batch = (
-        lambda evals, plans, snapshot, blocked_basis=None: False  # partial
+        lambda evals, plans, snapshot, blocked_basis=None, lane="batch":
+        False  # partial
     )
     committed = threading.Event()
     outcome = {"ok": None}

@@ -60,9 +60,17 @@ class _PlanQueue:
         return 0
 
 
+class _NoLane:
+    """A broker with no interactive eval ready: the lane a wait serves
+    finds nothing."""
+
+    def dequeue_ready(self, schedulers, timeout_s=None, min_priority=0):
+        return None, "", 0
+
+
 class _Srv:
     def __init__(self, order):
-        self.eval_broker = None
+        self.eval_broker = _NoLane()
         self.plan_queue = _PlanQueue()
         self.state = _State(order)
 

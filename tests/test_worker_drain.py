@@ -61,7 +61,7 @@ class ScriptedBroker:
         self.acked, self.nacked = [], []
         self.on_drain_dequeue = None  # hook: called inside the drain
 
-    def dequeue(self, schedulers, timeout_s=None):
+    def dequeue_ready(self, schedulers, timeout_s=None, min_priority=0):
         span = trace.thread_spans().get(threading.get_ident())
         if span == DRAIN and self.on_drain_dequeue is not None:
             self.on_drain_dequeue()
@@ -71,10 +71,16 @@ class ScriptedBroker:
         self.calls.append((timeout_s, span, answer[0] is not None))
         if answer[0] is None and span != DRAIN:
             self.stop.set()
-        return answer
+        return (*answer, 0)  # no ready instant: `lane.queue` is not timed
 
     def drain_calls(self):
         return [c for c in self.calls if c[1] == DRAIN]
+
+    def watch_ready(self, min_priority, event):
+        pass  # no eval becomes ready but at a dequeue
+
+    def unwatch_ready(self, event):
+        pass
 
     def ack(self, eval_id, token):
         self.acked.append(eval_id)
@@ -211,12 +217,12 @@ class _StopWhenIdle:
         self._stop = stop
         self.asked = []
 
-    def dequeue(self, schedulers, timeout_s=None):
+    def dequeue_ready(self, schedulers, timeout_s=None, min_priority=0):
         self.asked.append(timeout_s)
         if timeout_s == DEQUEUE_TIMEOUT_S and not self._broker.ready_count():
             self._stop.set()
-            return None, ""
-        return self._broker.dequeue(schedulers, timeout_s=0)
+            return None, "", 0
+        return self._broker.dequeue_ready(schedulers, timeout_s=0)
 
     def __getattr__(self, name):
         return getattr(self._broker, name)
