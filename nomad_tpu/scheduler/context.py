@@ -198,6 +198,13 @@ class EvalContext:
             self._regex_cache[pattern] = pat
         return pat
 
+    def plans(self) -> list[Plan]:
+        """The plan being built, then the batch's other plans: what
+        proposed_allocs applies over the snapshot, in that order."""
+        plans = [self.plan] if self.plan is not None else []
+        plans.extend(self.extra_plans)
+        return plans
+
     def proposed_allocs(self, node_id: str) -> list[Allocation]:
         """The node's allocs if the current plan were applied.
 
@@ -205,9 +212,7 @@ class EvalContext:
         terminal filtered (reference: context.go:120).
         """
         existing = self.state.allocs_by_node_terminal(node_id, False)
-        plans = [self.plan] if self.plan is not None else []
-        plans.extend(self.extra_plans)
-        for plan in plans:
+        for plan in self.plans():
             update_ids = {a.id for a in plan.node_update.get(node_id, [])}
             preempt_ids = {a.id for a in plan.node_preemptions.get(node_id, [])}
             drop = update_ids | preempt_ids

@@ -27,6 +27,9 @@ class PropertySet:
         self.allowed_count: int = 0  # distinct_property limit (0 = spread use)
         self._existing: Optional[dict[str, int]] = None
         self._cleared: dict[str, int] = {}
+        # used_counts' map and the (plan, plan.writes) it was counted at
+        self._combined: Optional[dict[str, int]] = None
+        self._combined_at: tuple = (None, -1)
 
     def set_job_constraint(self, constraint: Constraint) -> None:
         self.target_attribute = constraint.ltarget
@@ -73,11 +76,19 @@ class PropertySet:
 
     def used_counts(self) -> dict[str, int]:
         """existing − plan stops + plan placements, per attribute value
-        (reference: GetCombinedUseMap :250)."""
+        (reference: GetCombinedUseMap :250).
+
+        Counted once a plan state: the map is kept while the plan's write
+        count stands (a spread scores every ranked node against it), so
+        callers read it and never write it."""
+        plan = self.ctx.plan
+        at = (plan, plan.writes if plan is not None else 0)
+        if self._combined is not None and at[0] is self._combined_at[0] \
+                and at[1] == self._combined_at[1]:
+            return self._combined
         if self._existing is None:
             self._existing = self._compute_existing()
         combined = dict(self._existing)
-        plan = self.ctx.plan
         if plan is not None:
             for node_id, allocs in plan.node_allocation.items():
                 node = self.ctx.state.node_by_id(node_id)
@@ -97,6 +108,7 @@ class PropertySet:
                 for alloc in allocs:
                     if self._relevant(alloc):
                         combined[val] = max(0, combined.get(val, 0) - 1)
+        self._combined, self._combined_at = combined, at
         return combined
 
     def satisfies_distinct_property(self, node: Node) -> tuple[bool, str]:

@@ -32,6 +32,7 @@ from .feasible import (
 from .propertyset import PropertySet
 from .rank import (
     RankedNode,
+    RankMemo,
     binpack_rank,
     job_anti_affinity_rank,
     node_affinity_rank,
@@ -168,6 +169,9 @@ class GenericStack:
         # on Context).
         self._post_checkers: dict[str, list[FeasibilityChecker]] = {}
         self._spread_scorers: dict[str, SpreadScorer] = {}
+        # The walks' binpack rankings, kept between selects while the
+        # node's plan writes stand; `ranked` / `reused` count them.
+        self.ranks = RankMemo()
 
     def set_nodes(self, nodes: list[Node]) -> None:
         """Shuffle for scheduler-worker decorrelation and set the candidate
@@ -256,8 +260,10 @@ class GenericStack:
 
             feasible = _post_filter(feasible)
 
+        # a sticky try ranks its one preferred node afresh
         options = binpack_rank(
-            self.ctx, feasible, tg, metrics, evict=evict, job=job
+            self.ctx, feasible, tg, metrics, evict=evict, job=job,
+            memo=self.ranks if selected_nodes is None else None,
         )
         options = job_anti_affinity_rank(
             self.ctx, options, job.id, tg.name, tg.count, metrics

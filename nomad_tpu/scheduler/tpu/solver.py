@@ -103,6 +103,9 @@ class SolveOutcome:
     # positions of their permutations were ever drawn (stack.ShuffledNodes)
     stack_nodes: int = 0
     stack_nodes_drawn: int = 0
+    # binpack rankings its walks computed, and replayed (rank.RankMemo)
+    stack_ranked: int = 0
+    stack_reused: int = 0
 
 
 def may_preempt(state, config: SchedulerConfig, jobs, extra_tiers=()) -> bool:
@@ -1537,6 +1540,8 @@ class BatchSolver:
             out = self._solve_host(asks)
             span.set_attr("nodes", out.stack_nodes)
             span.set_attr("nodes_drawn", out.stack_nodes_drawn)
+            span.set_attr("ranked", out.stack_ranked)
+            span.set_attr("reused", out.stack_reused)
         out.solve_ns = now_ns() - t0
         metrics.time_ns("nomad.tpu.solve_seconds", out.solve_ns)
         metrics.observe("nomad.tpu.small_batch_requests", total_requests)
@@ -1774,8 +1779,13 @@ class BatchSolver:
             metrics.observe("nomad.sched.stack.nodes_drawn", drawn)
             if stack.nodes.eager:
                 metrics.incr("nomad.sched.stack.eager_finishes")
+            ranks = stack.ranks
+            metrics.observe("nomad.sched.stack.ranked", ranks.ranked)
+            metrics.observe("nomad.sched.stack.rank_reused", ranks.reused)
             out.stack_nodes += len(stack.nodes)
             out.stack_nodes_drawn += drawn
+            out.stack_ranked += ranks.ranked
+            out.stack_reused += ranks.reused
         return out
 
     def _tier_limit(self, table, grp: LoweredGroup) -> int:

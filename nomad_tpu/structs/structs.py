@@ -2021,6 +2021,17 @@ class Plan:
     # instead of per-row Allocations in node_allocation — the applier,
     # codec, and store consume the columns directly.
     alloc_batches: list = field(default_factory=list)
+    # Writes to node_allocation / node_update / node_preemptions, by node
+    # and in all: every method below that writes them counts. A host
+    # stack keeps a node's ranking while the node's count stands
+    # (scheduler/rank.py RankMemo), a property set its combined counts
+    # while the plan's does (scheduler/propertyset.py).
+    node_writes: dict[str, int] = field(default_factory=dict)
+    writes: int = 0
+
+    def _wrote(self, node_id: str) -> None:
+        self.node_writes[node_id] = self.node_writes.get(node_id, 0) + 1
+        self.writes += 1
 
     def append_placement_batch(self, batch) -> None:
         """Attach a SoA batch of fresh placements (already job-stamped
@@ -2038,6 +2049,7 @@ class Plan:
         for b in self.alloc_batches:
             for a in b.materialize():
                 self.node_allocation.setdefault(a.node_id, []).append(a)
+                self._wrote(a.node_id)
         self.alloc_batches = []
 
     def append_stopped_alloc(
@@ -2051,11 +2063,13 @@ class Plan:
         if client_status:
             new_alloc.client_status = client_status
         self.node_update.setdefault(alloc.node_id, []).append(new_alloc)
+        self._wrote(alloc.node_id)
 
     def append_alloc(self, alloc: Allocation, job: Optional[Job] = None) -> None:
         new_alloc = alloc.copy()
         new_alloc.job = job if job is not None else self.job
         self.node_allocation.setdefault(new_alloc.node_id, []).append(new_alloc)
+        self._wrote(new_alloc.node_id)
 
     def append_fresh_alloc(self, alloc: Allocation, job: Optional[Job] = None) -> None:
         """append_alloc without the defensive copy — ONLY for allocs minted
@@ -2063,6 +2077,7 @@ class Plan:
         solver's hot path: 100k copies would dominate the solve)."""
         alloc.job = job if job is not None else self.job
         self.node_allocation.setdefault(alloc.node_id, []).append(alloc)
+        self._wrote(alloc.node_id)
 
     def append_preempted_alloc(self, alloc: Allocation, preempting_id: str) -> None:
         new_alloc = alloc.copy()
@@ -2073,6 +2088,7 @@ class Plan:
             f"Preempted by alloc ID {preempting_id}"
         )
         self.node_preemptions.setdefault(alloc.node_id, []).append(new_alloc)
+        self._wrote(alloc.node_id)
 
     def pop_update(self, alloc: Allocation) -> None:
         """Remove a pending stop for alloc (in-place update promotion)."""
@@ -2082,6 +2098,7 @@ class Plan:
             existing.pop()
             if not existing:
                 del self.node_update[alloc.node_id]
+            self._wrote(alloc.node_id)
 
     def is_no_op(self) -> bool:
         return (
