@@ -170,7 +170,8 @@ class EvalContext:
 
     def __init__(self, state, plan: Optional[Plan] = None, logger=None,
                  scheduler_config: Optional[SchedulerConfig] = None,
-                 extra_plans: Optional[list] = None) -> None:
+                 extra_plans: Optional[list] = None,
+                 extra_usage=None) -> None:
         self.state = state  # StateSnapshot
         self.plan = plan
         # Other in-flight plans of the SAME batch solve (the small-batch
@@ -178,6 +179,11 @@ class EvalContext:
         # or two evals in one batch double-book a node — the dense path
         # coordinates through its shared caches instead.
         self.extra_plans = extra_plans or []
+        # Usage on a node beyond the snapshot and every plan, as
+        # `.get(node_id)` -> (cpu, mem, disk) or None: what a batch still
+        # in flight placed (the batch solver's host stack, beside it).
+        # Held, never a preemption candidate.
+        self.extra_usage = extra_usage
         self.logger = logger
         self.scheduler_config = scheduler_config or SchedulerConfig()
         self._regex_cache: dict[str, re.Pattern] = {}

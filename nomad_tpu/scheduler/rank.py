@@ -110,12 +110,15 @@ def binpack_node(
     proposed = ctx.proposed_allocs(node.id)
     available = node.available_resources()
     total_ask = tg.combined_resources()
+    held = (
+        ctx.extra_usage.get(node.id) if ctx.extra_usage is not None else None
+    ) or (0, 0, 0)
 
     def _utilization(allocs):
         util = Resources(
-            cpu=total_ask.cpu,
-            memory_mb=total_ask.memory_mb,
-            disk_mb=total_ask.disk_mb,
+            cpu=total_ask.cpu + held[0],
+            memory_mb=total_ask.memory_mb + held[1],
+            disk_mb=total_ask.disk_mb + held[2],
         )
         for alloc in allocs:
             r = alloc.comparable_resources()

@@ -315,7 +315,7 @@ class PendingEvalBatch:
 
     @property
     def chain(self):
-        """(node_ids, used' device array) from this batch's solve: the
+        """The UsageChain this batch's solve offers (solver.py): the
         NEXT in-flight batch chains on it to stay conflict-free while
         this one's commit is still pending (solver.py used_chain). Read
         live from the solver, not snapshotted at begin(): the
@@ -328,9 +328,8 @@ class PendingEvalBatch:
     def solved_in_begin(self) -> bool:
         """Did the solve complete in begin() (host stack, microsolve,
         a sticky partition, nothing to place)? Such a batch has no
-        kernel in flight and its commit is due in milliseconds: where it
-        offers no chain either, the worker waits for that commit rather
-        than solve the next batch blind to it (worker._solve_batch)."""
+        kernel in flight: finish() has nothing to block on. It offers a
+        chain all the same, and the next batch chains on it."""
         return self._pending.solved_in_begin
 
     @property

@@ -623,6 +623,99 @@ def _chain_adj_add(used_dev, table, adj, adj_in, shard_tag: int):
     return _scatter_add_rows(used_dev, idx, rows, shard_tag=shard_tag)
 
 
+class UsageChain:
+    """What a batch in flight offers the next solve (`chain_out`, the
+    worker's `PendingEvalBatch.chain`): the usage it left behind, while
+    its commit is pending, as [pad_n, 3] rows over a node universe. A
+    kernel batch offers its device `used'`, a microsolve batch the same
+    rows on the host, a host-stack batch the rows it read — the chain in
+    flight's, or the committed usage of its own snapshot — with its
+    placements added. Every form lies over the committed usage of one
+    snapshot, the chain's basis, so a commit that lands after it is
+    cancelled by the child's own committed usage (a child reads the
+    rows in place of it). A host-stack batch's rows are built at their
+    first read (`build`): a batch that nothing chains on builds none."""
+
+    __slots__ = ("_node_ids", "_used", "_index_of", "_build")
+
+    def __init__(self, node_ids=None, used=None, index_of=None,
+                 build=None) -> None:
+        self._node_ids = node_ids  # the node universe, in row order
+        self._used = used  # [pad_n, 3]: a jit output or a host array
+        self._index_of = index_of  # node id -> row of `used`
+        self._build = build  # () -> (node_ids, used, index_of)
+
+    def _resolve(self) -> None:
+        if self._build is not None:
+            self._node_ids, self._used, self._index_of = self._build()
+            self._build = None
+
+    @property
+    def node_ids(self) -> list:
+        self._resolve()
+        return self._node_ids
+
+    @property
+    def used(self):
+        self._resolve()
+        return self._used
+
+    @property
+    def index_of(self) -> dict:
+        self._resolve()
+        return self._index_of
+
+    def with_used(self, used) -> "UsageChain":
+        return UsageChain(self.node_ids, used, self.index_of)
+
+
+class _InFlightUsage:
+    """What a host-stack solve counts on a node over its snapshot
+    (EvalContext.extra_usage): the in-flight chain's row less the node's
+    committed usage, and the interactive lane's ledger. Read per node
+    as the stack visits it, so a sample of 17 nodes reads 17 rows of a
+    5,000-node chain."""
+
+    __slots__ = ("_usage", "rows", "_index_of", "_extra")
+
+    def __init__(self, state, chain: UsageChain,
+                 extra: Optional[dict]) -> None:
+        self._usage = state.node_usage
+        # a kernel parent's used' is read back here, once: the copy
+        # waits out its kernel, not its commit
+        self.rows = np.asarray(chain.used)
+        self._index_of = chain.index_of
+        self._extra = extra or {}
+
+    def get(self, nid: str):
+        d = None
+        i = self._index_of.get(nid)
+        if i is not None:
+            row, u = self.rows[i], self._usage(nid)
+            d = [int(row[0]) - u[0], int(row[1]) - u[1], int(row[2]) - u[2]]
+        v = self._extra.get(nid)
+        if v is not None:
+            if d is None:
+                d = [0, 0, 0]
+            d[0] += v[0]
+            d[1] += v[1]
+            d[2] += v[2]
+        return d
+
+
+def _union_nodes(lists) -> list:
+    """The union of ready-node lists, in first-seen order: the one list
+    itself where there is one."""
+    lists = list(lists)
+    if len(lists) == 1:
+        return lists[0]
+    all_nodes = {}
+    for nodes in lists:
+        for node in nodes:
+            all_nodes[node.id] = node
+    return list(all_nodes.values())
+
+
 _ALLOC_FIELD_NAMES = tuple(f.name for f in dataclass_fields(Allocation))
 
 
@@ -719,11 +812,11 @@ class BatchSolver:
         # worker's interactive-lane ledger: placements a priority-lane
         # eval committed after the chain basis, which neither the
         # chained used' tensor nor (for in-flight ones) the committed
-        # aggregate carries. Applied only on the usage-aggregate path
-        # (the host stack path coordinates through plans instead).
+        # aggregate carries. Applied on the usage-aggregate path, and by
+        # a host-stack solve that reads an in-flight chain.
         self.extra_usage = extra_usage
         # set when the solve ran the host microsolve kernel: zero device
-        # involvement, chain neither consumed nor produced
+        # involvement
         self.used_micro = False
         # host-table fingerprint token for the lowered-skeleton cache
         # (set when the resident host-table path produced this solve's
@@ -739,15 +832,17 @@ class BatchSolver:
         # keeps consecutive in-flight batches conflict-free WITHOUT
         # blocking on the device (a pure device-graph dependency) —
         # this is what makes the worker's solve/commit overlap pay at
-        # high fill (docs/pipeline.md).
-        self.used_chain = used_chain
-        # set during phase A when the compact path dispatches: the
-        # (node_ids, used' device array) the NEXT batch may chain on
-        self.chain_out: Optional[tuple] = None
+        # high fill (docs/pipeline.md). The host paths chain too: the
+        # microsolve reads the rows back, the host stack counts them per
+        # node it visits (UsageChain).
+        self.used_chain: Optional[UsageChain] = used_chain
+        # set during phase A: the UsageChain the NEXT batch may chain on
+        # (every path that places offers one)
+        self.chain_out: Optional[UsageChain] = None
         # did this solve actually CONSUME used_chain? False when the
-        # solve took the host/preempt path, the resident tensors won, or
-        # the chain was rejected on a node-universe/shape mismatch — the
-        # worker's chain-failure cascade only applies when this is True
+        # solve took the preempt path or walked the allocs, or the chain
+        # was rejected on a node-universe/shape mismatch — the worker's
+        # chain-failure cascade only applies when this is True
         self.chain_accepted = False
         self.ctx = EvalContext(state, None, logger, self.config)
         self.solve_fn = solve_fn or solve_placement
@@ -792,6 +887,11 @@ class BatchSolver:
         # accounting) that this solve must observe.
         self._partition_placed: list = []
         self._partition_plans: list = []
+        # what the host stack read and visited, for the chain it offers
+        # (_publish_host): the chain in flight, read once, and the ready
+        # list of each datacenter set
+        self._in_flight: Optional[_InFlightUsage] = None
+        self._host_nodes: dict[tuple, list] = {}
         # (eval_id, id(job), tg_name) -> _MintTemplate, shared across a
         # batch's groups (spread sub-groups and the relaxation retry
         # re-hit it; keyed by eval so same-job evals never cross-stamp).
@@ -834,10 +934,13 @@ class BatchSolver:
         out = SolveOutcome()
         self._outcome = out
         self._batch_has_cores = False
-        if not asks:
-            return out
-        kind, low = self._lower_batch(asks, out)
+        kind, low = self._lower_batch(asks, out) if asks else ("done", None)
         if kind == "done":
+            # nothing placed: the chain in flight is passed on whole, so
+            # the batch behind this one still sees what is under it
+            if self.used_chain is not None:
+                self.chain_out = self.used_chain
+                self.chain_accepted = True
             return out
         if kind == "host":
             return self._solve_host_timed(*low)
@@ -847,7 +950,7 @@ class BatchSolver:
             host_out = self._solve_host(sticky_asks)
             rest = [a for i, a in enumerate(asks) if i not in sticky_idx]
             if not rest:
-                return host_out
+                return self._publish_host(host_out)
             # the rest-solve must see the host partition's results:
             # its placements consume capacity; its plans feed the
             # host fast path's cross-eval accounting
@@ -876,18 +979,28 @@ class BatchSolver:
         # non-donated copy so the resident buffer stays committed-state.
         # On a mesh the resident tensors are placed per-shard
         # (ResidentClusterState.mesh). A micro solve skips all of it:
-        # the table's host arrays already carry the aggregate + adj.
+        # the table's host arrays already carry the aggregate + adj, and
+        # an in-flight chain's rows are read back in their place.
         dev_state = None
+        chain_used = None
+        if compact and usage_of is not None and self.used_chain is not None:
+            chain = self.used_chain
+            if (
+                chain.node_ids == self._node_id_col(table)
+                and chain.used.shape == (self._pad_n(n), 3)
+            ):
+                chain_used = chain.used
         if compact and usage_of is not None and not micro:
             shard_tag = self.mesh.n_dev if self.mesh is not None else 0
-            chain_used = None
-            if self.used_chain is not None:
-                chain_ids, chain_used = self.used_chain
-                if not (
-                    chain_ids == tuple(node.id for node in nodes)
-                    and chain_used.shape == (self._pad_n(n), 3)
-                ):
-                    chain_used = None
+            if isinstance(chain_used, np.ndarray):
+                # a host-path parent's rows: onto the device as the
+                # resident tensors are put there
+                import jax
+
+                chain_used = jax.device_put(
+                    chain_used,
+                    *([self.mesh.node_sharding()] if self.mesh else []),
+                )
             if self.resident is not None:
                 cap_dev, used_dev = self.resident.sync(self.state, nodes)
                 if chain_used is not None:
@@ -952,18 +1065,19 @@ class BatchSolver:
             return self._fail_full_cluster(groups, base_of, n, t0)
         if micro:
             inst, over, used_out = self._run_micro(
-                table, groups, used, total_requests
+                table, groups, used, total_requests, chain_used, adj
             )
-            # no chain_out: the micro result is host-known and commits
-            # ahead of any in-flight mega-batch; conflict-freedom for
-            # followers rides the worker's interactive ledger instead
+            if self.chain_accepted:
+                metrics.observe("nomad.tpu.host_chain_consumed", 1)
         elif compact:
             pending = self._run_compact_async(
                 table, groups, used, dev_state=dev_state
             )
             # expose this batch's post-solve usage for the NEXT batch's
             # chain (pending[2] is the kernel's used' device output)
-            self.chain_out = (tuple(node.id for node in nodes), pending[2])
+            self.chain_out = UsageChain(
+                self._node_id_col(table), pending[2], table.index_of
+            )
         else:
             # Exact-repair ledger as plain Python ints: it is touched once
             # per PLACED INSTANCE where small-array numpy ops cost ~10x an
@@ -987,7 +1101,9 @@ class BatchSolver:
                 # them again; the worker has it wait for this batch's
                 # commit instead (worker._solve_batch, docs/pipeline.md).
                 metrics.incr("nomad.tpu.preempt.chain_offered")
-                self.chain_out = (tuple(node.id for node in nodes), pending[2])
+                self.chain_out = UsageChain(
+                    self._node_id_col(table), pending[2], table.index_of
+                )
         # -- phase boundary: the kernel is dispatched, nothing has read
         # it back. The pipelined worker parks here and resumes on its
         # commit stage, so the device round-trip (and everything below)
@@ -1060,9 +1176,7 @@ class BatchSolver:
                 # _materialize_compact still isn't reflected — the
                 # applier's optimistic verification catches that residual
                 # over-placement direction.)
-                self.chain_out = (
-                    tuple(node.id for node in nodes), used_retry
-                )
+                self.chain_out = self.chain_out.with_used(used_retry)
                 leftovers2, mat2_ns = self._timed_materialize(
                     self._materialize_compact,
                     table, retry, inst2, over2, table.cap - used2,
@@ -1078,7 +1192,7 @@ class BatchSolver:
                     use_preempt=use_preempt,
                 )
                 if self.chain_out is not None:
-                    self.chain_out = (self.chain_out[0], used_retry)
+                    self.chain_out = self.chain_out.with_used(used_retry)
                 leftovers2, mat2_ns = self._timed_materialize(
                     self._materialize, table, retry, assign2, None
                 )
@@ -1222,14 +1336,7 @@ class BatchSolver:
                     key = tuple(ask.job.datacenters)
                     if key not in dc_cache:
                         dc_cache[key] = self._ready_nodes(key)[0]
-                if len(dc_cache) == 1:
-                    nodes = next(iter(dc_cache.values()))
-                else:
-                    all_nodes = {}
-                    for nodes_ in dc_cache.values():
-                        for node in nodes_:
-                            all_nodes[node.id] = node
-                    nodes = list(all_nodes.values())
+                nodes = _union_nodes(dc_cache.values())
                 if not nodes:
                     for ask in asks:
                         self._fail_all(out, ask, {})
@@ -1537,31 +1644,122 @@ class BatchSolver:
 
         t0 = now_ns()
         with trace.span(trace.current(), "host_solve", cpu=True) as span:
-            out = self._solve_host(asks)
+            out = self._publish_host(self._solve_host(asks))
             span.set_attr("nodes", out.stack_nodes)
             span.set_attr("nodes_drawn", out.stack_nodes_drawn)
             span.set_attr("ranked", out.stack_ranked)
             span.set_attr("reused", out.stack_reused)
+            span.set_attr("chain", self.chain_accepted)
         out.solve_ns = now_ns() - t0
         metrics.time_ns("nomad.tpu.solve_seconds", out.solve_ns)
         metrics.observe("nomad.tpu.small_batch_requests", total_requests)
+        if self.chain_accepted:
+            metrics.observe("nomad.tpu.host_chain_consumed", 1)
+        return out
+
+    def _offer_rows(self, table, used_out) -> None:
+        """A microsolve batch offers its used' rows as the NEXT batch's
+        chain, padded to the node bucket as the kernel's are and left on
+        the host: a microsolve child reads them as they are, a kernel
+        child puts them on the device."""
+        from ... import metrics
+
+        n = table.n
+        rows = np.zeros((self._pad_n(n), 3), dtype=np.int32)
+        rows[:n] = np.clip(np.asarray(used_out)[:n], 0, 2**31 - 1)
+        metrics.observe("nomad.tpu.host_chain_offered", 1)
+        self.chain_out = UsageChain(
+            self._node_id_col(table), rows, table.index_of
+        )
+
+    def _publish_host(self, out: SolveOutcome) -> SolveOutcome:
+        """A host-stack batch offers what it placed as the NEXT batch's
+        chain, as rows like every other path's: the rows it read from
+        the chain in flight, or with nothing in flight the committed
+        usage of its own snapshot, with its placements (and a sticky
+        partition's) added. The rows are built at the child's first
+        read. Its stops are left out: a follower that counts a stopped
+        alloc as held under-fills, and never over-places."""
+        from ... import metrics
+
+        base = self.used_chain
+        placed = [a for allocs in out.placements.values() for a in allocs]
+        placed += self._partition_placed
+        if not placed:
+            # nothing placed: what is in flight is passed on whole
+            self.chain_out = base
+            return out
+        metrics.observe("nomad.tpu.host_chain_offered", 1)
+        adds = []
+        for a in placed:
+            r = a.comparable_resources()
+            adds.append((a.node_id, (r.cpu, r.memory_mb, r.disk_mb)))
+        base_rows = self._in_flight.rows if base is not None else None
+        ready = list(self._host_nodes.values())
+
+        def build():
+            if base is not None and all(
+                nid in base.index_of for nid, _ in adds
+            ):
+                ids, index_of = base.node_ids, base.index_of
+                rows = np.array(base_rows, dtype=np.int64)
+            else:
+                ids = [node.id for node in _union_nodes(ready)]
+                index_of = {nid: i for i, nid in enumerate(ids)}
+                rows = np.zeros((self._pad_n(len(ids)), 3), dtype=np.int64)
+                if ids:
+                    rows[: len(ids)] = np.array(
+                        self.state.node_usage_many(ids), dtype=np.int64
+                    )[:, :3]
+                if base is not None:
+                    # the chain in flight over another universe: its
+                    # rows where the two meet
+                    for i, nid in enumerate(ids):
+                        j = base.index_of.get(nid)
+                        if j is not None:
+                            rows[i] = base_rows[j]
+            for nid, r in adds:
+                i = index_of.get(nid)
+                if i is not None:
+                    rows[i] += r
+            rows = np.clip(rows, 0, 2**31 - 1).astype(np.int32)
+            return ids, rows, index_of
+
+        self.chain_out = UsageChain(build=build)
         return out
 
     def _run_micro(self, table, groups: list[LoweredGroup], used_n,
-                   total_requests: int):
+                   total_requests: int, chain_used=None, adj=None):
         """Host microsolve dispatch: the numpy compact kernel over the
         UNPADDED table arrays — same readback contract as
         _run_compact_finish ((inst [G, maxC], over [N], used' [N, 3])),
         zero device involvement, zero jit signatures. The instance width
         is the groups' raw count bound (no pad_c bucketing: nothing is
-        transferred, so width stability buys nothing)."""
+        transferred, so width stability buys nothing). With `chain_used`
+        (the batch in flight's used' rows) those rows and this batch's
+        adjustments `adj` replace `used_n`; the result's rows are offered
+        as the NEXT batch's chain."""
         from ... import metrics
         from .microsolve import solve_placement_compact_micro
 
         t0 = now_ns()
         self.used_micro = True
         n = table.n
-        with trace.span(trace.current(), "micro_solve", cpu=True):
+        with trace.span(trace.current(), "micro_solve", cpu=True) as span:
+            if chain_used is not None:
+                # Chain the batch in flight as the kernel does: its used'
+                # rows supersede the committed aggregate, this batch's
+                # adjustments are added on top (_chain_adj_add's rule).
+                # A kernel parent's rows are read back once — the copy
+                # waits out its kernel, never its commit.
+                used_n = np.asarray(chain_used)[:n].astype(np.int64)
+                for nid, d in adj.items():
+                    i = table.index_of.get(nid)
+                    if i is not None:
+                        used_n[i] += d
+                np.maximum(used_n, 0, out=used_n)
+                self.chain_accepted = True
+            span.set_attr("chain", self.chain_accepted)
             maxc = max(1, max(int(grp.count) for grp in groups)) \
                 if groups else 1
             inst, over, used_out = solve_placement_compact_micro(
@@ -1579,6 +1777,7 @@ class BatchSolver:
                 ],
                 maxc,
             )
+            self._offer_rows(table, used_out)
         micro_ns = now_ns() - t0
         metrics.time_ns("nomad.tpu.micro_seconds", micro_ns)
         metrics.observe("nomad.tpu.micro_batch_requests", total_requests)
@@ -1650,6 +1849,15 @@ class BatchSolver:
 
         out = SolveOutcome()
         asks = sorted(asks, key=lambda a: -a.job.priority)
+        # A batch in flight: every node the stacks visit counts what it
+        # published there (its row over the committed usage) and the
+        # lane's ledger, as the kernel's chained solve does.
+        in_flight = None
+        if self.used_chain is not None:
+            in_flight = self._in_flight = _InFlightUsage(
+                self.state, self.used_chain, self.extra_usage
+            )
+            self.chain_accepted = True
         # Cross-eval accounting: every eval's stack must see every OTHER
         # plan in this batch (via ctx.extra_plans) or two evals would
         # double-book one node's capacity/ports — the dense path
@@ -1670,6 +1878,7 @@ class BatchSolver:
             cached = dc_cache.get(key)
             if cached is None:
                 cached = dc_cache[key] = self._ready_nodes(key)
+                self._host_nodes[key] = cached[0]
             nodes, dc_counts = cached
             if not nodes:
                 self._fail_all(out, ask, dc_counts)
@@ -1686,6 +1895,7 @@ class BatchSolver:
                     logger,
                     self.config,
                     extra_plans=[p for p in batch_plans if p is not ask.plan],
+                    extra_usage=in_flight,
                 )
                 stack = GenericStack(ask.eval_obj.type == "batch", ctx)
                 stack.set_nodes(nodes)

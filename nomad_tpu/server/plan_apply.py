@@ -33,7 +33,7 @@ from typing import Callable, Optional
 
 import numpy as np
 
-from .. import trace
+from .. import metrics, trace
 from ..gctune import paused_gc
 from ..state.store import usage_contribution
 from ..structs import Plan, PlanResult, allocs_fit
@@ -376,7 +376,11 @@ def evaluate_plan(snapshot, plan: Plan) -> PlanResult:
                 committed_batches.append(b.take(keep))
         result.alloc_batches = committed_batches
 
+    # plans verified, and those the verification cut: what a solve that
+    # could not see a batch in flight costs shows as trimmed plans
+    metrics.incr("nomad.plan_apply.plans_verified")
     if rejected:
+        metrics.incr("nomad.plan_apply.plans_trimmed")
         if plan.all_at_once:
             # all-or-nothing jobs: reject the ENTIRE plan — stops,
             # preemptions, and deployment changes must not land without
@@ -917,7 +921,7 @@ class PlanApplier:
                     result.refresh_index, snapshot.index
                 )
         if trimmed:
-            from .. import blackbox, metrics
+            from .. import blackbox
 
             metrics.incr("nomad.plan_apply.dup_mint_trimmed", trimmed)
             # flight-recorder journal: the dup-mint-invariant trigger
@@ -1012,8 +1016,6 @@ class PlanApplier:
         the next pass, on a fresh snapshot. One pass is the rule.
         Volume-touching plans never merge; their indices are returned
         for the caller's true serial path."""
-        from .. import metrics
-
         t0 = time.perf_counter()
         results: dict[int, PlanResult] = {}
         remaining = list(range(len(plans)))

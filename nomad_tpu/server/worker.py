@@ -609,10 +609,10 @@ class TPUBatchWorker:
                 # Take what is ready; never wait on an empty broker after
                 # a batch of one — a quiet cluster pays nothing here. After
                 # a batch of several, evals are arriving together: wait
-                # for a straggler, because what coalesces here solves
-                # conflict-free in ONE batch, and what does not solves in
-                # overlapping small batches that the host paths cannot
-                # chain (the applier trims the loser; PERF.md § 6, PR 25).
+                # for a straggler, because what coalesces here solves in
+                # ONE batch, and what does not solves in overlapping
+                # small batches, each chained on the one before it
+                # (PERF.md § 6).
                 wait_s = STRAGGLER_WAIT_S if coalescing else 0
                 while len(batch) < limit:
                     ev2, token2, ready2 = broker.dequeue_ready(
@@ -910,32 +910,13 @@ class TPUBatchWorker:
             max(ev.snapshot_index for ev in evals),
         )
         if allow_chain and self._prev is not None:
-            prev_pending, committed = self._prev[:2]
-            if not committed.is_set() and (
-                (prev_pending.solved_in_begin and prev_pending.chain is None)
-                or may_preempt(
-                    self.server.state, self.config,
-                    ((ev.type, ev.priority) for ev in evals),
-                )
+            committed = self._prev[1]
+            if not committed.is_set() and may_preempt(
+                self.server.state, self.config,
+                ((ev.type, ev.priority) for ev in evals),
             ):
-                # Two batches that may not solve beside the one in
-                # flight. (1) That one was solved whole in its phase A
-                # and offers no chain: a small batch that the host stack
-                # or the microsolve took. Until it commits, its
-                # placements — and, commits being FIFO, those of the
-                # mega-batch before it, whose used' tensor it dropped
-                # from the chain — are in no snapshot and in no tensor
-                # this solve could be given: a kernel batch solved now
-                # re-places onto the same nodes, the applier trims it,
-                # and every batch chained behind it is nacked for the
-                # broker's 5 s (on borg2011-12k.mixed-backlog, where
-                # 60 % of the jobs are one alloc: 10-21 batches and
-                # 4-7 s of a 11 s window, one run in four; PERF.md
-                # section 6, PR 32). Such a batch has nothing to wait
-                # for on the device: its commit takes milliseconds once
-                # the FIFO reaches it. (2) THIS batch may preempt: an
-                # eval of a type the operator lets preempt,
-                # PRIORITY_DELTA over some alloc's priority
+                # THIS batch may preempt: an eval of a type the operator
+                # lets preempt, PRIORITY_DELTA over some alloc's priority
                 # (solver.may_preempt, the test the solver picks its
                 # kernel by). A chained used' carries the parent's
                 # placements and what its victims freed, but this
@@ -943,14 +924,19 @@ class TPUBatchWorker:
                 # those victims: it would count them free again, and
                 # choose its exact victims and its exact room from a
                 # snapshot without the parent's plan — the applier trims
-                # it and the cascade is (1)'s (PERF.md section 6,
-                # PR 35). Either way: wait for that commit, before the
-                # snapshot is taken; the batch then chains on nothing.
-                # Every other batch beside a kernel or a pool RPC in
-                # flight (compact, preempt, remote) chains on the
-                # parent's used' — a preempt parent offers its own — and
-                # is never made to wait: the overlap is the pipeline's
-                # point.
+                # it, and every batch chained behind it is nacked for
+                # the broker's 5 s (PERF.md section 6). So it waits for
+                # that commit, before the snapshot is taken, and then
+                # chains on nothing. Every other batch beside
+                # one in flight chains on what that one offers and is
+                # never made to wait: a kernel or a preempt solve its
+                # used', a microsolve its used' rows, a host-stack batch
+                # the rows it read with its placements added
+                # (solver.UsageChain). A batch with nothing to offer —
+                # a pool RPC, a custom kernel — is overlapped all the
+                # same: the overlap is the pipeline's point. A lane eval
+                # that arrives meanwhile is served where this thread
+                # next blocks: the hand-off, or the next drain.
                 metrics.incr("nomad.worker.chain.waited")
                 with trace.span(trace.current(), "chain.wait"):
                     self._enter("chain.wait")
@@ -1030,9 +1016,9 @@ class TPUBatchWorker:
             extra_usage=self._lane_extra_usage(snapshot, chained_on),
         )
         if chained_on is not None and not pending.chain_accepted:
-            # the solver took a path that never consumed the chain (host
-            # partition, resident tensors, node-universe mismatch): this
-            # solve saw only committed state, so the parent's commit
+            # the solver took a path that never consumed the chain (the
+            # preempt kernel, an alloc walk, a node-universe mismatch):
+            # this solve saw only committed state, so the parent's commit
             # verdict must not nack it and its blocked evals need no
             # older basis index
             chained_on = None

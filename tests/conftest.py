@@ -92,6 +92,15 @@ _PINNED_TO_ITS_OWN_END = {
     # the last entry that lists `c1m-5k.deploys`
     "test_bench_host_role.py::test_the_sixteen_are_appended_in_the_tables_"
     "order_after_pr_35s": "c1m-5k.deploys",
+    # a configuration, a cell and metrics appended after the monitor
+    # cell: three of its tests read their own at the end of `configs`,
+    # `workloads`, `e2e_p50_ms`'s cells and `per_layer`
+    "test_bench_monitor.py::test_everything_but_the_monitoring_band_is_the_"
+    "bands_cells": "borg2011-12k-monitor.prod-lanes",
+    "test_bench_monitor.py::test_the_background_is_prod_backlogs_period_"
+    "paced": "borg2011-12k-monitor.prod-lanes",
+    "test_bench_monitor.py::test_the_cell_and_its_metrics_are_appended":
+        "borg2011-12k-monitor.prod-lanes",
 }
 
 

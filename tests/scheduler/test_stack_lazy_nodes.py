@@ -308,5 +308,6 @@ def test_the_host_solve_span_carries_nodes_and_nodes_drawn():
     assert len(spans) == 1
     assert spans[0].attrs == {
         "nodes": 640, "nodes_drawn": out.stack_nodes_drawn,
-        "ranked": out.stack_ranked, "reused": out.stack_reused}
+        "ranked": out.stack_ranked, "reused": out.stack_reused,
+        "chain": False}  # nothing in flight: no chain read
     assert 10 <= out.stack_nodes_drawn < 20

@@ -1,25 +1,22 @@
-"""A solve behind a batch in flight that was solved whole on the host and
-offers no chain waits for its commit (PR 32).
+"""Which solve behind a batch in flight waits for its commit.
 
 The pipelined worker solves batch N+1 while batch N commits, and keeps
-the two conflict-free by chaining N+1 on N's post-solve usage tensor. A
-small batch that the host stack or the microsolve took has no tensor to
-offer: what it placed — and, commits being FIFO, what the mega-batch
-before it placed — is invisible to the next solve until committed. On a
-backlog of mixed jobs a kernel batch solved in that gap re-placed onto
-the same nodes, was trimmed, and every batch chained behind it was
-nacked for the broker's 5 s.
+the two conflict-free by chaining N+1 on what N offers
+(`PendingEvalBatch.chain`, a `solver.UsageChain`): a kernel batch its
+post-solve usage tensor, a microsolve batch the same rows on the host,
+a host-stack batch the rows it read with its own placements added. So
+no batch waits behind a small batch solved whole on
+the host: it chains on it, and commits, being FIFO, keep the verdicts in
+order. A batch with a kernel or a pool RPC in flight that offers nothing
+(the dense kernel of a custom solve_fn, a `RemotePendingBatch`) is not
+waited for either: the overlap with it is the pipeline's point
+(docs/pipeline.md, docs/solver-pool.md).
 
-Only such a batch is waited for: one with a kernel or a pool RPC still in
-flight (the dense kernel, a `RemotePendingBatch`) offers no chain
-either, but its commit is far off and the overlap with it is the
-pipeline's point (docs/pipeline.md, docs/solver-pool.md).
-
-And a solve that MAY PREEMPT waits for whatever is in flight (PR 35), at
-the same place, before its snapshot: it reads its tiers, its exact room
-and its victims from the store, which holds the parent's plan only once
-it is committed. The preempt solve itself offers its used' tensor, so a
-batch behind it that cannot preempt chains on it and does not wait.
+A solve that MAY PREEMPT waits for whatever is in flight, before its
+snapshot: it reads its tiers, its exact room and its victims from the
+store, which holds the parent's plan only once it is committed. The
+preempt solve itself offers its used' tensor, so a batch behind it that
+cannot preempt chains on it and does not wait.
 """
 
 import threading
@@ -124,13 +121,17 @@ def waited() -> int:
 
 def test_a_solve_behind_a_chainless_batch_in_flight_waits_for_its_commit(
         worker):
+    """A batch solved whole on the host that offers nothing placed
+    nothing and had nothing in flight under it: the solve behind it has
+    nothing to wait for, and chains on nothing. (Named for what it
+    checked while the host paths offered no chain: it waited then.)"""
     w, order, given = worker
     t = in_flight(w, order, chain=None)
     pending, _, chained_on = w._solve_batch([mock.evaluation()])
+    assert order == ["snapshot"]  # the parent's commit is still pending
     t.join()
-    assert order == ["committed", "snapshot"]
     assert given == [None] and chained_on is None
-    assert waited() == 1
+    assert waited() == 0
 
 
 def test_a_solve_behind_a_batch_that_offers_its_tensor_does_not_wait(worker):
@@ -219,14 +220,17 @@ def real_pending(kind: str):
 
 
 @pytest.mark.parametrize("kind, solved_in_begin, offers_chain, waits", [
-    ("host", True, False, True),
-    ("micro", True, False, True),
+    ("host", True, True, False),
+    ("micro", True, True, False),
     ("compact", False, True, False),
     ("dense", False, False, False),
     ("remote", False, False, False),
 ])
 def test_only_a_batch_solved_whole_on_the_host_is_waited_for(
         worker, kind, solved_in_begin, offers_chain, waits):
+    """Every real pending is chained on or overlapped; none is waited
+    for. (Named for what it checked while the host paths offered no
+    chain: the host stack and the microsolve were waited for then.)"""
     w, order, given = worker
     pending = real_pending(kind)
     assert pending.solved_in_begin is solved_in_begin
