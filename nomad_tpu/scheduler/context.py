@@ -190,6 +190,9 @@ class EvalContext:
         self._version_cache: dict[str, object] = {}
         self.eligibility = EvalEligibility()
         self.metrics_nodes_evaluated = 0
+        # binpack rankings that found a node exhausted from the store's
+        # usage total, without building its proposed allocs
+        self.exhausted_by_usage = 0
 
     def set_plan(self, plan: Plan) -> None:
         self.plan = plan

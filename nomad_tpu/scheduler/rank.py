@@ -106,19 +106,23 @@ def binpack_node(
     job=None,
 ) -> tuple[Optional[RankedNode], str]:
     """One node of binpack_rank: its option, or None and the dimension
-    it is exhausted in."""
-    proposed = ctx.proposed_allocs(node.id)
+    it is exhausted in.
+
+    On a node that no plan of the context writes, the proposed allocs
+    are the snapshot's live allocs, whose sum the store keeps
+    (`node_usage`): the normal pass checks the fit against that total
+    and builds the node's alloc list only where the ask fits."""
     available = node.available_resources()
     total_ask = tg.combined_resources()
     held = (
         ctx.extra_usage.get(node.id) if ctx.extra_usage is not None else None
     ) or (0, 0, 0)
 
-    def _utilization(allocs):
+    def _utilization(allocs, used=(0, 0, 0)):
         util = Resources(
-            cpu=total_ask.cpu + held[0],
-            memory_mb=total_ask.memory_mb + held[1],
-            disk_mb=total_ask.disk_mb + held[2],
+            cpu=total_ask.cpu + held[0] + used[0],
+            memory_mb=total_ask.memory_mb + held[1] + used[1],
+            disk_mb=total_ask.disk_mb + held[2] + used[2],
         )
         for alloc in allocs:
             r = alloc.comparable_resources()
@@ -127,9 +131,18 @@ def binpack_node(
             util.disk_mb += r.disk_mb
         return util
 
-    util = _utilization(proposed)
+    if not evict and _untouched(ctx, node.id):
+        util = _utilization((), ctx.state.node_usage(node.id))
+        ok, dim = available.superset(util)
+        if not ok:
+            ctx.exhausted_by_usage += 1
+            return None, dim
+        proposed = ctx.proposed_allocs(node.id)
+    else:
+        proposed = ctx.proposed_allocs(node.id)
+        util = _utilization(proposed)
+        ok, dim = available.superset(util)
     preempted_allocs = None
-    ok, dim = available.superset(util)
     if not ok and evict and job is not None:
         from .preemption import Preemptor
 
@@ -216,6 +229,16 @@ def binpack_node(
         node, tg, task_resources, shared_networks, proposed,
         preempted_allocs, fit_score / 18.0,
     ), ""
+
+
+def _untouched(ctx: EvalContext, node_id: str) -> bool:
+    """No plan of the context places, stops or preempts on the node:
+    what proposed_allocs lays over the snapshot there is nothing."""
+    for plan in ctx.plans():
+        if (node_id in plan.node_allocation or node_id in plan.node_update
+                or node_id in plan.node_preemptions):
+            return False
+    return True
 
 
 def _ranked(node, tg, task_resources, shared_networks, proposed,

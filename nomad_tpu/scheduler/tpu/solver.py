@@ -106,6 +106,9 @@ class SolveOutcome:
     # binpack rankings its walks computed, and replayed (rank.RankMemo)
     stack_ranked: int = 0
     stack_reused: int = 0
+    # of those computed, the ones that found the node exhausted from the
+    # store's usage total alone (rank.binpack_node)
+    stack_by_usage: int = 0
 
 
 def may_preempt(state, config: SchedulerConfig, jobs, extra_tiers=()) -> bool:
@@ -1649,6 +1652,7 @@ class BatchSolver:
             span.set_attr("nodes_drawn", out.stack_nodes_drawn)
             span.set_attr("ranked", out.stack_ranked)
             span.set_attr("reused", out.stack_reused)
+            span.set_attr("by_usage", out.stack_by_usage)
             span.set_attr("chain", self.chain_accepted)
         out.solve_ns = now_ns() - t0
         metrics.time_ns("nomad.tpu.solve_seconds", out.solve_ns)
@@ -1992,10 +1996,13 @@ class BatchSolver:
             ranks = stack.ranks
             metrics.observe("nomad.sched.stack.ranked", ranks.ranked)
             metrics.observe("nomad.sched.stack.rank_reused", ranks.reused)
+            by_usage = stack.ctx.exhausted_by_usage
+            metrics.observe("nomad.sched.stack.exhausted_by_usage", by_usage)
             out.stack_nodes += len(stack.nodes)
             out.stack_nodes_drawn += drawn
             out.stack_ranked += ranks.ranked
             out.stack_reused += ranks.reused
+            out.stack_by_usage += by_usage
         return out
 
     def _tier_limit(self, table, grp: LoweredGroup) -> int:

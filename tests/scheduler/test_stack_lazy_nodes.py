@@ -309,5 +309,6 @@ def test_the_host_solve_span_carries_nodes_and_nodes_drawn():
     assert spans[0].attrs == {
         "nodes": 640, "nodes_drawn": out.stack_nodes_drawn,
         "ranked": out.stack_ranked, "reused": out.stack_reused,
+        "by_usage": 0,  # an empty cluster: no node exhausted
         "chain": False}  # nothing in flight: no chain read
     assert 10 <= out.stack_nodes_drawn < 20

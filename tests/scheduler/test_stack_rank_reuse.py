@@ -851,6 +851,116 @@ def test_a_group_with_ports_reuses_none(registry):
         "count": 1, "sum": 0}
 
 
+
+# -- rankings the store's usage total decided (nomad.sched.stack.exhausted_by_usage)
+
+def half_full_cluster(n: int, priority: int = 50) -> tuple[Harness, set]:
+    """`n` nodes over four datacenters; every other one holds a standing
+    alloc of its whole CPU. Returns the harness and the full nodes' ids."""
+    h = empty_cluster(n)
+    standing = mock.job(id="standing", priority=priority)
+    standing.task_groups[0].tasks[0].resources.networks = []
+    h.state.upsert_job(h.next_index(), standing)
+    allocs = []
+    for node in sorted(h.state.nodes(), key=lambda x: x.name)[::2]:
+        a = mock.alloc(job_=standing, node_=node, index=len(allocs))
+        a.resources.tasks["web"].cpu = node.available_resources().cpu
+        a.resources.tasks["web"].memory_mb = 64
+        allocs.append(a)
+    h.state.upsert_allocs(h.next_index(), allocs)
+    return h, {a.node_id for a in allocs}
+
+
+def spy_rankings(monkeypatch) -> list:
+    """Every binpack ranking computed, as (node id, evict, exhausted)."""
+    from nomad_tpu.scheduler import rank as rank_mod
+
+    calls = []
+    real = rank_mod.binpack_node
+
+    def spy(ctx, node, tg, algo, evict=False, job=None):
+        ranked, dim = real(ctx, node, tg, algo, evict, job)
+        calls.append((node.id, evict, ranked is None))
+        return ranked, dim
+
+    monkeypatch.setattr(rank_mod, "binpack_node", spy)
+    return calls
+
+
+def test_a_batch_over_a_half_full_cluster_counts_the_full_nodes_it_ranked(
+        registry, monkeypatch):
+    h, full = half_full_cluster(2_000)
+    evs = []
+    for i in range(2):
+        job = service(4, job_id=f"deploy-{i}")
+        _even_spread(job)
+        h.state.upsert_job(h.next_index(), job)
+        evs.append(mock.eval_for_job(job))
+    calls = spy_rankings(monkeypatch)
+    snap = h.snapshot()
+    _, asks = _reconcile_eval_batch(snap, h, evs, HOST_ONLY)
+    random.seed(3)
+    ctx = trace.TraceContext("tpu.batch")
+    with trace.use(ctx):
+        out = BatchSolver(snap, HOST_ONLY).solve(asks)
+    assert sum(len(out.placements[ev.id]) for ev in evs) == 8
+    on_full = [c for c in calls if c[0] in full]
+    assert all(exhausted and not evict for _, evict, exhausted in on_full)
+    assert len(calls) == out.stack_ranked  # no sticky try ranked
+    by_usage = out.stack_by_usage
+    assert by_usage == len(on_full) > 0
+    # a shuffled walk meets a full node about every other draw
+    assert 0.3 < by_usage / out.stack_ranked < 0.7
+    assert hist(registry, "nomad.sched.stack.exhausted_by_usage") == {
+        "count": 2, "sum": by_usage}  # one observation a stack
+    span, = [s for s in ctx.spans if s.name == "host_solve"]
+    assert span.attrs["by_usage"] == by_usage
+
+
+def test_an_empty_cluster_reads_no_ranking_by_usage(registry):
+    h = empty_cluster(2_000)
+    job = service(8)
+    h.state.upsert_job(h.next_index(), job)
+    ev = mock.eval_for_job(job)
+    ctx = trace.TraceContext("tpu.batch")
+    with trace.use(ctx):
+        out = host_solve(h, ev, seed=7)
+    assert len(out.placements[ev.id]) == 8 and out.stack_ranked > 0
+    assert out.stack_by_usage == 0
+    assert hist(registry, "nomad.sched.stack.exhausted_by_usage") == {
+        "count": 1, "sum": 0}
+    span, = [s for s in ctx.spans if s.name == "host_solve"]
+    assert span.attrs["by_usage"] == 0
+
+
+def test_the_evict_pass_counts_no_ranking_by_usage(monkeypatch):
+    """A cluster full of priority-20 allocs: the normal pass ends every
+    ranking on the usage total; the evict pass builds each node's list
+    for the Preemptor and counts none."""
+    from nomad_tpu.structs import AllocMetric
+
+    h, full = half_full_cluster(64, priority=20)
+    job = service(1, cpu=1_000, priority=70)
+    snap = h.snapshot()
+    nodes = [n for n in snap.nodes() if n.id in full]
+    calls = spy_rankings(monkeypatch)
+    got = {}
+    for evict in (False, True):
+        ctx = EvalContext(snap, Plan(job=job), None, SchedulerConfig())
+        stack = GenericStack(False, ctx)
+        stack.set_nodes(nodes)
+        stack.set_job(job)
+        random.seed(1)
+        option = stack.select(job.task_groups[0], metrics=AllocMetric(),
+                              evict=evict)
+        got[evict] = option, ctx.exhausted_by_usage
+    normal, evicting = got[False], got[True]
+    assert normal == (None, 32)  # every full node, each once
+    assert evicting[0] is not None and evicting[0].preempted_allocs
+    assert evicting[1] == 0
+    assert sum(1 for _, evict, _ in calls if evict) > 0
+
+
 # -- the plan's write counters --------------------------------------------------
 
 def _alloc(node_id: str, i: int = 0) -> Allocation:
